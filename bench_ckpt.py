@@ -98,6 +98,8 @@ def main() -> int:
     import jax
 
     from cxxnet_tpu.runtime.async_ckpt import AsyncCheckpointer
+    from cxxnet_tpu.utils.backend import enable_compile_cache
+    enable_compile_cache()
 
     batches = _batches(steps + 2, batch)   # 2 warmup + `steps` timed
 

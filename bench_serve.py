@@ -77,10 +77,10 @@ back-to-back for ``--duration`` seconds after a warmup.  Decode clients
 send mixed prompt lengths with staggered arrivals; per-token latency is
 the gap between consecutive emissions of one stream.
 
-Fallback policy (PR 5): when the accelerator backend cannot be reached
-within ``CXXNET_BENCH_BACKEND_WAIT`` seconds the run re-executes pinned
-to ``JAX_PLATFORMS=cpu`` and the receipt is tagged
-``"platform": "cpu-fallback"`` — the ledger always records a number.
+Backend: the run checks in this process that JAX is on a TPU and
+otherwise exits non-zero with one JSON ``error`` line; a caller that pins
+``JAX_PLATFORMS=cpu`` gets a correctness run stamped ``"platform":
+"cpu"`` whose timings are not device numbers.
 Env: CXXNET_SERVE_BENCH_* override the defaults below.
 """
 
@@ -89,12 +89,13 @@ from __future__ import annotations
 import argparse
 import json
 import os
-import subprocess
 import sys
 import threading
 import time
 
 import numpy as np
+
+from cxxnet_tpu.utils.backend import enable_compile_cache, require_chip
 
 NET_CFG = """
 netconfig=start
@@ -109,52 +110,6 @@ input_shape = 1,1,32
 batch_size = 32
 eta = 0.1
 """
-
-
-def _backend_ok(budget: float) -> bool:
-    """True when jax can reach a non-CPU backend (or CPU was asked for
-    explicitly); bounded subprocess probe, same policy as bench.py."""
-    plats = [p.strip() for p in
-             os.environ.get('JAX_PLATFORMS', '').split(',') if p.strip()]
-    if plats and all(p == 'cpu' for p in plats):
-        return True                       # explicit CPU run: no probe
-    try:
-        r = subprocess.run(
-            [sys.executable, '-c',
-             'import jax; print(jax.devices()[0].platform)'],
-            capture_output=True, text=True,
-            timeout=max(20.0, min(180.0, budget)))
-        return r.returncode == 0 and \
-            (r.stdout or '').strip().splitlines()[-1:] != ['cpu']
-    except subprocess.TimeoutExpired:
-        return False
-
-
-def _cpu_fallback(argv, reason: str) -> int:
-    """Re-run this bench pinned to CPU and re-tag its receipt."""
-    env = dict(os.environ)
-    env['JAX_PLATFORMS'] = 'cpu'
-    r = subprocess.run([sys.executable, os.path.abspath(__file__)]
-                      + list(argv or sys.argv[1:]),
-                      env=env, capture_output=True, text=True,
-                      timeout=3000)
-    payload = None
-    for line in reversed((r.stdout or '').strip().splitlines()):
-        try:
-            payload = json.loads(line)
-            break
-        except ValueError:
-            continue
-    if payload is None:
-        print(json.dumps({'metric': 'serve_bench', 'value': None,
-                          'error': f'cpu fallback produced no JSON '
-                                   f'(rc={r.returncode})',
-                          'fallback_reason': reason}))
-        return 1
-    payload['platform'] = 'cpu-fallback'
-    payload['fallback_reason'] = reason
-    print(json.dumps(payload))
-    return 0 if payload.get('value') is not None else 1
 
 
 def bench_predict(args) -> dict:
@@ -1218,10 +1173,6 @@ def main(argv=None) -> int:
                 flags + ' --xla_force_host_platform_device_count=8'
             ).strip()
 
-    budget = float(os.environ.get('CXXNET_BENCH_BACKEND_WAIT', '60'))
-    if not _backend_ok(budget):
-        return _cpu_fallback(argv, f'TPU backend unavailable within '
-                                   f'{budget:.0f}s')
     modes = {'predict': bench_predict, 'decode': bench_decode,
              'decode_matrix': bench_decode_matrix,
              'prefix': bench_prefix, 'spec': bench_spec,
@@ -1239,6 +1190,8 @@ def main(argv=None) -> int:
                'kv_tiers': 'kv_tier_speedup',
                'sharded': 'decode_shard_scaling'}
     try:
+        enable_compile_cache()
+        require_chip()
         out = modes[args.mode](args)
     except Exception as e:  # structured failure, never a bare traceback
         out = {'metric': metrics[args.mode],

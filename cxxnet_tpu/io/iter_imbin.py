@@ -185,8 +185,8 @@ class ImageBinIterator(IIterator):
             yield from enumerate(self._iter_pages(self._bins[part]))
             return
         page_order = list(page_order)
-        from ..runtime.native import NativePageReader, native_order_available
-        if native_order_available():
+        from ..runtime.native import NativePageReader, native_available
+        if native_available():
             reader = NativePageReader(self._bins[part], order=page_order)
             try:
                 for pidx, page in zip(page_order, reader.iter_pages()):

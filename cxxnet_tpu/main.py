@@ -23,10 +23,18 @@ from typing import List, Optional, Tuple
 from .io.data import create_iterator
 from .nnet import checkpoint as model_io
 from .nnet.trainer import NetTrainer
+from .utils.backend import enable_compile_cache
 from .utils.config import apply_cli_overrides, parse_config_file
 from .utils.profiler import TraceWindow
 
 ConfigEntry = Tuple[str, str]
+
+#: task= name -> the LearnTask method that runs it (serve.mode=decode
+#: has its own, see run)
+_TASKS = {'train': 'task_train', 'finetune': 'task_train',
+          'pred': 'task_predict', 'pred_raw': 'task_predict_raw',
+          'extract': 'task_extract', 'serve': 'task_serve',
+          'online': 'task_online', 'autotune': 'task_autotune'}
 
 
 class LearnTask:
@@ -1679,6 +1687,9 @@ class LearnTask:
         cfg = apply_cli_overrides(cfg, argv[1:])
         for name, val in cfg:
             self.set_param(name, val)
+        if self.task not in _TASKS:
+            raise ValueError(
+                f'unknown task {self.task!r}; choose from {sorted(_TASKS)}')
         if self.task == 'train' and self.dist_rank < 0 \
                 and (self.dist_hosts > 1
                      or (self.dist_hosts == 1 and self.dist_launch)):
@@ -1718,23 +1729,10 @@ class LearnTask:
             self._obs_register_iterators()
             if not self.silent:
                 print('initializing end, start working')
-            if self.task in ('train', 'finetune'):
-                self.task_train()
-            elif self.task == 'pred':
-                self.task_predict()
-            elif self.task == 'pred_raw':
-                self.task_predict_raw()
-            elif self.task == 'extract':
-                self.task_extract()
-            elif self.task == 'serve':
-                if self.serve_mode == 'decode':
-                    self.task_serve_decode()
-                else:
-                    self.task_serve()
-            elif self.task == 'online':
-                self.task_online()
-            elif self.task == 'autotune':
-                self.task_autotune()
+            if self.task == 'serve' and self.serve_mode == 'decode':
+                self.task_serve_decode()
+            else:
+                getattr(self, _TASKS[self.task])()
         finally:
             self._obs_stop()
         if plan is not None and not self.silent:
@@ -1749,6 +1747,7 @@ class LearnTask:
 
 
 def main(argv: Optional[List[str]] = None) -> int:
+    enable_compile_cache()
     return LearnTask().run(argv if argv is not None else sys.argv[1:])
 
 

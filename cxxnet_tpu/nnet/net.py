@@ -710,8 +710,7 @@ class Net:
         use_fused = bool(self._convact_pairs or self._convact_solo)
         if use_fused:
             from ..ops.pallas_cnn import conv_use_fused
-            use_fused = conv_use_fused(self._fuse_knob,
-                                       spmd_devices=ctx.spmd_devices)
+            use_fused = conv_use_fused(self._fuse_knob)
         for i in self._exec_order:
             info = cfg.layers[i]
             layer = self.layers[i]

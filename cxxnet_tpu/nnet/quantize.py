@@ -254,7 +254,7 @@ def _int8_mm(aq, bq):
     ``dot_general`` otherwise — bitwise-identical either way (exact
     integer accumulation; pinned in tests/test_quantize.py)."""
     from ..ops import pallas_kernels as PK
-    if PK.pallas_enabled() and PK.pltpu is not None:
+    if PK.pallas_enabled():
         return PK.pallas_int8_matmul(aq, bq)
     return jax.lax.dot_general(aq, bq, (((1,), (0,)), ((), ())),
                                preferred_element_type=jnp.int32)

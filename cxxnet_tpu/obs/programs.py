@@ -58,38 +58,47 @@ from typing import Callable, Dict, List, Optional, Tuple
 __all__ = ['ProgramLedger', 'LedgerProgram', 'ProgramEntry', 'get_ledger',
            'install_ledger', 'peak_bytes_for', 'DeviceMemory',
            'register_hbm', 'ProfilerSession', 'profile_session',
-           'peak_flops', 'mfu', 'PEAK_BF16_TFLOPS']
+           'peak_flops', 'mfu', 'PEAK_BF16_TFLOPS',
+           'UnknownDeviceKindError']
 
 
 # --- per-platform peak FLOPs (MFU denominators) -----------------------------
 
-#: bf16 peak TFLOP/s by TPU generation (marketing peak).  THE table —
-#: bench.py and the train eval line both divide by it.
+#: bf16 peak TFLOP/s of one chip by TPU generation (Google Cloud TPU
+#: documentation; v5e: 197), matched as a substring of the lower-cased,
+#: space-stripped ``device_kind`` — the v5e reports "TPU v5 lite".  THE
+#: table: bench.py and the train eval line both divide by it.
 PEAK_BF16_TFLOPS: Tuple[Tuple[str, float], ...] = (
     ('v6', 918.0), ('v5p', 459.0), ('v5', 197.0), ('v4', 275.0),
 )
 
 
+class UnknownDeviceKindError(LookupError):
+    """An accelerator whose ``device_kind`` has no row in
+    :data:`PEAK_BF16_TFLOPS`."""
+
+
 def peak_flops(device=None) -> float:
     """Peak bf16 FLOP/s of one chip.  ``CXXNET_PEAK_TFLOPS`` overrides
     (how a CPU run or an untabulated part gets an honest denominator);
-    0.0 on CPU with no override — MFU is then unreported, never faked."""
+    0.0 on CPU with no override — MFU is then unreported, never faked.
+    An accelerator that is not in the table raises: a guessed
+    denominator would print a utilization nobody measured."""
     env = os.environ.get('CXXNET_PEAK_TFLOPS')
     if env:
         return float(env) * 1e12
     import jax
     if device is None:
-        devs = jax.devices()
-        if not devs:
-            return 0.0
-        device = devs[0]
+        device = jax.devices()[0]
     if device.platform == 'cpu':
         return 0.0
-    kind = getattr(device, 'device_kind', '').lower().replace(' ', '')
+    kind = device.device_kind.lower().replace(' ', '')
     for key, tflops in PEAK_BF16_TFLOPS:
         if key in kind:
             return tflops * 1e12
-    return 197e12                        # v5e-class default
+    raise UnknownDeviceKindError(
+        f'no peak-FLOPs row for device_kind {device.device_kind!r}; add it '
+        f'to PEAK_BF16_TFLOPS or set CXXNET_PEAK_TFLOPS')
 
 
 def mfu(flops_per_step: float, steps_per_sec: float,

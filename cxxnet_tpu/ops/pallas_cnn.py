@@ -48,7 +48,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 
 from .pallas_kernels import (_block_spec, _compiler_params, _interpret,
-                             pallas_mode, pltpu)
+                             pallas_mode)
 
 _DN = ('NHWC', 'HWIO', 'NHWC')
 
@@ -64,15 +64,14 @@ _FUSED_ATOL = 1e-5
 _TILE_PIXELS = 512
 
 
-def conv_use_fused(explicit=None, *, spmd_devices: int = 1) -> bool:
+def conv_use_fused(explicit=None) -> bool:
     """Whether eligible conv(+bias)+relu pairs take the fused Pallas
     block.  ``explicit`` is the ``fuse=`` net param: ``1``/``0`` force it
     on/off (``1`` engages even in interpret mode — that is the CPU
-    validation path), anything else (``'auto'``/None) defers to the
-    tri-state ``pallas_mode()`` gate.  ``auto`` engages only on a real
-    single-device TPU: under GSPMD a ``pallas_call`` is an opaque custom
-    call with no sharding rule (same scoping as ``lrn_auto_mode``), and
-    in interpret mode the kernel is a correctness tool, not a win."""
+    validation path), anything else (``'auto'``/None) defers to
+    ``pallas_mode()``, whose own ``auto`` never picks the block: Mosaic
+    refuses the kernel on the TPU (doc/kernels.md quotes the compiler),
+    so only a forced spelling reaches it, and there it fails loudly."""
     if explicit is not None:
         text = str(explicit).strip().lower()
         if text in ('1', 'true', 'yes', 'on'):
@@ -80,12 +79,7 @@ def conv_use_fused(explicit=None, *, spmd_devices: int = 1) -> bool:
         if text in ('0', 'false', 'no', 'off'):
             return False
         # anything else ('auto', '') falls through to the global gate
-    mode = pallas_mode()
-    if mode == 'on':
-        return True
-    if mode == 'off':
-        return False
-    return not _interpret() and pltpu is not None and spmd_devices == 1
+    return pallas_mode() == 'on'
 
 
 def _conv_ref(x, w, strides, pad, groups=1):

@@ -29,13 +29,8 @@ import math
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-try:                                    # jax >= 0.5 spelling
-    from jax import shard_map
-except ImportError:                     # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map
 
 
 def _local_attention(q, k, v, scale, mask=None):
@@ -97,11 +92,8 @@ def _ring_attention_local(q, k, v, axis_name: str, causal: bool):
     l0 = jnp.zeros((b, h, sq), jnp.float32)
     # constants start "unvarying" under shard_map's varying-manual-axes
     # tracking; mark them varying over the ring axis for the scan carry
-    try:
-        acc0, m0, l0 = (lax.pcast(x, (axis_name,), to='varying')
-                        for x in (acc0, m0, l0))
-    except (AttributeError, TypeError):   # older jax without vma tracking
-        pass
+    acc0, m0, l0 = (lax.pcast(x, (axis_name,), to='varying')
+                    for x in (acc0, m0, l0))
     _, _, acc, m, l = lax.fori_loop(0, n, body, (k, v, acc0, m0, l0))
     l = jnp.where(l == 0.0, 1.0, l)
     out = acc / l[..., None]
@@ -132,10 +124,9 @@ def _ulysses_local(q, k, v, axis_name: str, causal: bool,
     k = lax.all_to_all(k, axis_name, split_axis=2, concat_axis=1, tiled=True)
     v = lax.all_to_all(v, axis_name, split_axis=2, concat_axis=1, tiled=True)
     from ..ops import pallas_kernels as pk
-    if use_flash and pk.pltpu is not None:
+    if use_flash:
         # fused online-softmax kernel: O(seq) memory for the local dense
-        # attention after the head scatter (dense fallback when the TPU
-        # pallas memory spaces aren't importable)
+        # attention after the head scatter
         out = pk.flash_attention(q, k, v, causal=causal)
     else:
         scale = 1.0 / math.sqrt(q.shape[-1])
@@ -166,14 +157,8 @@ def ulysses_attention(q, k, v, mesh: Mesh, axis_name: str = 'data',
                               causal=causal, use_flash=use_flash)
     wrap = functools.partial(shard_map, local, mesh=mesh,
                              in_specs=(spec, spec, spec), out_specs=spec)
-    if not use_flash:
-        fn = wrap()
-    else:
-        # pallas_call doesn't propagate varying-manual-axes through its
-        # interpreter yet; jax's own error message prescribes disabling the
-        # replication check (check_rep on older jax spellings)
-        try:
-            fn = wrap(check_vma=False)
-        except TypeError:
-            fn = wrap(check_rep=False)
+    # pallas_call doesn't propagate varying-manual-axes through its
+    # interpreter yet; jax's own error message prescribes disabling the
+    # replication check
+    fn = wrap(check_vma=False) if use_flash else wrap()
     return fn(q, k, v)

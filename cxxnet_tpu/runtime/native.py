@@ -12,6 +12,8 @@ from __future__ import annotations
 
 import ctypes
 import os
+import subprocess
+import sys
 from typing import Iterator, Optional
 
 import numpy as np
@@ -35,10 +37,24 @@ def _load():
         return None
     path = _lib_path()
     if not os.path.exists(path):
-        # try building it once, quietly
+        # built on first use; a failed build is said once, with make's
+        # own error, because the Python path that takes over is slower
         makefile_dir = os.path.dirname(path)
         if os.path.exists(os.path.join(makefile_dir, 'Makefile')):
-            os.system(f'make -s -C {makefile_dir} >/dev/null 2>&1')
+            try:
+                r = subprocess.run(['make', '-s', '-C', makefile_dir],
+                                   capture_output=True, text=True)
+                err = None
+                if r.returncode:
+                    lines = (r.stderr or r.stdout).strip().splitlines()
+                    err = next((ln for ln in lines if 'error' in ln.lower()),
+                               f'make rc={r.returncode}')
+            except OSError as e:            # no make on this machine
+                err = str(e)
+            if err is not None:
+                print(f'cxxnet_tpu: building {path} failed ({err}); using '
+                      f'the pure-Python page reader and JPEG decoder',
+                      file=sys.stderr)
     if not os.path.exists(path):
         return None
     try:
@@ -47,11 +63,10 @@ def _load():
         return None
     lib.cxr_open.restype = ctypes.c_void_p
     lib.cxr_open.argtypes = [ctypes.c_char_p, ctypes.c_int]
-    if hasattr(lib, 'cxr_open_order'):      # older prebuilt .so lacks it
-        lib.cxr_open_order.restype = ctypes.c_void_p
-        lib.cxr_open_order.argtypes = [
-            ctypes.c_char_p, ctypes.POINTER(ctypes.c_int64), ctypes.c_int,
-            ctypes.c_int]
+    lib.cxr_open_order.restype = ctypes.c_void_p
+    lib.cxr_open_order.argtypes = [
+        ctypes.c_char_p, ctypes.POINTER(ctypes.c_int64), ctypes.c_int,
+        ctypes.c_int]
     lib.cxr_next_page.restype = ctypes.c_int
     lib.cxr_next_page.argtypes = [ctypes.c_void_p]
     lib.cxr_get_obj.restype = ctypes.c_void_p
@@ -70,11 +85,6 @@ def native_available() -> bool:
     return _load() is not None
 
 
-def native_order_available() -> bool:
-    lib = _load()
-    return lib is not None and hasattr(lib, 'cxr_open_order')
-
-
 class NativePageReader:
     """Iterates the blobs of a BinaryPage stream with C++-side prefetch.
 
@@ -88,9 +98,6 @@ class NativePageReader:
             raise RuntimeError('native runtime not available')
         self._lib = lib
         if order is not None:
-            if not hasattr(lib, 'cxr_open_order'):
-                raise RuntimeError('native runtime lacks cxr_open_order '
-                                   '(rebuild runtime/)')
             arr = np.ascontiguousarray(order, dtype=np.int64)
             self._h = lib.cxr_open_order(
                 path.encode(),

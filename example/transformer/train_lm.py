@@ -51,8 +51,6 @@ def main() -> int:
     n = args.pp * args.dp * args.sp * args.tp
 
     import jax
-    if os.environ.get('JAX_PLATFORMS', '') == 'cpu':
-        jax.config.update('jax_platforms', 'cpu')
     if len(jax.devices()) < n:
         # virtual CPU mesh for development machines
         from jax.extend import backend as jexb

@@ -122,15 +122,21 @@ def test_fused_relu_grad_matches_reference_at_exact_ties():
     assert float(jnp.abs(gf[2]).max()) > 0.0
 
 
-def test_conv_use_fused_gate_tristate():
+def test_conv_use_fused_gate_tristate(monkeypatch):
     """``fuse=1`` forces the block on (the CPU validation path),
-    ``fuse=0`` kills it, auto defers to ``pallas_mode()`` — which on a
-    cpu host (interpret mode) stays off, and under GSPMD stays off."""
+    ``fuse=0`` kills it, and auto never picks it, on any backend: Mosaic
+    refuses the kernel on the chip (doc/kernels.md), so only a forced
+    spelling (``fuse=1`` / ``use_pallas=1``) reaches it."""
+    from cxxnet_tpu.ops import pallas_kernels as pk
+    monkeypatch.delenv('CXXNET_PALLAS', raising=False)
     assert conv_use_fused('1') is True
     assert conv_use_fused('0') is False
-    assert conv_use_fused('auto') is False          # cpu = interpret mode
-    assert conv_use_fused('auto', spmd_devices=8) is False
+    monkeypatch.setattr(pk, '_interpret', lambda: False)   # "on a TPU"
+    assert conv_use_fused('auto') is False
     assert conv_use_fused(None) is False
+    monkeypatch.setenv('CXXNET_PALLAS', '1')
+    assert conv_use_fused('auto') is True
+    assert conv_use_fused('0') is False             # explicit key wins
 
 
 # --- net-level fusion pass -------------------------------------------------
@@ -411,41 +417,6 @@ def test_micro_batch_bounds_ledger_peak_bytes():
         peaks[split] = max(int(e.peak_bytes) for e in entries)
     assert peaks[4] <= peaks[1], peaks
     assert peaks[4] > 0
-
-
-# --- bench self-heal covers BENCH_CNN (satellite) --------------------------
-
-def test_self_heal_covers_cnn_fused_receipts(tmp_path, monkeypatch):
-    """A BENCH_CNN receipt stamped cpu-fallback is a heal candidate the
-    first time a real chip is up, and the healed rerun lands in THIS
-    script's receipt slot (receipts/bench_cnn_fused.json) — not in the
-    bench_serve namespace."""
-    import json as _json
-    import os
-    import sys
-    sys.path.insert(0, os.path.dirname(
-        os.path.dirname(os.path.abspath(__file__))))
-    import bench
-    monkeypatch.setenv('JAX_PLATFORMS', 'tpu,cpu')
-    monkeypatch.delenv('CXXNET_BENCH_NO_HEAL', raising=False)
-    stale = {'metric': 'cnn_fused_speedup', 'value': 1.1,
-             'platform': 'cpu-fallback'}
-    (tmp_path / 'BENCH_CNN_r01.json').write_text(_json.dumps(stale))
-    cands = bench.heal_candidates(str(tmp_path))
-    assert [(m, s) for _, m, s in cands] == \
-        [('cnn_fused_speedup', ('bench.py', 'cnn_fused'))]
-
-    healed = bench.self_heal_receipts(
-        str(tmp_path),
-        runner=lambda s, m: {'metric': 'cnn_fused_speedup', 'value': 1.4,
-                             'platform': 'tpu'})
-    assert len(healed) == 1
-    receipt = tmp_path / 'receipts' / 'bench_cnn_fused.json'
-    assert receipt.exists()
-    assert _json.loads(receipt.read_text())['heals'].endswith(
-        'BENCH_CNN_r01.json')
-    # the healed receipt supersedes the stale trajectory entry
-    assert bench.heal_candidates(str(tmp_path)) == []
 
 
 # --- doc drift (satellite 5) -----------------------------------------------

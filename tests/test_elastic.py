@@ -624,55 +624,6 @@ def test_real_jax_distributed_two_process_world():
     assert 'of 2' in outs[0][0] and 'of 2' in outs[1][0]
 
 
-# --- bench self-healing receipts (satellite) -------------------------------
-
-
-def test_bench_self_heal_receipts(tmp_path, monkeypatch):
-    sys.path.insert(0, REPO)
-    import bench
-    monkeypatch.setenv('JAX_PLATFORMS', 'tpu,cpu')
-    monkeypatch.delenv('CXXNET_BENCH_NO_HEAL', raising=False)
-    stale = {'metric': 'decode_int8_resident_reduction', 'value': 3.2,
-             'platform': 'cpu-fallback'}
-    (tmp_path / 'BENCH_SERVE_r03.json').write_text(
-        __import__('json').dumps(stale))
-    cands = bench.heal_candidates(str(tmp_path))
-    assert [(m, s[1]) for _, m, s in cands] == \
-        [('decode_int8_resident_reduction', 'decode_matrix')]
-
-    ran = []
-
-    def fake_runner(script, mode):
-        ran.append((script, mode))
-        return {'metric': 'decode_int8_resident_reduction', 'value': 9.9,
-                'platform': 'tpu'}
-
-    healed = bench.self_heal_receipts(str(tmp_path), runner=fake_runner)
-    assert ran == [('bench_serve.py', 'decode_matrix')]
-    assert len(healed) == 1
-    receipt = tmp_path / 'receipts' / 'bench_serve_decode_matrix.json'
-    assert receipt.exists()
-    # the healed receipt supersedes the stale ledger entry: nothing
-    # left to heal
-    assert bench.heal_candidates(str(tmp_path)) == []
-
-    # a rerun that silently landed back on CPU must NOT count as healed
-    (tmp_path / 'receipts' / 'bench_serve_decode_matrix.json').unlink()
-    healed = bench.self_heal_receipts(
-        str(tmp_path),
-        runner=lambda s, m: {'value': 1.0, 'platform': 'cpu-fallback'})
-    assert healed == []
-
-    # explicit CPU-only runs never try to heal
-    monkeypatch.setenv('JAX_PLATFORMS', 'cpu')
-    assert bench.self_heal_receipts(str(tmp_path),
-                                    runner=fake_runner) == []
-    monkeypatch.setenv('JAX_PLATFORMS', 'tpu,cpu')
-    monkeypatch.setenv('CXXNET_BENCH_NO_HEAL', '1')
-    assert bench.self_heal_receipts(str(tmp_path),
-                                    runner=fake_runner) == []
-
-
 # --- lint surface ----------------------------------------------------------
 
 

@@ -270,7 +270,6 @@ def small_pages(monkeypatch):
     monkeypatch.setattr(BinaryPage, 'N_BYTES', 512 * 4)
     from cxxnet_tpu.runtime import native
     monkeypatch.setattr(native, 'native_available', lambda: False)
-    monkeypatch.setattr(native, 'native_order_available', lambda: False)
 
 
 def test_imgbinx_matches_imgbin_when_unshuffled(tmp_path, small_pages):

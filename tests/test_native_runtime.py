@@ -82,9 +82,6 @@ def test_imgbin_iterator_uses_native_jpeg(tmp_path):
 def test_native_ordered_page_reader(tmp_path):
     """cxr_open_order reads pages by index with seeks — arbitrary order,
     repeats included (the imgbinx shuffled-epoch access pattern)."""
-    from cxxnet_tpu.runtime.native import native_order_available
-    if not native_order_available():
-        pytest.skip('runtime .so predates cxr_open_order')
     pages = [[b'page0-a', b'page0-b'], [b'page1-a'], [b'page2-a', b'x' * 999]]
     path = make_bin(tmp_path, pages)
     order = [2, 0, 1, 0]
@@ -95,9 +92,6 @@ def test_native_ordered_page_reader(tmp_path):
 
 
 def test_native_ordered_reader_edge_cases(tmp_path):
-    from cxxnet_tpu.runtime.native import native_order_available
-    if not native_order_available():
-        pytest.skip('runtime .so predates cxr_open_order')
     pages = [[b'p0'], [b'p1']]
     path = make_bin(tmp_path, pages)
     # empty order reads NOTHING (sharded worker owning no pages)

@@ -242,9 +242,8 @@ def test_attn_use_flash_gate(monkeypatch):
     monkeypatch.setattr(pk, '_interpret', lambda: True)
     assert not pk.attn_use_flash(16384, batch=2, heads=8)
     monkeypatch.setattr(pk, '_interpret', lambda: False)
-    if pk.pltpu is not None:
-        assert pk.attn_use_flash(16384, batch=2, heads=8)    # ~17 GB
-        assert pk.attn_use_flash(4096, batch=64, heads=16)   # big b*h
+    assert pk.attn_use_flash(16384, batch=2, heads=8)    # ~17 GB
+    assert pk.attn_use_flash(4096, batch=64, heads=16)   # big b*h
     assert not pk.attn_use_flash(4096, batch=2, heads=8)     # ~1 GB
     assert not pk.attn_use_flash(16384)                      # b1 h1: fits
     monkeypatch.setenv('CXXNET_PALLAS', '1')
@@ -271,7 +270,7 @@ def test_lrn_auto_gate_scoped_to_single_device(monkeypatch):
 
 def test_matmul_wide_n_preset_numerics():
     """The measured-winning fc6 tile preset (MATMUL_TILES_WIDE_N,
-    receipts/micro_matmul_tiles.log) must be numerically identical to the
+    BASELINE.md kernel table) must be numerically identical to the
     default tiling — it is a pure schedule change."""
     from cxxnet_tpu.ops import pallas_kernels as pk
     rng = np.random.RandomState(6)

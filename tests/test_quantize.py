@@ -135,8 +135,6 @@ def test_qdot_quantized_pallas_mode_invariant(monkeypatch):
     """serve.dtype=int8 outputs are a pure function of the int8 weights:
     identical with CXXNET_PALLAS unset (XLA int8 dot) and =1 (Pallas
     kernel, interpret on CPU)."""
-    if PK.pltpu is None:
-        pytest.skip('pallas TPU memory spaces unavailable')
     rng = np.random.RandomState(4)
     x = jnp.asarray(rng.randn(6, 32), jnp.bfloat16)
     w = Q.quantize_leaf(rng.randn(32, 24).astype(np.float32),

@@ -12,13 +12,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-try:
-    from jax import shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map
 
 from cxxnet_tpu.models import transformer as tfm
 from cxxnet_tpu.parallel.moe import moe_ffn_local, moe_ffn_reference

@@ -14,9 +14,8 @@ on, so the guard enforces the rules the bench modes promise
   writer follows since PR 8).  A receipt that fails to parse strictly
   fails the guard.
 * **platform stamp** — a measured payload (``value`` not null) must
-  say what it was measured ON (``"platform"``: ``tpu`` /
-  ``cpu-fallback`` / ...), or a host number could pass as a per-chip
-  one.  Receipts committed before the stamp rule are grandfathered in
+  say what it was measured ON (``"platform"``: ``tpu`` / ``cpu`` /
+  ``host``), or a host number could pass as a per-chip one.  Receipts committed before the stamp rule are grandfathered in
   ``LEGACY_NO_PLATFORM`` — a shrink-only list: entries may be removed
   as old rounds are re-measured, never added.
 * **regression flags** — within a receipt family (``BENCH_SERVE_r03``
@@ -64,8 +63,6 @@ from typing import Dict, List, Optional, Tuple
 #: shrink-only: remove entries as rounds are re-measured, NEVER add
 LEGACY_NO_PLATFORM = frozenset({
     'BENCH_IO_r01.json',       # PR 5 host-only io sweep (no device leg)
-    'BENCH_r02.json',          # pre-rule driver envelopes
-    'BENCH_r03.json',
 })
 
 _ROUND_RE = re.compile(r'^(.*)_r(\d+)\.json$')
@@ -466,7 +463,7 @@ def check_file(path: str) -> Tuple[List[str], List[dict]]:
         if 'platform' not in p and name not in LEGACY_NO_PLATFORM:
             errs.append(
                 f'{name}: measured payload {p.get("metric")!r} carries '
-                'no "platform" stamp (tpu / cpu-fallback / ...)')
+                'no "platform" stamp (tpu / cpu / host)')
         if p.get('metric') == SCENARIO_METRIC:
             s_errs, synth = expand_scenarios(p, name)
             errs.extend(s_errs)

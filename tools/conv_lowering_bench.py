@@ -30,6 +30,7 @@ import jax.numpy as jnp  # noqa: E402
 # so it must measure the code that policy gates, not a copy
 from cxxnet_tpu.layers.conv import (conv_im2col, conv_native,  # noqa: E402
                                     conv_s2d, conv_split)
+from cxxnet_tpu.utils.backend import enable_compile_cache  # noqa: E402
 
 # (name, batch, in_y/x, cin, cout, kernel, stride, pad, ngroup)
 SHAPES = [
@@ -59,6 +60,7 @@ def flops(b, y, cin, cout, k, stride, pad, g):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument('--json', default=None)
     ap.add_argument('--only', default=None, help='substring filter on name')
@@ -103,7 +105,7 @@ def main():
                 # atomic replace so a mid-write kill can't leave a
                 # truncated (non-empty but unparseable) receipt.  The
                 # 'partial' flag comes off only in the final dump below,
-                # so an idempotent relaunch (run_chip_pending.sh) re-runs
+                # so a relaunch that skips finished receipts re-runs
                 # an interrupted sweep instead of skipping it forever.
                 if args.json:
                     _dump_json(args.json, dev, results, partial=True)

@@ -29,20 +29,17 @@ import sys
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
-os.environ.setdefault(
-    'JAX_COMPILATION_CACHE_DIR',
-    os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-                 '.jax_cache'))
-os.environ.setdefault('JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS', '2')
-
 from chiptime import atomic_receipt_dump, time_op              # noqa: E402
 
 import jax                                                     # noqa: E402
 import jax.numpy as jnp                                        # noqa: E402
 import numpy as np                                             # noqa: E402
 
+from cxxnet_tpu.utils.backend import enable_compile_cache    # noqa: E402
+
 
 def main() -> int:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument('--json', default=None)
     ap.add_argument('--seq', type=int, default=32768)
