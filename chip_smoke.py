@@ -229,9 +229,9 @@ def main() -> int:
     os.environ.pop('CXXNET_PEAK_TFLOPS', None)
     import jax
 
-    from cxxnet_tpu.utils.backend import enable_compile_cache
+    from cxxnet_tpu.utils.backend import enable_compile_cache, meet_backend
     cache_dir = enable_compile_cache()
-    backend = jax.default_backend()
+    backend = meet_backend()
     if backend != 'tpu':
         fail(f'JAX backend is {backend!r} ({jax.devices()[0]}), not a TPU')
     from cxxnet_tpu.obs.programs import peak_flops

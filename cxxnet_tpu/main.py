@@ -23,6 +23,7 @@ from typing import List, Optional, Tuple
 from .io.data import create_iterator
 from .nnet import checkpoint as model_io
 from .nnet.trainer import NetTrainer
+from .obs import span
 from .utils.backend import enable_compile_cache
 from .utils.config import apply_cli_overrides, parse_config_file
 from .utils.profiler import TraceWindow
@@ -35,6 +36,19 @@ _TASKS = {'train': 'task_train', 'finetune': 'task_train',
           'pred': 'task_predict', 'pred_raw': 'task_predict_raw',
           'extract': 'task_extract', 'serve': 'task_serve',
           'online': 'task_online', 'autotune': 'task_autotune'}
+
+
+def _spanned_batches(batches):
+    """``batches``, with each wait for the next one inside an ``io.next``
+    span: what the step loop pays the input chain, serial or pooled (the
+    chain's own counters exist only with ``nworker``)."""
+    it = iter(batches)
+    while True:
+        with span('io.next', 'io'):
+            batch = next(it, None)
+        if batch is None:
+            return
+        yield batch
 
 
 class LearnTask:
@@ -576,7 +590,7 @@ class LearnTask:
             return
         if self.test_io:
             print('start I/O test')
-        tracer = TraceWindow()
+        tracer = TraceWindow(hlo_text=self.net_trainer.step_program_text)
         tracer.configure(self.cfg)
         batch_counter = 0
         try:
@@ -648,7 +662,7 @@ class LearnTask:
         it = self._sup_iter
 
         def factory(k):
-            return itertools.islice(iter(it), k, None)
+            return _spanned_batches(itertools.islice(iter(it), k, None))
 
         def before_step(i):
             # same progress/trace cadence as the unsupervised loop
@@ -701,7 +715,7 @@ class LearnTask:
             before_dispatch=lambda u: tracer.before_update(
                 batch_counter + u))
         sample_counter = 0
-        for batch in self.itr_train:
+        for batch in _spanned_batches(self.itr_train):
             if self.test_io == 0:
                 stepper.feed(batch)
             sample_counter += 1
@@ -786,7 +800,7 @@ class LearnTask:
         flops = self.net_trainer.train_step_flops()
         if flops > 0:
             st.gauge('flops_per_step', flops)
-        m = mfu(flops, sps)
+        m = mfu(flops, sps, devices=self.net_trainer._mesh.devices.size)
         if m is not None:
             st.gauge('mfu', round(m, 5))
         sys.stderr.write(st.print('train').lstrip('\t') + '\n')

@@ -15,6 +15,7 @@ Layout: activations are NHWC; the input node accepts NCHW host batches
 
 from __future__ import annotations
 
+import re
 from typing import Dict, List, Optional
 
 import sys
@@ -83,10 +84,24 @@ class Net:
         for i, info in enumerate(cfg.layers):
             if isinstance(self.layers[i], SplitLayer):
                 self.layers[i].set_num_outputs(len(info.nindex_out))
+        self.layer_scopes = self._layer_scopes()
         self._infer_shapes()
         self._build_sibling_fusion()
         self._build_blockdiag_fusion()
         self._build_convact_fusion()
+
+    def _layer_scopes(self) -> List[str]:
+        """One ``jax.named_scope`` name per conf layer, from the conf
+        alone: index, type and the conf's layer name where it gives one
+        (``l03_lrn``, ``l00_conv_c1``).  Unique by the index, the same in
+        every run, and free of ``/ ( )``, which the profiler's ``op_name``
+        paths (``jit(f)/jvp(l03_lrn)/...``) use as separators
+        (utils/profiler.device_time_by_scope reads them back)."""
+        width = max(2, len(str(len(self.cfg.layers) - 1)))
+        return [re.sub(r'[^A-Za-z0-9_]', '_', '_'.join(
+            [f'l{i:0{width}d}', self.layers[i].type_name]
+            + ([info.name] if info.name else [])))
+            for i, info in enumerate(self.cfg.layers)]
 
     # --- horizontal fusion ------------------------------------------------
     def _build_sibling_fusion(self) -> None:
@@ -688,7 +703,9 @@ class Net:
         """
         cfg = self.cfg
         values: List[Optional[jax.Array]] = [None] * cfg.num_nodes
-        values[0] = self._input_to_device_layout(batch, ctx.compute_dtype)
+        with jax.named_scope('input'):
+            values[0] = self._input_to_device_layout(batch,
+                                                     ctx.compute_dtype)
         if cfg.extra_data_num:
             if extra_data is None or len(extra_data) < cfg.extra_data_num:
                 raise ValueError(
@@ -723,35 +740,36 @@ class Net:
             ins = [values[j] for j in info.nindex_in]
             if capture is not None and i in capture:
                 capture[i] = ins
-            if isinstance(layer, LossLayerBase) and labels is not None:
-                total_loss = total_loss + layer.loss(
-                    lp, ins, labels.field(layer.target), lctx, loss_mask)
-            if i in identity_layers:
-                outs = [ins[0]]
-            elif i in fused_act:
-                outs = [ins[0]]   # activation already applied in the conv
-            elif use_fused and i in self._convact_pairs:
-                outs = self._fused_convact_outputs(lp, ins[0], i, 'relu')
-                fused_act.add(self._convact_pairs[i])
-            elif use_fused and i in self._convact_solo:
-                outs = self._fused_convact_outputs(lp, ins[0], i,
-                                                   'identity')
-            elif i in self._sibling_groups:
-                if i not in fused:   # first member: run the fused conv
-                    members = self._sibling_groups[i]
-                    for m, v in zip(members, self._fused_sibling_outputs(
-                            params, ins[0], members)):
-                        fused[m] = v
-                outs = [fused[i]]
-            elif i in self._blockdiag_groups:
-                if i not in fused_bd:   # first member in exec order
-                    members = self._blockdiag_groups[i]
-                    for m, v in zip(members, self._fused_blockdiag_outputs(
-                            params, values, members)):
-                        fused_bd[m] = v
-                outs = [fused_bd[i]]
-            else:
-                outs = layer.forward(lp, ins, lctx)
+            with jax.named_scope(self.layer_scopes[i]):
+                if isinstance(layer, LossLayerBase) and labels is not None:
+                    total_loss = total_loss + layer.loss(
+                        lp, ins, labels.field(layer.target), lctx, loss_mask)
+                if i in identity_layers:
+                    outs = [ins[0]]
+                elif i in fused_act:
+                    outs = [ins[0]]   # activation already applied in the conv
+                elif use_fused and i in self._convact_pairs:
+                    outs = self._fused_convact_outputs(lp, ins[0], i, 'relu')
+                    fused_act.add(self._convact_pairs[i])
+                elif use_fused and i in self._convact_solo:
+                    outs = self._fused_convact_outputs(lp, ins[0], i,
+                                                       'identity')
+                elif i in self._sibling_groups:
+                    if i not in fused:   # first member: run the fused conv
+                        members = self._sibling_groups[i]
+                        for m, v in zip(members, self._fused_sibling_outputs(
+                                params, ins[0], members)):
+                            fused[m] = v
+                    outs = [fused[i]]
+                elif i in self._blockdiag_groups:
+                    if i not in fused_bd:   # first member in exec order
+                        members = self._blockdiag_groups[i]
+                        for m, v in zip(members, self._fused_blockdiag_outputs(
+                                params, values, members)):
+                            fused_bd[m] = v
+                    outs = [fused_bd[i]]
+                else:
+                    outs = layer.forward(lp, ins, lctx)
             for j, v in zip(info.nindex_out, outs):
                 values[j] = v
         return values, total_loss

@@ -35,10 +35,11 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..layers import ForwardContext
+from ..obs import span
 from ..parallel.mesh import (batch_sharding, build_mesh, param_shardings,
                              replicated_sharding)
 from ..updater import (apply_updates, create_updater_hyper, init_opt_state)
-from ..utils.backend import cpu_pinned
+from ..utils.backend import cpu_pinned, meet_backend
 from ..utils.metric import MetricSet
 from . import checkpoint
 from .net import Net
@@ -128,6 +129,8 @@ class NetTrainer:
         self.nan_breaker = 0       # consecutive non-finite losses -> raise
         self.nan_streak = 0        # current consecutive non-finite count
         self._pending_loss = None  # (step, device loss) deferred one step
+        self._loss_listeners: List = []   # add_loss_listener
+        self._step_avals = None    # the first dispatched step's arguments
         self.compute_dtype = jnp.float32
         self.dev = ''              # the dev= value; '' = default device
         self.metric = MetricSet()
@@ -230,6 +233,7 @@ class NetTrainer:
         # there (the per-worker view, matching the reference's
         # one-worker-per-host deployment); gradients cross hosts at the
         # elastic/ps layer, not through the mesh
+        meet_backend()     # the CLI's first touch of the backend, spanned
         all_devs = (jax.local_devices() if jax.process_count() > 1
                     else jax.devices())
         devs = select_devices(self.dev, all_devs)
@@ -265,9 +269,14 @@ class NetTrainer:
         self._compile_steps()
 
     def init_model(self) -> None:
-        self.init_net()
-        self.params = self.net.init_params(jax.random.fold_in(self._rng, 0xC0FFEE))
-        self._post_params_init()
+        with span('net.init_model', 'train') as sp:
+            self.init_net()
+            self.params = self.net.init_params(
+                jax.random.fold_in(self._rng, 0xC0FFEE))
+            self._post_params_init()
+            leaves = jax.tree.leaves(self.params)
+            sp.attrs.update(leaves=len(leaves),
+                            bytes=sum(int(x.nbytes) for x in leaves))
 
     def _post_params_init(self) -> None:
         shardings = param_shardings(self.net, self.params, self._mesh)
@@ -370,21 +379,28 @@ class NetTrainer:
             (loss, evals), grads = jax.value_and_grad(
                 loss_fn, has_aux=True)(params, data, label, extra, mask,
                                        rng, rnd, norm)
+            # the scopes below and Net.forward's one per conf layer are
+            # what utils/profiler.device_time_by_scope reads back
             if nan_skip:
                 # failure detection beyond the reference's NaN-zeroing clip
                 # (sgd_updater-inl.hpp:15-22): a non-finite loss — or a
                 # finite loss whose backward overflowed (0*inf etc.) —
                 # poisons the weights; drop this batch's contribution
-                ok = jnp.isfinite(loss)
-                for g in jax.tree.leaves(grads):
-                    ok &= jnp.all(jnp.isfinite(g))
-                grads = jax.tree.map(
-                    lambda g: jnp.where(ok, g, jnp.zeros_like(g)), grads)
-            grad_acc = jax.tree.map(jnp.add, grad_acc, grads)
+                with jax.named_scope('nan_gate'):
+                    ok = jnp.isfinite(loss)
+                    for g in jax.tree.leaves(grads):
+                        ok &= jnp.all(jnp.isfinite(g))
+                    grads = jax.tree.map(
+                        lambda g: jnp.where(ok, g, jnp.zeros_like(g)),
+                        grads)
+            with jax.named_scope('grad_acc'):
+                grad_acc = jax.tree.map(jnp.add, grad_acc, grads)
             if do_update:
-                params, opt_state = apply_updates(
-                    updater_type, hypers, params, grad_acc, opt_state, epoch)
-                grad_acc = jax.tree.map(jnp.zeros_like, grad_acc)
+                with jax.named_scope('update'):
+                    params, opt_state = apply_updates(
+                        updater_type, hypers, params, grad_acc, opt_state,
+                        epoch)
+                    grad_acc = jax.tree.map(jnp.zeros_like, grad_acc)
             return params, opt_state, grad_acc, loss, evals
 
         net = self.net
@@ -479,21 +495,25 @@ class NetTrainer:
                     loss_fn, has_aux=True)(params, data, label, (), mask,
                                            rng, rnd, norm)
                 if nan_skip:
-                    ok = jnp.isfinite(loss)
-                    for g in jax.tree.leaves(grads):
-                        ok &= jnp.all(jnp.isfinite(g))
-                    grads = jax.tree.map(
-                        lambda g: jnp.where(ok, g, jnp.zeros_like(g)), grads)
+                    with jax.named_scope('nan_gate'):
+                        ok = jnp.isfinite(loss)
+                        for g in jax.tree.leaves(grads):
+                            ok &= jnp.all(jnp.isfinite(g))
+                        grads = jax.tree.map(
+                            lambda g: jnp.where(ok, g, jnp.zeros_like(g)),
+                            grads)
                 # accumulate-then-apply, exactly as the per-step path: the
                 # 0+g add is kept even at P=1 so the float ops match
                 # bitwise (the per-step train_step always adds into the
                 # zeroed accumulator before applying)
-                grad_acc = jax.tree.map(jnp.add, grad_acc, grads)
+                with jax.named_scope('grad_acc'):
+                    grad_acc = jax.tree.map(jnp.add, grad_acc, grads)
                 if period == 1:
-                    params, opt_state = apply_updates(
-                        updater_type, hypers, params, grad_acc, opt_state,
-                        epoch)
-                    grad_acc = jax.tree.map(jnp.zeros_like, grad_acc)
+                    with jax.named_scope('update'):
+                        params, opt_state = apply_updates(
+                            updater_type, hypers, params, grad_acc,
+                            opt_state, epoch)
+                        grad_acc = jax.tree.map(jnp.zeros_like, grad_acc)
                     epoch = epoch + 1
                 else:
                     def _apply(args):
@@ -502,10 +522,11 @@ class NetTrainer:
                                              e)
                         return p, o, jax.tree.map(jnp.zeros_like, g), e + 1
 
-                    params, opt_state, grad_acc, epoch = jax.lax.cond(
-                        (sc0 + t + 1) % period == 0, _apply,
-                        lambda args: args,
-                        (params, opt_state, grad_acc, epoch))
+                    with jax.named_scope('update'):
+                        params, opt_state, grad_acc, epoch = jax.lax.cond(
+                            (sc0 + t + 1) % period == 0, _apply,
+                            lambda args: args,
+                            (params, opt_state, grad_acc, epoch))
                 ys = (loss, tuple(evals) if train_eval else ())
                 return (params, opt_state, grad_acc, epoch), ys
 
@@ -665,10 +686,12 @@ class NetTrainer:
         sc0 = self.sample_counter
         old_pending = self._pending_train_eval
         self._pending_train_eval = None
-        (self.params, self.opt_state, self.grad_acc, losses, evals) = \
-            multi_fn(self.params, self.opt_state, self.grad_acc, data_stack,
-                     label_stack, self._rng, self.epoch_counter, sc0,
-                     mask_stack, self.round, norm)
+        with span('train.launch', 'train', k=n_steps, update=sc0):
+            (self.params, self.opt_state, self.grad_acc, losses, evals) = \
+                multi_fn(self.params, self.opt_state, self.grad_acc,
+                         data_stack, label_stack, self._rng,
+                         self.epoch_counter, sc0, mask_stack, self.round,
+                         norm)
         # the accumulation cadence BAKED INTO the compiled body, not the
         # live config — a multi_fn compiled before an update_period tweak
         # applies the optimizer on its compile-time cadence, and the host
@@ -693,6 +716,9 @@ class NetTrainer:
         if old_pending is not None:
             self._drain_train_eval(old_pending)
         self._gate_losses(losses, sc0)
+        for listener in self._loss_listeners:
+            for t in range(n_steps):
+                listener(losses[t])
         return losses[-1]
 
     def _gate_losses(self, losses, sc0: int) -> None:
@@ -827,28 +853,35 @@ class NetTrainer:
         :meth:`update_staged`).  Returns an opaque handle for
         :meth:`update_staged`.  Safe because the batch adapters allocate
         fresh arrays per batch (io/iter_batch.py)."""
-        norm = self._norm_args(batch)
-        # raw (uncentered) pixels must not be pre-cast to bf16: values
-        # ~128 lose ~0.4% relative each, which mean-subtraction amplifies
-        # ~100x.  uint8 ships as-is; raw f32 (affine path) ships f32 and
-        # is centered on device before any compute-dtype cast.
-        data = self._shard_batch(batch.data, cast=not norm)
-        label = self._shard_batch(batch.label, cast=False)
-        extra = tuple(self._shard_batch(e) for e in batch.extra_data)
-        # synthetic pad rows of a short tail batch (round_batch=0) carry
-        # zero loss-mask so they contribute nothing to grads; real rows —
-        # including round_batch=1 wrapped instances, which the reference
-        # trains on (nnet_impl:141-170) — keep the reference's per-instance
-        # 1/batch_size weight
-        bs = batch.batch_size
-        if batch.num_batch_padd and getattr(batch, 'pad_synthetic', False):
-            mask = np.ones(bs, np.float32)
-            mask[bs - batch.num_batch_padd:] = 0.0
-            mask = self._shard_batch(mask, cast=False)
-        else:
-            mask = self._ones_mask(bs)
-        host_label = (np.asarray(batch.label)
-                      if self.eval_train and len(self.train_metric) else None)
+        with span('train.stage', 'train', rows=batch.batch_size) as sp:
+            norm = self._norm_args(batch)
+            # raw (uncentered) pixels must not be pre-cast to bf16: values
+            # ~128 lose ~0.4% relative each, which mean-subtraction
+            # amplifies ~100x.  uint8 ships as-is; raw f32 (affine path)
+            # ships f32 and is centered on device before any compute-dtype
+            # cast.
+            data = self._shard_batch(batch.data, cast=not norm)
+            label = self._shard_batch(batch.label, cast=False)
+            extra = tuple(self._shard_batch(e) for e in batch.extra_data)
+            # synthetic pad rows of a short tail batch (round_batch=0) carry
+            # zero loss-mask so they contribute nothing to grads; real rows
+            # — including round_batch=1 wrapped instances, which the
+            # reference trains on (nnet_impl:141-170) — keep the reference's
+            # per-instance 1/batch_size weight
+            bs = batch.batch_size
+            if batch.num_batch_padd \
+                    and getattr(batch, 'pad_synthetic', False):
+                mask = np.ones(bs, np.float32)
+                mask[bs - batch.num_batch_padd:] = 0.0
+                mask = self._shard_batch(mask, cast=False)
+            else:
+                mask = self._ones_mask(bs)
+            host_label = (
+                np.asarray(batch.label)
+                if self.eval_train and len(self.train_metric) else None)
+            sp.attrs.update(
+                bytes=int(data.nbytes) + int(label.nbytes),
+                cast=bool(data.dtype != getattr(batch.data, 'dtype', None)))
         return (data, label, extra, mask, host_label, bs,
                 batch.num_batch_padd, norm)
 
@@ -871,12 +904,20 @@ class NetTrainer:
                                  self.round)
         old_pending = self._pending_train_eval
         self._pending_train_eval = None
-        (self.params, self.opt_state, self.grad_acc, loss, evals) = \
-            self._train_step_fn(self.params, self.opt_state, self.grad_acc,
-                                data, label, extra, mask, rng,
-                                self.epoch_counter, self.round,
-                                do_update=do_update, norm=norm)
+        if self._step_avals is None:
+            self._step_avals = (jax.tree.map(
+                lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                               sharding=x.sharding),
+                (data, label, extra, mask, norm)), do_update)
+        with span('train.launch', 'train', k=1, update=self.sample_counter):
+            (self.params, self.opt_state, self.grad_acc, loss, evals) = \
+                self._train_step_fn(self.params, self.opt_state,
+                                    self.grad_acc, data, label, extra, mask,
+                                    rng, self.epoch_counter, self.round,
+                                    do_update=do_update, norm=norm)
         self._observe_loss(loss)
+        for listener in self._loss_listeners:
+            listener(loss)
         if host_label is not None:
             # defer this step's metric readback one step: by the next
             # update() (or evaluate()) the values are already on host, so
@@ -892,6 +933,30 @@ class NetTrainer:
         if do_update:
             self.epoch_counter += 1
         self.sample_counter += 1
+
+    def step_program_text(self) -> str:
+        """Compiled text of the per-step program as :meth:`update_staged`
+        first dispatched it (shapes and shardings of that call's batch,
+        the live parameters'), ``''`` before any step.  Its
+        ``metadata={op_name=...}`` is where a profiler trace's events get
+        their conf layer from (``utils/profiler.device_time_by_scope``).
+        Compiles, or reads the persistent cache: not for the step loop."""
+        if self._step_avals is None:
+            return ''
+        (data, label, extra, mask, norm), do_update = self._step_avals
+        return self._train_step_fn.compiled_text(
+            self.params, self.opt_state, self.grad_acc, data, label, extra,
+            mask, jax.random.fold_in(self._rng, 0), self.epoch_counter,
+            self.round, do_update=do_update, norm=norm)
+
+    def add_loss_listener(self, listener) -> None:
+        """Call ``listener(loss)`` with every dispatched training step's
+        loss, still a device scalar (nothing is fetched here: a listener
+        that converts it waits for the step): after each
+        :meth:`update_staged`, and once per step of a scanned window, in
+        step order.  For harnesses and monitors; the trainer's own gate
+        is :meth:`_observe_loss`."""
+        self._loss_listeners.append(listener)
 
     def _observe_loss(self, loss) -> None:
         """Host-side divergence gate over the step's loss.
@@ -977,25 +1042,36 @@ class NetTrainer:
         return cached
 
     def _drain_train_eval(self, pending) -> None:
+        # two spans a drain: the wait for the device and the copy to the
+        # host (train.eval_fetch), then the metrics' numpy
+        # (train.eval_score)
         if isinstance(pending, dict):
             # a scanned window's stacked eval outputs: ONE readback, then
             # the per-step host math in step order — bitwise the same
             # metric accumulation as K per-step drains
-            losses = np.asarray(pending['losses'])
-            evals = [np.asarray(e) for e in pending['evals']]
-            for t, (info, n) in enumerate(zip(pending['infos'],
-                                              pending['ns'])):
-                if self.nan_action == 'skip' and not np.isfinite(losses[t]):
-                    continue
-                self.train_metric.add_eval([e[t][:n] for e in evals],
-                                           info.slice(n))
+            rows = int(sum(pending['ns']))
+            with span('train.eval_fetch', 'train', rows=rows):
+                losses = np.asarray(pending['losses'])
+                evals = [np.asarray(e) for e in pending['evals']]
+            with span('train.eval_score', 'train', rows=rows):
+                for t, (info, n) in enumerate(zip(pending['infos'],
+                                                  pending['ns'])):
+                    if self.nan_action == 'skip' \
+                            and not np.isfinite(losses[t]):
+                        continue
+                    self.train_metric.add_eval([e[t][:n] for e in evals],
+                                               info.slice(n))
             return
         loss, evals, label_info, n = pending
-        if self.nan_action == 'skip' and not np.isfinite(float(loss)):
-            return  # poisoned batch: its NaN outputs would wreck the
-                    # round's train metrics along with the weights
-        self.train_metric.add_eval(
-            [np.asarray(e)[:n] for e in evals], label_info.slice(n))
+        with span('train.eval_fetch', 'train', rows=n):
+            # a poisoned batch's NaN outputs would wreck the round's train
+            # metrics along with the weights
+            ok = self.nan_action != 'skip' or np.isfinite(float(loss))
+            evals = [np.asarray(e)[:n] for e in evals] if ok else None
+        if not ok:
+            return
+        with span('train.eval_score', 'train', rows=n):
+            self.train_metric.add_eval(evals, label_info.slice(n))
 
     def update_on_device(self, data, label, norm=()) -> None:
         """One training step over batches already resident on device (jax

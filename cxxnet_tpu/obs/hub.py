@@ -90,15 +90,25 @@ def format_report_parts(prefix: str, counters: dict, samples: dict) -> str:
 
 # --- spans ------------------------------------------------------------------
 
+#: what a hub span is called in a profiler trace: ``cxxnet.train.launch``
+_TRACE_PREFIX = 'cxxnet.'
+
+
 class _Span:
     """One live span (context-manager form).  ``attrs`` may be mutated
     inside the ``with`` block; the record is written at exit (errors
     stamp ``attrs['error']`` with the exception type).  A disabled hub
     is honored at ENTER time, so the decorator form — which re-enters a
-    fresh span per call — respects ``hub.enabled`` flips either way."""
+    fresh span per call — respects ``hub.enabled`` flips either way.
+
+    The span is also a ``jax.profiler.TraceAnnotation`` named
+    ``cxxnet.<name>``: while any profiler trace is being taken
+    (``profile_dir=``, ``/profile?ms=N``, a benchmark's) it is an event on
+    the host plane of that trace, on the device events' clock; with no
+    trace live the annotation is a flag test."""
 
     __slots__ = ('_hub', 'name', 'subsystem', 'trace_id', 'attrs', '_t0',
-                 '_off')
+                 '_off', '_annotation')
 
     def __init__(self, hub: 'TelemetryHub', name: str, subsystem: str,
                  trace_id: Optional[str], attrs: dict):
@@ -109,6 +119,7 @@ class _Span:
         self.attrs = attrs
         self._t0 = 0
         self._off = False
+        self._annotation = None
 
     def __enter__(self):
         h = self._hub
@@ -119,6 +130,9 @@ class _Span:
         if self.trace_id is None and stack:
             self.trace_id = stack[-1][0]     # inherit the enclosing span's
         stack.append((self.trace_id, self.name))
+        from jax.profiler import TraceAnnotation
+        self._annotation = TraceAnnotation(_TRACE_PREFIX + self.name)
+        self._annotation.__enter__()
         self._t0 = time.monotonic_ns()
         return self
 
@@ -126,6 +140,7 @@ class _Span:
         if self._off:
             return False
         dur = time.monotonic_ns() - self._t0
+        self._annotation.__exit__(et, ev, tb)
         h = self._hub
         stack = h._span_stack()
         if stack:

@@ -102,10 +102,13 @@ def peak_flops(device=None) -> float:
 
 
 def mfu(flops_per_step: float, steps_per_sec: float,
-        device=None) -> Optional[float]:
+        device=None, devices: int = 1) -> Optional[float]:
     """Model FLOPs utilization, or None when the peak (or the flops)
-    is unknown — the null-not-NaN receipt rule, applied to gauges."""
-    peak = peak_flops(device)
+    is unknown — the null-not-NaN receipt rule, applied to gauges.
+    ``flops_per_step`` is the whole step's, over all chips (what the
+    ledger reports for a partitioned program too), so the peak is that of
+    the ``devices`` chips the step runs on: the trainer's mesh size."""
+    peak = peak_flops(device) * max(1, int(devices))
     if peak <= 0 or flops_per_step <= 0 or steps_per_sec <= 0:
         return None
     return flops_per_step * steps_per_sec / peak
@@ -226,6 +229,10 @@ class _WrappedJit:
             self._on_trace(args, kwargs)
             return fn(*args, **kwargs)
 
+        # the compiled module and every op_name in it are named after the
+        # program's function (jit_train_step, jit(train_step)/...), not
+        # after this hook: that is what a profiler trace shows
+        traced.__name__ = getattr(fn, '__name__', traced.__name__)
         self._jit = jax.jit(traced, **kw)
 
     @staticmethod
@@ -260,13 +267,27 @@ class _WrappedJit:
         ``(compile_ms, compiled)`` — the lazy analysis probe, run off
         the hot path by :meth:`ProgramLedger.ensure_analyzed`."""
         args, kwargs = skel
+        t0 = time.monotonic()
+        compiled = self._probe_compile(args, kwargs)
+        return (time.monotonic() - t0) * 1e3, compiled
+
+    def _probe_compile(self, args, kwargs):
+        """``lower().compile()`` with the trace hook suppressed: counts
+        and the sentinel never see a probe."""
         _PROBE_TLS.active = True
         try:
-            t0 = time.monotonic()
-            compiled = self._jit.lower(*args, **kwargs).compile()
-            return (time.monotonic() - t0) * 1e3, compiled
+            return self._jit.lower(*args, **kwargs).compile()
         finally:
             _PROBE_TLS.active = False
+
+    def compiled_text(self, *args, **kwargs) -> str:
+        """The compiled program's text for these arguments (live arrays
+        or ``ShapeDtypeStruct``s carrying their shardings, so a program
+        partitioned over a mesh reads as the one that runs), without
+        executing it and without counting as a compilation.  What
+        ``utils/profiler.device_time_by_scope`` joins a trace's events
+        to."""
+        return self._probe_compile(args, kwargs).as_text()
 
     def ensure_compiled(self, *args, **kwargs) -> Optional['ProgramEntry']:
         """Register (and analyze) this signature WITHOUT executing —

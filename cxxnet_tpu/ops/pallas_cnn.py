@@ -187,6 +187,7 @@ def _fused_call(x, w, b, strides, padding, groups, act):
             jax.ShapeDtypeStruct((n, oy_p, ox, cout), jnp.float32),
         ],
         interpret=_interpret(),
+        name='conv_bias_act',
         **_compiler_params('parallel', 'parallel'),
     )(xp32, w2, bvec)
     return y[:, :oy], z[:, :oy]

@@ -180,6 +180,23 @@ def _interpret() -> bool:
     return jax.default_backend() != 'tpu'
 
 
+#: every Pallas kernel's ``name=``.  The compiled program names the custom
+#: call after it (``%lrn_fwd.1 = ... custom_call_target="tpu_custom_call"``)
+#: and the profiler's device event carries that text, so a trace tells the
+#: kernels apart and ``utils/profiler.device_time_by_scope`` sums each one
+#: (doc/observability.md).  ``[a-z0-9_]`` only: a trace reader that sorts
+#: events by words such as ``convolution`` or ``all-reduce`` in their text
+#: must keep seeing a Mosaic custom call.  A new ``pallas_call`` adds its
+#: name here (tests/test_trace_names.py holds every call site to the table).
+KERNEL_NAMES = (
+    'lrn_fwd', 'lrn_bwd',
+    'matmul', 'matmul_nt', 'matmul_tn', 'int8_matmul',
+    'flash_fwd', 'flash_bwd_dq', 'flash_bwd_dkv',
+    'paged_decode', 'paged_verify',
+    'conv_bias_act',
+)
+
+
 def _block_spec(shape, index_map=None):
     return pl.BlockSpec(shape, index_map, memory_space=pltpu.VMEM)
 
@@ -239,10 +256,11 @@ def _lrn_bwd_kernel(x_ref, g_ref, band_ref, norm_ref, dx_ref, *, alpha_n,
 _ROW_TILE = 512
 
 
-def _lrn_call(kernel, outs, args, c, rows_padded, band_arg):
+def _lrn_call(kernel, name, outs, args, c, rows_padded, band_arg):
     """band_arg: index into ``args`` of the (c, c) band matrix — dispatch
     is positional because row blocks can also be (c, c) when the padded
-    row count happens to equal the channel count."""
+    row count happens to equal the channel count.  ``name``: the caller's
+    entry of ``KERNEL_NAMES`` (forward and backward share this call)."""
     grid = (rows_padded // _ROW_TILE,)
     row_spec = _block_spec((_ROW_TILE, c), lambda i: (i, 0))
     band_spec = _block_spec((c, c), lambda i: (0, 0))
@@ -256,6 +274,7 @@ def _lrn_call(kernel, outs, args, c, rows_padded, band_arg):
         out_specs=[row_spec] * len(outs) if isinstance(outs, list)
         else row_spec,
         interpret=_interpret(),
+        name=name,
         **_compiler_params('parallel'),
     )(*args)
 
@@ -275,7 +294,7 @@ def _lrn_fwd_impl(x, nsize, alpha, beta, knorm):
     kernel = functools.partial(_lrn_fwd_kernel, alpha_n=alpha / nsize,
                                beta=beta, knorm=knorm)
     out, norm = _lrn_call(
-        kernel,
+        kernel, 'lrn_fwd',
         [jax.ShapeDtypeStruct(x2.shape, x.dtype),
          jax.ShapeDtypeStruct(x2.shape, jnp.float32)],
         (x2, band), c, x2.shape[0], band_arg=1)
@@ -301,7 +320,7 @@ def _lrn_vjp_bwd(nsize, alpha, beta, knorm, res, g):
     kernel = functools.partial(_lrn_bwd_kernel, alpha_n=alpha / nsize,
                                beta=beta)
     dx = _lrn_call(
-        kernel, jax.ShapeDtypeStruct(x2.shape, x.dtype),
+        kernel, 'lrn_bwd', jax.ShapeDtypeStruct(x2.shape, x.dtype),
         (x2, g2, band, n2), c, x2.shape[0], band_arg=2)
     return (dx[:rows].reshape(*b, c),)
 
@@ -473,6 +492,7 @@ def _matmul_nt_impl(g, b, tile_m: int = 256, tile_n: int = 512,
         out_specs=_block_spec((tile_m, tile_k), lambda i, j, t: (i, j)),
         scratch_shapes=[_scratch((tile_m, tile_k))],
         interpret=_interpret(),
+        name='matmul_nt',
         **_compiler_params('parallel', 'parallel', 'arbitrary'),
     )(gp, bp)
     return out[:m, :k]
@@ -497,6 +517,7 @@ def _matmul_tn_impl(a, g, tile_m: int = 512, tile_n: int = 256,
         out_specs=_block_spec((tile_k, tile_n), lambda i, j, t: (i, j)),
         scratch_shapes=[_scratch((tile_k, tile_n))],
         interpret=_interpret(),
+        name='matmul_tn',
         **_compiler_params('parallel', 'parallel', 'arbitrary'),
     )(ap, gp)
     return out[:k, :n]
@@ -523,6 +544,7 @@ def _matmul_impl(a, b, tile_m: int = 256, tile_n: int = 256,
         out_specs=_block_spec((tile_m, tile_n), lambda i, j, t: (i, j)),
         scratch_shapes=[_scratch((tile_m, tile_n))],
         interpret=_interpret(),
+        name='matmul',
         **_compiler_params('parallel', 'parallel', 'arbitrary'),
     )(ap, bp)
     return out[:m, :n]
@@ -735,6 +757,7 @@ def _flash_fwd_impl(q, k, v, causal, block_q, block_k):
         scratch_shapes=[_scratch((bq, d)), _scratch((bq, 1)),
                         _scratch((bq, 1))],
         interpret=_interpret(),
+        name='flash_fwd',
         **_compiler_params('parallel', 'parallel', 'arbitrary'),
     )(qp, kp, vp)
     return out[:, :sq], lse[:, :sq, 0]
@@ -781,6 +804,7 @@ def _flash_bhsd_bwd(causal, block_q, block_k, res, g):
         out_specs=_block_spec((1, bq, d), lambda i, j, t: (i, j, 0)),
         scratch_shapes=[_scratch((bq, d))],
         interpret=_interpret(),
+        name='flash_bwd_dq',
         **_compiler_params('parallel', 'parallel', 'arbitrary'),
     )(qp, kp, vp, gp, lse_p, delta_p)
 
@@ -801,6 +825,7 @@ def _flash_bhsd_bwd(causal, block_q, block_k, res, g):
                    _block_spec((1, bk, d), lambda i, t, j: (i, t, 0))],
         scratch_shapes=[_scratch((bk, d)), _scratch((bk, d))],
         interpret=_interpret(),
+        name='flash_bwd_dkv',
         **_compiler_params('parallel', 'parallel', 'arbitrary'),
     )(qp, kp, vp, gp, lse_p, delta_p)
 
@@ -922,6 +947,7 @@ def paged_flash_decode(q, kpool, vpool, table, pos, w, scale):
         out_shape=jax.ShapeDtypeStruct((S, H, hd), vpool.dtype),
         grid_spec=grid_spec,
         interpret=_interpret(),
+        name='paged_decode',
         **_compiler_params('parallel', 'arbitrary'),
     )(table, pos, w, q, kpool, vpool)
 
@@ -1000,6 +1026,7 @@ def paged_flash_verify(q, kpool, vpool, table, pos, w, scale):
         out_shape=jax.ShapeDtypeStruct((S, K, H, hd), vpool.dtype),
         grid_spec=grid_spec,
         interpret=_interpret(),
+        name='paged_verify',
         **_compiler_params('parallel', 'arbitrary'),
     )(table, pos, w, q, kpool, vpool)
 
@@ -1052,6 +1079,7 @@ def pallas_int8_matmul(a, b, tile_m: int = 256, tile_n: int = 256,
         out_specs=_block_spec((tile_m, tile_n), lambda i, j, t: (i, j)),
         scratch_shapes=[_scratch((tile_m, tile_n), jnp.int32)],
         interpret=_interpret(),
+        name='int8_matmul',
         **_compiler_params('parallel', 'parallel', 'arbitrary'),
     )(ap, bp)
     return out[:m, :n]

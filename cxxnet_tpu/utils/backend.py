@@ -11,6 +11,7 @@ from __future__ import annotations
 import os
 
 _CACHE_ENV = 'JAX_COMPILATION_CACHE_DIR'
+_met = False      # meet_backend has recorded this process's first touch
 
 
 class BackendUnavailable(RuntimeError):
@@ -49,13 +50,32 @@ def cpu_pinned() -> bool:
     return bool(plats) and all(p == 'cpu' for p in plats)
 
 
+def meet_backend() -> str:
+    """``jax.default_backend()``; the process's first call is its first
+    touch of the backend (loading the runtime, reaching the chip: seconds
+    on a TPU) and runs inside the hub span ``entry.backend`` [platform,
+    devices], recorded once a process.  Both ways in come through here:
+    :func:`require_chip` (the benchmark, ``chip_smoke.py``) and the
+    trainer's device lookup (the CLI)."""
+    global _met
+    import jax
+    if _met:
+        return jax.default_backend()
+    from ..obs import span
+    with span('entry.backend', 'entry') as sp:
+        backend = jax.default_backend()
+        sp.attrs.update(platform=backend, devices=jax.device_count())
+    _met = True
+    return backend
+
+
 def require_chip() -> str:
     """The backend a device measurement may run on: ``'tpu'``, or
     ``'cpu'`` when the caller pinned it (a correctness run, stamped as
     such).  Anything else — above all JAX's silent fall to the CPU when
     it finds no accelerator — raises."""
     import jax
-    backend = jax.default_backend()
+    backend = meet_backend()
     if backend == 'tpu' or cpu_pinned():
         return backend
     raise BackendUnavailable(

@@ -13,13 +13,19 @@ Config surface (global section)::
     profile_stop_batch = 20     #   skipping compile) .. last (exclusive)
 
 The window is batch-based so the first (compiling) steps are excluded by
-default.
+default.  When the window closes, :func:`device_time_by_scope` reads the
+trace back and the table goes to stderr, a line a row: device time per step
+by conf layer and pass, and by Pallas kernel (doc/observability.md).
 """
 
 from __future__ import annotations
 
+import glob
+import os
+import re
+import sys
 import threading
-from typing import List, Optional, Tuple
+from typing import Callable, Dict, List, Optional, Tuple
 
 # --- single-flight arbitration ---------------------------------------------
 # jax.profiler holds ONE global trace per process: the config-driven
@@ -56,10 +62,161 @@ def trace_owner() -> Optional[str]:
         return _TRACE_OWNER
 
 
+# --- reading the program's own names back -----------------------------------
+# Net.forward runs every conf layer in a ``jax.named_scope`` and train_step
+# its ``nan_gate`` / ``grad_acc`` / ``update``; every Pallas kernel has a
+# ``name=`` (ops/pallas_kernels.KERNEL_NAMES).  The compiler keeps both: the
+# scope in the instruction's ``op_name`` (``jit(train_step)/jvp(l03_lrn)/..``
+# forward, ``transpose(jvp(l03_lrn))`` backward), the kernel's name as the
+# custom call's own (``%lrn_fwd.1``).  A device event of the TPU's trace is
+# named by its instruction's text and carries no ``op_name`` (looked at on the
+# v5e, PR 27: its stats are offsets and durations), so the scope is found
+# through the instruction's name in the compiled program's text.
+
+_BWD = re.compile(r'transpose\(jvp\(([^()/]+)\)\)')
+_FWD = re.compile(r'jvp\(([^()/]+)\)')
+_CONTROL = re.compile(r'^(jit|pjit)\(.*\)$|^(while|body|cond|checkpoint)$'
+                      r'|^branch_\d+_fun$')
+_HLO_COMPUTATION = re.compile(r'^(?:ENTRY )?%([\w.\-]+) \(.*\{$')
+_HLO_INSTRUCTION = re.compile(r'^\s+(?:ROOT )?%([\w.\-]+) = ')
+_HLO_OP_NAME = re.compile(r'metadata=\{[^}]*op_name="([^"]*)"')
+_HLO_CALLS = re.compile(r'(?:calls|to_apply)=%([\w.\-]+)')
+_OP_LINE, _MODULE_LINE = 'XLA Ops', 'XLA Modules'
+
+
+def scope_of(op_name: str) -> Tuple[str, str]:
+    """(scope, pass) of an instruction's ``op_name``: pass ``bwd`` inside
+    ``transpose(jvp(<scope>))``, ``fwd`` inside ``jvp(<scope>)``, ``-`` for a
+    scope outside differentiation (``update``, or a layer of the forward-only
+    program); ``('other', '-')`` where no scope holds the instruction."""
+    m = _BWD.search(op_name)
+    if m:
+        return m.group(1), 'bwd'
+    m = _FWD.search(op_name)
+    if m:
+        return m.group(1), 'fwd'
+    parts = [p for p in op_name.split('/')[:-1] if not _CONTROL.match(p)]
+    return (parts[0], '-') if parts else ('other', '-')
+
+
+def hlo_op_names(hlo_text: str) -> Dict[str, str]:
+    """instruction name -> ``op_name`` from a compiled program's text
+    (``compiled.as_text()``).  The compiler's own rewrites lose the
+    ``op_name`` of an instruction and keep it on what the instruction calls
+    (LRN's channel cumsum becomes a windowed reduction in an unnamed fusion,
+    9 ms of GoogLeNet's step): such an instruction takes the first
+    ``op_name`` inside the computation it calls."""
+    own: Dict[str, str] = {}
+    calls: Dict[str, str] = {}
+    inside: Dict[str, List[str]] = {}
+    comp = ''
+    for line in hlo_text.splitlines():
+        m = _HLO_COMPUTATION.match(line)
+        if m:
+            comp = m.group(1)
+            continue
+        m = _HLO_INSTRUCTION.match(line)
+        if not m:
+            continue
+        name = m.group(1)
+        inside.setdefault(comp, []).append(name)
+        op = _HLO_OP_NAME.search(line)
+        if op:
+            own[name] = op.group(1)
+        called = _HLO_CALLS.search(line)
+        if called:
+            calls[name] = called.group(1)
+
+    def resolve(name: str, depth: int) -> str:
+        if name in own or depth > 4:
+            return own.get(name, '')
+        return next(filter(None, (resolve(n, depth + 1) for n in
+                                  inside.get(calls.get(name, ''), ()))), '')
+    return {name: resolve(name, 0) for name in set(own) | set(calls)}
+
+
+def device_time_by_scope(xplane_path: str, hlo_text: Callable[[], str]):
+    """Device time of one step of the program a trace mostly ran, by this
+    program's own names: ``{'module', 'steps', 'scopes': {(scope, pass):
+    ms}, 'kernels': {name: ms}}``, milliseconds a step on the first device
+    plane, or ``None`` where the trace has no device plane (a CPU's).
+    ``hlo_text()`` is that program's compiled text
+    (``NetTrainer.step_program_text``; asked for only once a device plane
+    is found, it costs a compile): an event is given the ``op_name`` of the
+    instruction of its name there, and is ``other`` without one."""
+    from jax.profiler import ProfileData
+    # a chip's plane is /device:TPU:<n>; the trace has other /device:
+    # planes without an op line (/device:CUSTOM:Megascale Trace)
+    found = sorted(
+        (p.name, lines) for p in ProfileData.from_file(xplane_path).planes
+        if p.name.startswith('/device:')
+        for lines in [{l.name: l for l in p.lines}]
+        if _OP_LINE in lines and _MODULE_LINE in lines)
+    if not found:
+        return None
+    lines = found[0][1]
+    return reduce_by_scope(list(lines[_OP_LINE].events),
+                           list(lines[_MODULE_LINE].events), hlo_text())
+
+
+def reduce_by_scope(ops, modules, hlo_text: str):
+    """The reduction behind :func:`device_time_by_scope`, over the events of
+    one device's op line and module line."""
+    wall: Dict[str, float] = {}
+    for m in modules:
+        wall[m.name] = wall.get(m.name, 0.0) + m.duration_ns
+    if not wall:
+        return None
+    main = max(wall, key=wall.get)
+    runs = sorted((m.start_ns, m.start_ns + m.duration_ns)
+                  for m in modules if m.name == main)
+    by_name = hlo_op_names(hlo_text)
+    scopes: Dict[Tuple[str, str], float] = {}
+    kernels: Dict[str, float] = {}
+    i = 0
+    for e in sorted(ops, key=lambda e: e.start_ns):
+        while i < len(runs) and runs[i][1] <= e.start_ns:
+            i += 1
+        if i == len(runs) or e.start_ns < runs[i][0]:
+            continue                   # ran outside the step program
+        name = e.name.split(' = ', 1)[0].lstrip('%')
+        key = scope_of(by_name.get(name, ''))
+        scopes[key] = scopes.get(key, 0.0) + e.duration_ns
+        if 'tpu_custom_call' in e.name:
+            kernel = re.sub(r'[.\d]+$', '', name)
+            kernels[kernel] = kernels.get(kernel, 0.0) + e.duration_ns
+    per_step = 1e-6 / len(runs)
+    return {'module': main, 'steps': len(runs),
+            'scopes': {k: v * per_step for k, v in scopes.items()},
+            'kernels': {k: v * per_step for k, v in kernels.items()}}
+
+
+def format_scope_table(table) -> List[str]:
+    """The table as lines for stderr: layers in conf order (their scope
+    names sort that way), forward and backward side by side, then the
+    kernels."""
+    rows: Dict[str, Dict[str, float]] = {}
+    for (scope, pas), ms in table['scopes'].items():
+        rows.setdefault(scope, {})[pas] = ms
+    total = sum(table['scopes'].values())
+    out = [f'profile: {table["module"]}: {table["steps"]} steps traced, '
+           f'{total:.3f} ms of device time a step; by scope '
+           f'(fwd / bwd / outside differentiation, ms a step)']
+    for scope in sorted(rows):
+        r = rows[scope]
+        out.append(f'profile-scope\t{scope}\t{r.get("fwd", 0.0):.3f}\t'
+                   f'{r.get("bwd", 0.0):.3f}\t{r.get("-", 0.0):.3f}')
+    for kernel in sorted(table['kernels']):
+        out.append(f'profile-kernel\t{kernel}\t'
+                   f'{table["kernels"][kernel]:.3f}')
+    return out
+
+
 class TraceWindow:
     """Start/stop ``jax.profiler`` around a window of training batches."""
 
-    def __init__(self):
+    def __init__(self, hlo_text: Optional[Callable[[], str]] = None):
+        self.hlo_text = hlo_text     # the traced program's compiled text
         self.profile_dir = ''
         self.start_batch = 10
         self.stop_batch = 20
@@ -106,3 +263,13 @@ class TraceWindow:
             self._active = False
             self._done = True
             release_trace('profile_dir')
+            self._write_scope_table()
+
+    def _write_scope_table(self) -> None:
+        found = sorted(glob.glob(os.path.join(
+            self.profile_dir, 'plugins', 'profile', '*', '*.xplane.pb')))
+        if not found or self.hlo_text is None:
+            return
+        table = device_time_by_scope(found[-1], self.hlo_text)
+        if table is not None:
+            sys.stderr.write('\n'.join(format_scope_table(table)) + '\n')
