@@ -185,12 +185,9 @@ def check_pallas_compiled() -> None:
     """The gates the traced steps consulted, read in the same process."""
     from cxxnet_tpu.ops import pallas_kernels as PK
     got = {'interpret': PK._interpret(),
-           'lrn(256)': PK.lrn_auto_mode(256),
-           'lrn(96)': PK.lrn_auto_mode(96),
            'fc8 eval': PK.fullc_use_pallas(BATCH, 4096, 1000,
                                            is_train=False)}
-    want = {'interpret': False, 'lrn(256)': 'full', 'lrn(96)': 'hybrid',
-            'fc8 eval': True}
+    want = {'interpret': False, 'fc8 eval': True}
     if got != want:
         fail(f'Pallas gates {got}, expected {want}')
 

@@ -25,7 +25,7 @@ Traced scope is resolved statically per module:
   slow a dispatch, it breaks compilation on real hardware while
   silently "working" under ``interpret=True`` on CPU.  A kernel that
   reaches ``pallas_call`` through a helper's *parameter*
-  (``_lrn_call(kernel, ...)`` where the helper forwards ``kernel`` into
+  (``_call(kernel, ...)`` where the helper forwards ``kernel`` into
   the call position) IS resolved, one call level deep: the helper's
   forwarding parameters are computed from its body, and the caller's
   matching argument (positional or keyword, directly or through
@@ -168,7 +168,7 @@ class _Scope:
 
     def _forwarded_params(self, helper: ast.AST):
         """Parameters of ``helper`` that flow into a traced HOF position
-        inside its own body — the ``_lrn_call(kernel, ...)`` indirection:
+        inside its own body — the ``_call(kernel, ...)`` indirection:
         a helper taking ``kernel`` and forwarding it into
         ``pl.pallas_call(kernel, ...)`` (directly or via ``partial``)
         makes the CALLER's matching argument a traced function.  One
@@ -231,7 +231,7 @@ class _Scope:
                                 if t is not None:
                                     self.traced.add(t)
                     else:
-                        # helper indirection: _lrn_call(kernel, ...)
+                        # helper indirection: _call(kernel, ...)
                         # where the helper forwards a parameter into a
                         # HOF position — the caller's argument is traced
                         helper = self._resolve(child.func, fn_parent, cls)

@@ -162,7 +162,7 @@ class ConvolutionLayer(Layer):
         experiments (tools/conv_lowering_bench.py times THESE module
         functions); auto flips per shape class only when an on-chip
         receipt shows a win (same policy as
-        ops.pallas_kernels.lrn_auto_mode)."""
+        ops.pallas_kernels.fullc_use_pallas)."""
         mode = self.param.conv_lowering
         if mode == 'auto':
             return 'native'

@@ -13,9 +13,80 @@ learnable slope is visited under the 'wmat' tag, bias under 'bias'.
 
 from __future__ import annotations
 
+import functools
+
+import jax
 import jax.numpy as jnp
+import numpy as np
 
 from .base import Layer, kBatchNorm, kLRN, register_layer
+
+
+def _norm(x, nsize: int, alpha: float, knorm: float):
+    """``knorm + alpha/nsize * sum(x[..., j-lo : j+hi+1] ** 2)`` in float32,
+    zeros past the ends: ``nsize`` shifted slices of the zero-padded ``x``,
+    each converted and squared by itself.  The cost an element is the
+    window's width whatever the channel count, the compiler sees plain
+    elementwise work that it fuses with the tail in the layout the
+    neighbouring layers keep, and what the fusion reads is ``x`` in its own
+    dtype (squared before the pad, the layer before writes a float32 copy
+    of ``x * x`` for it)."""
+    c = x.shape[-1]
+    lo = (nsize - 1) // 2
+    pad = jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(lo, nsize - 1 - lo)])
+    window = None
+    for k in range(nsize):
+        term = pad[..., k:k + c].astype(jnp.float32)
+        window = term * term if window is None else window + term * term
+    return knorm + (alpha / nsize) * window
+
+
+def _lrn_out(x, nsize, alpha, beta, knorm):
+    norm = _norm(x, nsize, alpha, knorm)
+    return (x.astype(jnp.float32) * jnp.power(norm, -beta)).astype(x.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4))
+def lrn(x, nsize: int, alpha: float, beta: float, knorm: float):
+    """Cross-channel LRN over a channels-last ``x``:
+    ``x * (knorm + alpha/nsize * sum_window(x^2)) ** -beta``, the window
+    centred on each channel (``(nsize-1)//2`` below, the rest above).
+    Float32 inside, ``x.dtype`` out.  The one LRN of every channel count,
+    batch and mesh: plain XLA, so it shards with the batch and fuses where
+    it stands.  The backward keeps ``x`` alone and recomputes the norm.
+    Which spelling each pass sums its window with was read from the
+    layers' rows of the step's trace on the chip, not from a
+    microbenchmark (PERF.md 6, PR 28)."""
+    return _lrn_out(x, nsize, alpha, beta, knorm)
+
+
+def _lrn_fwd(x, nsize, alpha, beta, knorm):
+    return _lrn_out(x, nsize, alpha, beta, knorm), x
+
+
+def _lrn_bwd(nsize, alpha, beta, knorm, x, g):
+    x32 = x.astype(jnp.float32)
+    g32 = g.astype(jnp.float32)
+    norm = _norm(x, nsize, alpha, knorm)
+    npow = jnp.power(norm, -beta)
+    t = g32 * x32 * npow / norm
+    # dx_j = g_j norm_j^-b - 2 b alpha/n x_j sum_i t_i over the windows i
+    # that CONTAIN j: rows j-hi .. j+lo of t, the forward's widths swapped.
+    # Summed as a banded (c, c) dot: t costs a pow an element, so shifted
+    # slices of it would send it through HBM in float32 and back, while a
+    # dot takes it as an operand computed in the same fusion, tail
+    # included.  0/1 weights and HIGHEST: the sum is float32's
+    lo = (nsize - 1) // 2
+    hi = nsize - 1 - lo
+    i = np.arange(x.shape[-1])
+    band = (i[:, None] >= i[None, :] - hi) & (i[:, None] <= i[None, :] + lo)
+    win_t = jnp.einsum('...i,ij->...j', t, jnp.asarray(band, jnp.float32),
+                       precision=jax.lax.Precision.HIGHEST)
+    dx = g32 * npow - 2.0 * beta * (alpha / nsize) * x32 * win_t
+    return (dx.astype(x.dtype),)
+
+
+lrn.defvjp(_lrn_fwd, _lrn_bwd)
 
 
 @register_layer
@@ -46,33 +117,8 @@ class LRNLayer(Layer):
         return [in_specs[0]]
 
     def forward(self, params, inputs, ctx):
-        x = inputs[0]  # (b, y, x, c)
-        from ..ops.pallas_kernels import (lrn_auto_mode, lrn_hybrid,
-                                          lrn_pallas)
-        mode = lrn_auto_mode(x.shape[-1], ctx.spmd_devices)
-        if mode == 'full':
-            # Pallas forward AND backward: fwd+bwd measured 2.16x ahead
-            # of XLA at 128-lane-aligned channels
-            # (receipts/micro_lrn.json; ops/pallas_kernels.py)
-            return [lrn_pallas(x, self.nsize, self.alpha, self.beta,
-                               self.knorm)]
-        if mode == 'hybrid':
-            # Pallas forward / XLA backward: the fused fwd wins even at
-            # non-MXU-aligned channel counts but the Pallas bwd loses
-            return [lrn_hybrid(x, self.nsize, self.alpha, self.beta,
-                               self.knorm)]
-        x32 = x.astype(jnp.float32)
-        n = self.nsize
-        half_lo = (n - 1) // 2
-        half_hi = n - 1 - half_lo
-        sq = x32 * x32
-        # cross-channel window sum via cumulative sum along the channel axis
-        c = x.shape[-1]
-        pad = jnp.pad(sq, [(0, 0)] * (x.ndim - 1) + [(half_lo + 1, half_hi)])
-        cums = jnp.cumsum(pad, axis=-1)
-        window = (cums[..., n:n + c] - cums[..., 0:c])
-        norm = window * (self.alpha / n) + self.knorm
-        return [(x32 * jnp.power(norm, -self.beta)).astype(x.dtype)]
+        return [lrn(inputs[0], self.nsize, self.alpha, self.beta,
+                    self.knorm)]
 
 
 def fold_scale_shift(gamma, beta, mean, var, eps):

@@ -555,7 +555,7 @@ class Net:
         self._convact_solo: set = set()
         if fuse == '0' or tp > 1:
             # under GSPMD a pallas_call is an opaque custom call with no
-            # sharding rule — same scoping as lrn_auto_mode
+            # sharding rule — same scoping as fullc_use_pallas
             return
         reads, writes = self._node_version_maps()
         readers: Dict[tuple, List[int]] = {}

@@ -1,6 +1,5 @@
 """Device compute ops: Pallas TPU kernels + XLA lowerings."""
 
-from .pallas_kernels import (decode_use_flash, lrn_auto_mode, lrn_hybrid,
-                             lrn_pallas, paged_flash_decode,
+from .pallas_kernels import (decode_use_flash, paged_flash_decode,
                              pallas_enabled, pallas_int8_matmul,
                              pallas_matmul, pallas_mode)

@@ -7,12 +7,10 @@ CUDA/mshadow hot paths become TPU kernels.  Design notes:
   TPU those already lower to MXU-optimal programs (the cuDNN analogy);
   a hand-written Pallas conv would have to re-derive XLA's spatial
   partitioning to break even.  Measured, not assumed: see bench notes.
-* **LRN** is the real fusion win: the XLA lowering materializes the
-  padded/cumsum intermediates in HBM, while the Pallas kernel computes
-  ``x * (k + alpha/n * (x^2 @ band))^-beta`` in one VMEM pass — the
-  channel-window sum becomes a banded matmul on the MXU, and square /
-  power / multiply fuse around it.  Forward and backward are both single
-  kernels wired through ``jax.custom_vjp``.
+* **LRN** has no kernel here: in the net the (rows, c) layout a custom
+  call demands cost more in copies than the kernels saved, and XLA fuses
+  the O(local_size) window sum of ``layers/norm.lrn`` in the layout the
+  neighbouring convolutions keep (PERF.md 6, PR 28).
 * **fullc** gets a tiled-MXU matmul (``pallas_matmul``) used when
   ``CXXNET_PALLAS=1``; XLA's dot is the default.
 
@@ -28,7 +26,6 @@ import os
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -38,7 +35,7 @@ def pallas_mode() -> str:
     ``CXXNET_PALLAS=1`` forces every Pallas path), ``'off'`` (explicit 0
     disables even the measured-profitable ones), ``'auto'`` (unset: each
     op consults its own receipts-derived profitability gate — see
-    ``lrn_auto_mode`` and receipts/micro_*.json)."""
+    ``fullc_use_pallas`` and receipts/micro_*.json)."""
     v = os.environ.get('CXXNET_PALLAS')
     if v is None or not v.strip():
         return 'auto'
@@ -100,8 +97,7 @@ def fullc_use_pallas(m: int, k: int, n: int, *, is_train: bool,
         return True
     if os.environ.get('CXXNET_FULLC_PALLAS', '').strip() == '0':
         # fullc-only kill switch: lets bench.py eval_alexnet A/B THIS
-        # gate in isolation — CXXNET_PALLAS=0 would also flip the LRN
-        # auto winners and confound the receipt
+        # gate in isolation, whatever else CXXNET_PALLAS reaches
         return False
     if is_train or _interpret() or spmd_devices != 1:
         return False
@@ -112,46 +108,6 @@ def fullc_pallas_shape_class(m: int, k: int, n: int) -> bool:
     """The measured fc8 shape class (receipts/micro_matmul.json):
     lane-ragged N big enough to matter."""
     return n % 128 != 0 and m >= 128 and k >= 1024 and n >= 512
-
-
-def lrn_auto_mode(c: int, spmd_devices: int = 1) -> str:
-    """Which LRN implementation the ``auto`` Pallas mode picks at channel
-    count ``c``: ``'full'`` (Pallas fwd+bwd), ``'hybrid'`` (Pallas fwd /
-    XLA bwd), or ``'xla'``.
-
-    From receipts/micro_lrn.json (TPU v5 lite, bf16, 2026-07-30
-    scatter-add-perturbation rerun — the earlier broadcast-perturbation
-    numbers let XLA hoist work and are superseded):
-    c=256 (AlexNet norm2): fwd 1.37x, fwd+bwd **2.16x** -> full Pallas;
-    c=96  (AlexNet norm1): fwd 1.90x, fwd+bwd 0.66x -> the fused fwd
-    wins even with the 96-lane underfill but the bwd loses, so the
-    hybrid keeps the fwd win and hands the bwd to XLA.  The gates:
-    128-lane-aligned channels run full Pallas; other sublane-aligned
-    (c % 8) counts at or above the measured c=96 floor run the hybrid
-    (smaller channel counts underfill the (c, c) band matmul worse than
-    anything measured, so they stay on XLA); ragged counts stay on XLA.
-
-    ``spmd_devices`` is the mesh size of the CALLING program (threaded
-    through ForwardContext): auto engages only in single-device
-    programs, because under GSPMD a ``pallas_call`` is an opaque custom
-    call with no sharding rule — the partitioner would gather the full
-    sharded activation around it, slower and memory-fatter than the XLA
-    path it replaces (and the receipts are single-chip measurements).
-    Explicit ``use_pallas=1`` still forces the full kernel everywhere;
-    the shard_map'd paths in parallel/sequence.py run per-shard by
-    construction and take no such scoping."""
-    mode = pallas_mode()
-    if mode == 'off':
-        return 'xla'
-    if mode == 'on':
-        return 'full'
-    if _interpret() or spmd_devices != 1:
-        return 'xla'
-    if c % 128 == 0:
-        return 'full'
-    if c % 8 == 0 and c >= 96:
-        return 'hybrid'
-    return 'xla'
 
 
 def decode_use_flash(explicit=None) -> bool:
@@ -181,7 +137,7 @@ def _interpret() -> bool:
 
 
 #: every Pallas kernel's ``name=``.  The compiled program names the custom
-#: call after it (``%lrn_fwd.1 = ... custom_call_target="tpu_custom_call"``)
+#: call after it (``%matmul.1 = ... custom_call_target="tpu_custom_call"``)
 #: and the profiler's device event carries that text, so a trace tells the
 #: kernels apart and ``utils/profiler.device_time_by_scope`` sums each one
 #: (doc/observability.md).  ``[a-z0-9_]`` only: a trace reader that sorts
@@ -189,7 +145,6 @@ def _interpret() -> bool:
 #: must keep seeing a Mosaic custom call.  A new ``pallas_call`` adds its
 #: name here (tests/test_trace_names.py holds every call site to the table).
 KERNEL_NAMES = (
-    'lrn_fwd', 'lrn_bwd',
     'matmul', 'matmul_nt', 'matmul_tn', 'int8_matmul',
     'flash_fwd', 'flash_bwd_dq', 'flash_bwd_dkv',
     'paged_decode', 'paged_verify',
@@ -209,169 +164,6 @@ def _compiler_params(*dimension_semantics):
         return {}
     return {'compiler_params':
             pltpu.CompilerParams(dimension_semantics=dimension_semantics)}
-
-
-def _band_matrix(c: int, nsize: int, dtype=jnp.float32):
-    """(c, c) 0/1 band: column j sums channels in j's LRN window."""
-    half_lo = (nsize - 1) // 2
-    half_hi = nsize - 1 - half_lo
-    idx = np.arange(c)
-    band = ((idx[:, None] >= idx[None, :] - half_lo)
-            & (idx[:, None] <= idx[None, :] + half_hi))
-    return jnp.asarray(band, dtype)
-
-
-def _pad_rows(x2, tile):
-    rows = x2.shape[0]
-    pad = (-rows) % tile
-    if pad:
-        x2 = jnp.pad(x2, ((0, pad), (0, 0)))
-    return x2, rows
-
-
-# --- LRN ------------------------------------------------------------------
-
-def _lrn_fwd_kernel(x_ref, band_ref, o_ref, norm_ref, *, alpha_n, beta,
-                    knorm):
-    x = x_ref[:].astype(jnp.float32)
-    win = jnp.dot(x * x, band_ref[:], preferred_element_type=jnp.float32)
-    norm = knorm + alpha_n * win
-    norm_ref[:] = norm
-    o_ref[:] = (x * jnp.power(norm, -beta)).astype(o_ref.dtype)
-
-
-def _lrn_bwd_kernel(x_ref, g_ref, band_ref, norm_ref, dx_ref, *, alpha_n,
-                    beta):
-    x = x_ref[:].astype(jnp.float32)
-    g = g_ref[:].astype(jnp.float32)
-    norm = norm_ref[:]
-    npow = jnp.power(norm, -beta)
-    # dL/dx = g * norm^-b - 2*b*alpha_n * x * ((g*x*norm^(-b-1)) @ band^T)
-    inner = jnp.dot(g * x * npow / norm, band_ref[:],
-                    preferred_element_type=jnp.float32)
-    dx_ref[:] = (g * npow - 2.0 * beta * alpha_n * x * inner
-                 ).astype(dx_ref.dtype)
-
-
-_ROW_TILE = 512
-
-
-def _lrn_call(kernel, name, outs, args, c, rows_padded, band_arg):
-    """band_arg: index into ``args`` of the (c, c) band matrix — dispatch
-    is positional because row blocks can also be (c, c) when the padded
-    row count happens to equal the channel count.  ``name``: the caller's
-    entry of ``KERNEL_NAMES`` (forward and backward share this call)."""
-    grid = (rows_padded // _ROW_TILE,)
-    row_spec = _block_spec((_ROW_TILE, c), lambda i: (i, 0))
-    band_spec = _block_spec((c, c), lambda i: (0, 0))
-    specs = [band_spec if i == band_arg else row_spec
-             for i in range(len(args))]
-    return pl.pallas_call(
-        kernel,
-        out_shape=outs,
-        grid=grid,
-        in_specs=specs,
-        out_specs=[row_spec] * len(outs) if isinstance(outs, list)
-        else row_spec,
-        interpret=_interpret(),
-        name=name,
-        **_compiler_params('parallel'),
-    )(*args)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4))
-def lrn_pallas(x, nsize: int, alpha: float, beta: float, knorm: float):
-    """Cross-channel LRN over NHWC input, Pallas-fused."""
-    out, _ = _lrn_fwd_impl(x, nsize, alpha, beta, knorm)
-    return out
-
-
-def _lrn_fwd_impl(x, nsize, alpha, beta, knorm):
-    b = x.shape[:-1]
-    c = x.shape[-1]
-    x2, rows = _pad_rows(x.reshape(-1, c), _ROW_TILE)
-    band = _band_matrix(c, nsize)
-    kernel = functools.partial(_lrn_fwd_kernel, alpha_n=alpha / nsize,
-                               beta=beta, knorm=knorm)
-    out, norm = _lrn_call(
-        kernel, 'lrn_fwd',
-        [jax.ShapeDtypeStruct(x2.shape, x.dtype),
-         jax.ShapeDtypeStruct(x2.shape, jnp.float32)],
-        (x2, band), c, x2.shape[0], band_arg=1)
-    return out[:rows].reshape(*b, c), norm[:rows]
-
-
-def _lrn_vjp_fwd(x, nsize, alpha, beta, knorm):
-    out, norm = _lrn_fwd_impl(x, nsize, alpha, beta, knorm)
-    return out, (x, norm)
-
-
-def _lrn_vjp_bwd(nsize, alpha, beta, knorm, res, g):
-    x, norm = res
-    b = x.shape[:-1]
-    c = x.shape[-1]
-    x2, rows = _pad_rows(x.reshape(-1, c), _ROW_TILE)
-    g2, _ = _pad_rows(g.reshape(-1, c).astype(jnp.float32), _ROW_TILE)
-    n2, _ = _pad_rows(norm, _ROW_TILE)
-    n2 = jnp.where(n2 == 0.0, 1.0, n2)   # padded rows: avoid 0^-b
-    # backward contracts the transposed band: dx_j sums over windows i
-    # that contain j (identical for symmetric/odd windows)
-    band = _band_matrix(c, nsize).T
-    kernel = functools.partial(_lrn_bwd_kernel, alpha_n=alpha / nsize,
-                               beta=beta)
-    dx = _lrn_call(
-        kernel, 'lrn_bwd', jax.ShapeDtypeStruct(x2.shape, x.dtype),
-        (x2, g2, band, n2), c, x2.shape[0], band_arg=2)
-    return (dx[:rows].reshape(*b, c),)
-
-
-lrn_pallas.defvjp(_lrn_vjp_fwd, _lrn_vjp_bwd)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4))
-def lrn_hybrid(x, nsize: int, alpha: float, beta: float, knorm: float):
-    """Cross-channel LRN: Pallas forward, XLA backward.
-
-    The measured split (receipts/micro_lrn.json, 2026-07-30 rerun): the
-    fused forward wins at every measured shape (1.90x at c=96, 1.37x at
-    c=256), while the Pallas backward only wins at 128-lane-aligned
-    channels (fwd+bwd 2.16x at c=256 — ``lrn_auto_mode`` routes those to
-    the full ``lrn_pallas``) and loses below that (fwd+bwd 0.66x at
-    c=96, where the bwd band matmul underfills the MXU worse than the
-    fwd because it runs two elementwise chains per tile).  So this
-    hybrid — the auto choice at non-128-aligned channels — keeps the
-    Pallas forward and runs the backward as plain jnp ops (the cumsum
-    window trick of ``layers/norm.py``) on the residuals the Pallas
-    forward already produced."""
-    out, _ = _lrn_fwd_impl(x, nsize, alpha, beta, knorm)
-    return out
-
-
-def _lrn_hybrid_fwd(x, nsize, alpha, beta, knorm):
-    out, norm = _lrn_fwd_impl(x, nsize, alpha, beta, knorm)
-    return out, (x, norm.reshape(x.shape))
-
-
-def _lrn_hybrid_bwd(nsize, alpha, beta, knorm, res, g):
-    x, norm = res
-    x32 = x.astype(jnp.float32)
-    g32 = g.astype(jnp.float32)
-    npow = jnp.power(norm, -beta)
-    t = g32 * x32 * npow / norm
-    n = nsize
-    half_lo = (n - 1) // 2
-    half_hi = n - 1 - half_lo
-    c = x.shape[-1]
-    # dx_j sums t_i over windows i that CONTAIN j — the transposed
-    # window [j-half_hi, j+half_lo], hence the swapped pad widths
-    pad = jnp.pad(t, [(0, 0)] * (x.ndim - 1) + [(half_hi + 1, half_lo)])
-    cums = jnp.cumsum(pad, axis=-1)
-    win = cums[..., n:n + c] - cums[..., 0:c]
-    dx = g32 * npow - 2.0 * beta * (alpha / n) * x32 * win
-    return (dx.astype(x.dtype),)
-
-
-lrn_hybrid.defvjp(_lrn_hybrid_fwd, _lrn_hybrid_bwd)
 
 
 # --- tiled matmul (fullc) -------------------------------------------------

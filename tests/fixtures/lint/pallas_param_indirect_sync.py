@@ -1,5 +1,5 @@
 """Seeded violation twin: a kernel reaching ``pallas_call`` through a
-helper's PARAMETER — the ``_lrn_call(kernel, ...)`` indirection that was
+helper's PARAMETER — the ``_call(kernel, ...)`` indirection that was
 this rule's documented soundness hole.  The helper itself is clean; the
 violation lives in the kernel body the caller hands it, positionally in
 one case and by keyword (through a ``partial`` wrapper) in the other.

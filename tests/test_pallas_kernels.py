@@ -5,104 +5,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from cxxnet_tpu.ops.pallas_kernels import lrn_pallas, pallas_matmul
-
-
-def lrn_ref(x, nsize, alpha, beta, knorm):
-    """Pure-jnp LRN (the XLA path in layers/norm.py)."""
-    c = x.shape[-1]
-    half_lo = (nsize - 1) // 2
-    sq = x * x
-    out = np.zeros_like(x)
-    for ch in range(c):
-        lo = max(0, ch - half_lo)
-        hi = min(c, ch + (nsize - 1 - half_lo) + 1)
-        norm = knorm + alpha / nsize * np.sum(sq[..., lo:hi], axis=-1)
-        out[..., ch] = x[..., ch] * norm ** -beta
-    return out
-
-
-@pytest.mark.parametrize('nsize', [3, 5, 4])
-def test_lrn_pallas_forward(nsize):
-    rng = np.random.RandomState(0)
-    x = rng.rand(2, 3, 5, 96).astype(np.float32)
-    out = np.asarray(lrn_pallas(jnp.asarray(x), nsize, 0.001, 0.75, 1.0))
-    ref = lrn_ref(x, nsize, 0.001, 0.75, 1.0)
-    np.testing.assert_allclose(out, ref, rtol=1e-5, atol=1e-6)
-
-
-@pytest.mark.parametrize('nsize', [5, 4])
-def test_lrn_pallas_grad_matches_autodiff(nsize):
-    rng = np.random.RandomState(1)
-    x = jnp.asarray(rng.rand(2, 2, 3, 32).astype(np.float32) + 0.1)
-
-    def jnp_lrn(x):
-        c = x.shape[-1]
-        half_lo = (nsize - 1) // 2
-        half_hi = nsize - 1 - half_lo
-        sq = x * x
-        pad = jnp.pad(sq, [(0, 0)] * 3 + [(half_lo + 1, half_hi)])
-        cums = jnp.cumsum(pad, axis=-1)
-        win = cums[..., nsize:nsize + c] - cums[..., 0:c]
-        norm = win * (0.001 / nsize) + 1.0
-        return x * jnp.power(norm, -0.75)
-
-    g_ref = jax.grad(lambda x: jnp.sum(jnp_lrn(x) ** 2))(x)
-    g_pl = jax.grad(lambda x: jnp.sum(
-        lrn_pallas(x, nsize, 0.001, 0.75, 1.0) ** 2))(x)
-    np.testing.assert_allclose(np.asarray(g_pl), np.asarray(g_ref),
-                               rtol=1e-4, atol=1e-5)
-
-
-@pytest.mark.parametrize('nsize', [5, 4])
-def test_lrn_hybrid_matches_full_pallas(nsize):
-    """lrn_hybrid (pallas fwd / XLA bwd, the default TPU path at
-    MXU-aligned channel counts) must agree with lrn_pallas in both
-    passes."""
-    from cxxnet_tpu.ops.pallas_kernels import lrn_hybrid
-    rng = np.random.RandomState(7)
-    x = jnp.asarray(rng.rand(2, 2, 3, 32).astype(np.float32) + 0.1)
-    out_h = lrn_hybrid(x, nsize, 0.001, 0.75, 1.0)
-    out_p = lrn_pallas(x, nsize, 0.001, 0.75, 1.0)
-    np.testing.assert_allclose(np.asarray(out_h), np.asarray(out_p),
-                               rtol=1e-5, atol=1e-6)
-    g_h = jax.grad(lambda x: jnp.sum(
-        lrn_hybrid(x, nsize, 0.001, 0.75, 1.0) ** 2))(x)
-    g_p = jax.grad(lambda x: jnp.sum(
-        lrn_pallas(x, nsize, 0.001, 0.75, 1.0) ** 2))(x)
-    np.testing.assert_allclose(np.asarray(g_h), np.asarray(g_p),
-                               rtol=1e-4, atol=1e-5)
-
-
-def test_lrn_auto_mode_gate(monkeypatch):
-    """'auto' picks full Pallas at 128-lane-aligned channels, the
-    fwd-only hybrid at other sublane-aligned counts, XLA for ragged
-    channels or off-TPU; explicit on/off override both ways
-    (receipts/micro_lrn.json)."""
-    from cxxnet_tpu.ops import pallas_kernels as pk
-    monkeypatch.delenv('CXXNET_PALLAS', raising=False)
-    assert pk.pallas_mode() == 'auto'
-    # off a real TPU (interpret mode) auto never turns pallas on
-    monkeypatch.setattr(pk, '_interpret', lambda: True)
-    assert pk.lrn_auto_mode(256) == 'xla'
-    monkeypatch.setattr(pk, '_interpret', lambda: False)
-    assert pk.lrn_auto_mode(256) == 'full'     # norm2: fwd+bwd 2.16x
-    assert pk.lrn_auto_mode(96) == 'hybrid'    # norm1: fwd 1.90x, bwd loses
-    assert pk.lrn_auto_mode(50) == 'xla'       # ragged channel count
-    assert pk.lrn_auto_mode(24) == 'xla'       # below the measured floor
-    monkeypatch.setenv('CXXNET_PALLAS', '0')
-    assert pk.lrn_auto_mode(256) == 'xla'
-    monkeypatch.setenv('CXXNET_PALLAS', '1')
-    assert pk.lrn_auto_mode(96) == 'full'
-
-
-def test_lrn_pallas_under_jit():
-    rng = np.random.RandomState(2)
-    x = jnp.asarray(rng.rand(4, 2, 2, 16).astype(np.float32))
-    f = jax.jit(lambda x: lrn_pallas(x, 5, 0.001, 0.75, 1.0))
-    np.testing.assert_allclose(np.asarray(f(x)),
-                               lrn_ref(np.asarray(x), 5, 0.001, 0.75, 1.0),
-                               rtol=1e-5, atol=1e-6)
+from cxxnet_tpu.ops.pallas_kernels import pallas_matmul
 
 
 @pytest.mark.parametrize('m,k,n', [(100, 64, 70), (256, 512, 256)])
@@ -112,22 +15,6 @@ def test_pallas_matmul(m, k, n):
     b = rng.randn(k, n).astype(np.float32)
     out = np.asarray(pallas_matmul(jnp.asarray(a), jnp.asarray(b)))
     np.testing.assert_allclose(out, a @ b, rtol=1e-4, atol=1e-4)
-
-
-def test_lrn_layer_uses_pallas_when_enabled(monkeypatch):
-    monkeypatch.setenv('CXXNET_PALLAS', '1')
-    from cxxnet_tpu.layers import ForwardContext, NodeSpec, create_layer
-    from cxxnet_tpu.layers.base import get_layer_type
-    rng = np.random.RandomState(4)
-    x = rng.rand(2, 3, 3, 8).astype(np.float32)
-    layer = create_layer(get_layer_type('lrn'))
-    layer.set_param('local_size', '5')
-    layer.infer_shapes([NodeSpec(8, 3, 3)])
-    ctx = ForwardContext(is_train=False)
-    out = layer.forward({}, [jnp.asarray(x)], ctx)[0]
-    np.testing.assert_allclose(np.asarray(out),
-                               lrn_ref(x, 5, 0.001, 0.75, 1.0),
-                               rtol=1e-5, atol=1e-6)
 
 
 def test_clamp_tile():
@@ -151,19 +38,6 @@ def test_pallas_matmul_grad():
                                rtol=1e-4, atol=1e-4)
     np.testing.assert_allclose(np.asarray(db), np.asarray(a.T @ g),
                                rtol=1e-4, atol=1e-4)
-
-
-def test_lrn_pallas_rows_equal_channels():
-    # regression: padded row count == channel count must not misroute the
-    # band matrix (positional BlockSpec dispatch in _lrn_call)
-    from cxxnet_tpu.ops import pallas_kernels as pk
-    rng = np.random.RandomState(6)
-    c = pk._ROW_TILE
-    x = jnp.asarray(rng.rand(pk._ROW_TILE // 4, 2, 2, c).astype(np.float32))
-    out = pk.lrn_pallas(x, 5, 0.001, 0.75, 1.0)
-    np.testing.assert_allclose(np.asarray(out),
-                               lrn_ref(np.asarray(x), 5, 0.001, 0.75, 1.0),
-                               rtol=1e-4, atol=1e-5)
 
 
 class TestFlashAttention:
@@ -250,22 +124,6 @@ def test_attn_use_flash_gate(monkeypatch):
     assert pk.attn_use_flash(64)
     monkeypatch.setenv('CXXNET_PALLAS', '0')
     assert not pk.attn_use_flash(16384, batch=2, heads=8)
-
-
-def test_lrn_auto_gate_scoped_to_single_device(monkeypatch):
-    """The auto LRN hybrid must stand down inside multi-device GSPMD
-    programs (no sharding rule for the opaque pallas_call); explicit
-    use_pallas=1 still forces it.  The mesh size is threaded per-program
-    through ForwardContext, not a process global."""
-    from cxxnet_tpu.layers import ForwardContext
-    from cxxnet_tpu.ops import pallas_kernels as pk
-    monkeypatch.delenv('CXXNET_PALLAS', raising=False)
-    monkeypatch.setattr(pk, '_interpret', lambda: False)
-    assert pk.lrn_auto_mode(256, spmd_devices=1) == 'full'
-    assert pk.lrn_auto_mode(256, spmd_devices=8) == 'xla'
-    monkeypatch.setenv('CXXNET_PALLAS', '1')
-    assert pk.lrn_auto_mode(256, spmd_devices=8) == 'full'
-    assert ForwardContext(is_train=False).spmd_devices == 1
 
 
 def test_matmul_wide_n_preset_numerics():

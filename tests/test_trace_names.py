@@ -176,11 +176,6 @@ def _grad(fn):
 
 
 KERNEL_CASES = {
-    'lrn_fwd': lambda: (lambda x: pk.lrn_pallas(x, 5, 1e-4, 0.75, 1.0),
-                        (_f32(2, 4, 4, 16),)),
-    'lrn_bwd': lambda: (_grad(lambda x: pk.lrn_pallas(x, 5, 1e-4, 0.75,
-                                                      1.0)),
-                        (_f32(2, 4, 4, 16),)),
     'matmul': lambda: (pk.pallas_matmul, (_f32(8, 16), _f32(16, 8))),
     'matmul_nt': lambda: (_grad(pk.pallas_matmul),
                           (_f32(8, 16), _f32(16, 8))),
@@ -220,7 +215,7 @@ def test_pallas_call_carries_its_name(name):
 
 def test_every_pallas_call_site_is_named_from_the_table():
     """No ``pl.pallas_call(`` under ``ops/`` without a ``name=`` that is a
-    literal of the table, or the caller's (``_lrn_call``)."""
+    literal of the table."""
     sites = 0
     for path in glob.glob(os.path.join(REPO, 'cxxnet_tpu', 'ops', '*.py')):
         with open(path) as f:
@@ -233,10 +228,9 @@ def test_every_pallas_call_site_is_named_from_the_table():
                 kw = {k.arg: k.value for k in node.keywords}
                 assert 'name' in kw, f'{path}:{node.lineno}'
                 v = kw['name']
-                assert (isinstance(v, ast.Name) and v.id == 'name') or (
-                    isinstance(v, ast.Constant)
-                    and v.value in pk.KERNEL_NAMES), f'{path}:{node.lineno}'
-    assert sites == 11
+                assert isinstance(v, ast.Constant) \
+                    and v.value in pk.KERNEL_NAMES, f'{path}:{node.lineno}'
+    assert sites == 10
 
 
 # --- B: hub spans on the profiler's clock -----------------------------------
@@ -400,14 +394,15 @@ HAND_MADE = """
 # Ops (ns):                                       op_name in the HLO text
 #   fusion.1     [1000, 2000) and [6000, 7200)     none of its own; what
 #                                                  it calls: jvp(l00_conv_c1)
-#   lrn_fwd.1    [2000, 2500) and [7200, 7700)     jvp(l03_lrn)
-#   lrn_bwd.1    [2500, 3500) and [7700, 8500)     transpose(jvp(l03_lrn))
+#   matmul.1     [2000, 2500) and [7200, 7700)     jvp(l05_fullc_fc)
+#   matmul_nt.1  [2500, 3500) and [7700, 8500)     transpose(jvp(
+#                                                  l05_fullc_fc))
 #   fusion.2     [3500, 4000) and [8500, 9000)     .../update/mul
 #   copy.7       [4000, 4100) and [9000, 9100)     none
 #   fusion.1     [10500, 10600)                    outside the step program
-# a step: l00_conv_c1 fwd (1000 + 1200) / 2 = 1100 ns; l03_lrn fwd 500, bwd
-# (1000 + 800) / 2 = 900; update 500; other 100; kernels lrn_fwd 500,
-# lrn_bwd 900.
+# a step: l00_conv_c1 fwd (1000 + 1200) / 2 = 1100 ns; l05_fullc_fc fwd 500,
+# bwd (1000 + 800) / 2 = 900; update 500; other 100; kernels matmul 500,
+# matmul_nt 900.
 planes {
   id: 1
   name: "/device:TPU:0"
@@ -432,8 +427,8 @@ planes {
     events { metadata_id: 8 offset_ps: 9500000 duration_ps: 100000 }
   }
   event_metadata { key: 1 value { id: 1 name: "%fusion.1 = bf16[8]{0} fusion(bf16[8]{0} %p), kind=kOutput, calls=%fused_computation.1" } }
-  event_metadata { key: 2 value { id: 2 name: "%lrn_fwd.1 = (bf16[8,128]{1,0}, f32[8,128]{1,0}) custom-call(bf16[8,128]{1,0} %bitcast), custom_call_target=\\"tpu_custom_call\\"" } }
-  event_metadata { key: 3 value { id: 3 name: "%lrn_bwd.1 = bf16[8,128]{1,0} custom-call(bf16[8,128]{1,0} %bitcast.2), custom_call_target=\\"tpu_custom_call\\"" } }
+  event_metadata { key: 2 value { id: 2 name: "%matmul.1 = (bf16[8,128]{1,0}, f32[8,128]{1,0}) custom-call(bf16[8,128]{1,0} %bitcast), custom_call_target=\\"tpu_custom_call\\"" } }
+  event_metadata { key: 3 value { id: 3 name: "%matmul_nt.1 = bf16[8,128]{1,0} custom-call(bf16[8,128]{1,0} %bitcast.2), custom_call_target=\\"tpu_custom_call\\"" } }
   event_metadata { key: 4 value { id: 4 name: "%fusion.2 = f32[8]{0} fusion(f32[8]{0} %p.2), kind=kLoop, calls=%fused_computation.2" } }
   event_metadata { key: 5 value { id: 5 name: "%copy.7 = f32[8]{0} copy(f32[8]{0} %p.3)" } }
   event_metadata { key: 7 value { id: 7 name: "jit_train_step(123)" } }
@@ -446,8 +441,8 @@ HAND_MADE_HLO = """
 }
 ENTRY %main (p: bf16[8]) -> f32[8] {
   %fusion.1 = bf16[8]{0} fusion(%p), kind=kOutput, calls=%fused_computation.1
-  %lrn_fwd.1 = (bf16[8,128]{1,0}, f32[8,128]{1,0}) custom-call(%bitcast), custom_call_target="tpu_custom_call", metadata={op_name="jit(train_step)/jvp(l03_lrn)/lrn_fwd/pallas_call" stack_frame_id=9}
-  %lrn_bwd.1 = bf16[8,128]{1,0} custom-call(%bitcast.2), custom_call_target="tpu_custom_call", metadata={op_name="jit(train_step)/transpose(jvp(l03_lrn))/lrn_bwd/pallas_call" stack_frame_id=2}
+  %matmul.1 = (bf16[8,128]{1,0}, f32[8,128]{1,0}) custom-call(%bitcast), custom_call_target="tpu_custom_call", metadata={op_name="jit(train_step)/jvp(l05_fullc_fc)/matmul/pallas_call" stack_frame_id=9}
+  %matmul_nt.1 = bf16[8,128]{1,0} custom-call(%bitcast.2), custom_call_target="tpu_custom_call", metadata={op_name="jit(train_step)/transpose(jvp(l05_fullc_fc))/matmul_nt/pallas_call" stack_frame_id=2}
   %fusion.2 = f32[8]{0} fusion(%p.2), kind=kLoop, calls=%fused_computation.2, metadata={op_name="jit(train_step)/update/mul"}
   ROOT %copy.7 = f32[8]{0} copy(%p.3)
 }
@@ -478,17 +473,18 @@ def test_device_time_by_scope_on_a_hand_made_trace():
     ops, modules = (list(l.events) for l in plane.lines)
     table = profiler.reduce_by_scope(ops, modules, HAND_MADE_HLO)
     assert table['module'] == 'jit_train_step(123)' and table['steps'] == 2
-    want = {('l00_conv_c1', 'fwd'): 1100e-6, ('l03_lrn', 'fwd'): 500e-6,
-            ('l03_lrn', 'bwd'): 900e-6, ('update', '-'): 500e-6,
+    want = {('l00_conv_c1', 'fwd'): 1100e-6,
+            ('l05_fullc_fc', 'fwd'): 500e-6,
+            ('l05_fullc_fc', 'bwd'): 900e-6, ('update', '-'): 500e-6,
             ('other', '-'): 100e-6}
     assert set(table['scopes']) == set(want)
     for key, ms in want.items():
         assert table['scopes'][key] == pytest.approx(ms), key
-    assert table['kernels'] == {'lrn_fwd': pytest.approx(500e-6),
-                                'lrn_bwd': pytest.approx(900e-6)}
+    assert table['kernels'] == {'matmul': pytest.approx(500e-6),
+                                'matmul_nt': pytest.approx(900e-6)}
     lines = profiler.format_scope_table(table)
     assert lines[1].split('\t')[:2] == ['profile-scope', 'l00_conv_c1']
-    assert 'profile-kernel\tlrn_bwd\t0.001' in lines
+    assert 'profile-kernel\tmatmul_nt\t0.001' in lines
     # without the program's text every event is 'other'; kernels keep names
     bare = profiler.reduce_by_scope(ops, modules, '')
     assert bare['scopes'] == {('other', '-'): pytest.approx(3100e-6)}
