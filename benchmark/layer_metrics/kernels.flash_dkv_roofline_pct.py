@@ -1,0 +1,18 @@
+"""``kernels.flash_dkv_roofline_pct`` - LAYER Pallas kernels
+(``ops/attention.py``: JAX's ``flash_attention`` backward kernel of keys and
+values); UNIT %; MOVES ``samples_per_s``; cells of a conf with ``mla`` layers
+on one chip.
+
+``kernel_costs.flash_attention_dkv`` (the scores again, ``do v^T``, ``p^T
+do`` and ``ds^T q``: four products a pair) times one call a layer, over the
+device time a step of the events named ``flash_mha_bwd_dkv...``, against the
+chip's peaks.  Compute-bound."""
+
+from benchmark import kernel_costs
+
+LAYER, UNIT, MOVES = 'kernels', '%', 'samples_per_s'
+
+
+def read(run):
+    return kernel_costs.attention_roofline(
+        run, 'flash_mha_bwd_dkv', kernel_costs.flash_attention_dkv, recomputed=False)

@@ -1,6 +1,8 @@
 """Config-key drift: code vs. doc tables (rule ``config-key-drift``).
 
-``main.py``/``wrapper.py`` parse their config keys through two idioms —
+``main.py``/``wrapper.py`` (and, since the sequence layers, ``layers/
+sequence.py`` and ``io/iter_tokens.py``, documented in ``doc/sequence.md``)
+parse their config keys through two idioms —
 the ``simple`` string-key dispatch table inside ``set_param`` and
 ``name == '<key>'`` section-marker comparisons.  Both are extracted
 statically here and cross-checked against the key tables in
@@ -23,8 +25,10 @@ from .core import Finding, Module, Repo
 RULES = ('config-key-drift',)
 
 #: config-parsing sources and the doc files whose tables document them
-KEY_SOURCES = ('cxxnet_tpu/main.py', 'cxxnet_tpu/wrapper.py')
-DOC_FILES = ('doc/tasks.md', 'doc/io.md', 'doc/trainer.md')
+KEY_SOURCES = ('cxxnet_tpu/main.py', 'cxxnet_tpu/wrapper.py',
+               'cxxnet_tpu/layers/sequence.py', 'cxxnet_tpu/io/iter_tokens.py')
+DOC_FILES = ('doc/tasks.md', 'doc/io.md', 'doc/trainer.md',
+             'doc/sequence.md')
 
 _KEY_RE = re.compile(r'^[a-z_][a-z0-9_]*(\.[a-z_][a-z0-9_]*)*$')
 

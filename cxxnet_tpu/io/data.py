@@ -277,6 +277,10 @@ def create_iterator(cfg: List[ConfigEntry]) -> IIterator:
                     from .iter_imbin import ImageBinIterator
                     src = ImageBinIterator()
                 it = BatchAdaptIterator(AugmentIterator(src))
+            elif val == 'synth_tokens':
+                assert it is None, 'synth_tokens cannot chain over another'
+                from .iter_tokens import SynthTokenIterator
+                it = SynthTokenIterator()
             elif val == 'threadbuffer':
                 assert it is not None, 'must specify input of threadbuffer'
                 it = ThreadBufferIterator(it)

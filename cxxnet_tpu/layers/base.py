@@ -55,6 +55,16 @@ kChConcat = 28
 kPRelu = 29
 kBatchNorm = 30
 kFixConnect = 31
+# sequence layers (layers/sequence.py); no reference counterpart, ids of
+# this repo's own, stable on disk like the rest
+kEmbedding = 40
+kRMSNorm = 41
+kMLA = 42
+kSwiGLU = 43
+kMoE = 44
+kMTPJoin = 45
+kLMHeadLoss = 46
+kSeqSlice = 48
 kPairTestGap = 1024
 
 _NAME2TYPE = {
@@ -68,6 +78,9 @@ _NAME2TYPE = {
     'insanity': kInsanity, 'insanity_max_pooling': kInsanityPooling,
     'l2_loss': kL2Loss, 'multi_logistic': kMultiLogistic,
     'ch_concat': kChConcat, 'prelu': kPRelu, 'batch_norm': kBatchNorm,
+    'embedding': kEmbedding, 'rmsnorm': kRMSNorm, 'mla': kMLA,
+    'swiglu': kSwiGLU, 'moe': kMoE, 'mtp_join': kMTPJoin,
+    'lm_head_loss': kLMHeadLoss, 'seq_slice': kSeqSlice,
 }
 _TYPE2NAME = {v: k for k, v in _NAME2TYPE.items()}
 _TYPE2NAME[kMaxPooling] = 'max_pooling'  # keep canonical names on collision
@@ -297,6 +310,12 @@ class Layer:
     type_id: int = -1
     # fields that participate in weight decay / tag-scoped lr ('wmat'/'bias')
     param_fields: Sequence[str] = ()
+    # sequence layers (layers/sequence.py): the layer's forward is
+    # checkpointed in a training step; it returns step statistics beside
+    # its outputs (``forward_with_stats``); its input is integer token ids
+    recompute = False
+    has_stats = False
+    takes_token_ids = False
 
     def __init__(self, name: str = ''):
         self.name = name

@@ -1,0 +1,534 @@
+"""Sequence layers: what a decoder-only language model with latent
+attention, routed experts and a multi-token-prediction head is made of, as
+conf layer types on the same ``Net`` graph as the CNN zoo (doc/sequence.md).
+
+The node contract.  A *sequence node* is ``NodeSpec(c=d, y=1, x=seq)``,
+stored ``(batch, 1, seq, d)`` like any NHWC node.  Token ids travel as a
+matrix node ``(batch, n)`` that stays integer from the iterator through
+``stage_batch`` to the embedding (``Net.takes_token_ids``).  Labels are
+columns of the label matrix, one per token and head (``label_vec[0,2*seq)
+= label`` for a main and a multi-token-prediction head).
+
+One layer type a sublayer, so that the scope a conf layer gets in a trace
+(``lNN_mla``, ``lNN_moe``) splits the step by sublayer.  The residual layers
+(``mla``, ``swiglu``, ``moe``) hold their pre-norm and their residual add
+inside, take and return the residual stream, and are recomputed in the
+backward pass (``recompute``): what is saved between them is the residual
+stream alone.
+
+Parameters are float32 masters; products run in the context's compute type
+with float32 accumulation; norms, softmax, router scores, rotary angles and
+the loss are float32 inside.
+"""
+
+from __future__ import annotations
+
+import math
+
+import jax
+import jax.numpy as jnp
+
+from ..ops.attention import causal_attention
+from ..parallel import moe as moe_ops
+from .base import (Layer, NodeSpec, Params, kEmbedding, kLMHeadLoss, kMLA,
+                   kMoE, kMTPJoin, kRMSNorm, kSeqSlice, kSwiGLU,
+                   register_layer)
+from .loss import LossLayerBase
+
+
+def rms_norm(x, gamma, eps: float):
+    """``x * rsqrt(mean(x^2) + eps) * gamma`` over the last axis, float32
+    inside, ``x.dtype`` out."""
+    x32 = x.astype(jnp.float32)
+    var = jnp.mean(x32 * x32, axis=-1, keepdims=True)
+    return (x32 * jax.lax.rsqrt(var + eps)
+            * gamma.astype(jnp.float32)).astype(x.dtype)
+
+
+def rope(x, theta: float):
+    """Rotary positions over the last axis of ``x`` (..., seq, heads, dim),
+    all ``dim`` of them, half-split layout: the pair of ``x[..., i]`` is
+    ``x[..., i + dim/2]``.  Position = index along ``seq``."""
+    seq, dim = x.shape[-3], x.shape[-1]
+    half = dim // 2
+    inv = theta ** (-jnp.arange(half, dtype=jnp.float32) * 2.0 / dim)
+    ang = jnp.arange(seq, dtype=jnp.float32)[:, None] * inv[None, :]
+    cos, sin = jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]
+    x32 = x.astype(jnp.float32)
+    a, b = x32[..., :half], x32[..., half:]
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
+                           axis=-1).astype(x.dtype)
+
+
+def swiglu(x, w_gate, w_up, w_down):
+    dt = x.dtype
+    g = jnp.dot(x, w_gate.astype(dt), preferred_element_type=jnp.float32)
+    u = jnp.dot(x, w_up.astype(dt), preferred_element_type=jnp.float32)
+    h = (jax.nn.silu(g) * u).astype(dt)
+    return jnp.dot(h, w_down.astype(dt), preferred_element_type=jnp.float32)
+
+
+class SequenceLayer(Layer):
+    """Shared by the layers below: the model width and ``eps`` keys, the
+    initialiser, and the sequence node's shape."""
+
+    def __init__(self, name: str = ''):
+        super().__init__(name=name)
+        self.eps = 1e-5
+
+    def set_param(self, name: str, val: str) -> None:
+        super().set_param(name, val)
+        if name == 'eps':
+            self.eps = float(val)
+
+    @staticmethod
+    def _seq_spec(spec: NodeSpec, what: str) -> NodeSpec:
+        if spec.y != 1:
+            raise ValueError(f'{what}: input must be a sequence node '
+                             f'(c=d, y=1, x=seq), got {spec}')
+        return spec
+
+    def _w(self, rng, i: int, shape, dtype):
+        fan_in, fan_out = shape[-2], shape[-1]
+        return self.param.rand_init_weight(jax.random.fold_in(rng, i), shape,
+                                           fan_in, fan_out, dtype)
+
+
+@register_layer
+class SeqSliceLayer(SequenceLayer):
+    """``seq_len`` columns of a matrix of token ids from ``offset`` on: the
+    model's tokens (offset 0) and the multi-token-prediction module's (offset
+    1) are windows of one staged row of ``seq_len + 1`` ids.  ``seq_len`` is
+    a global key of the conf, so that one pair changes the sequence length
+    (with ``input_shape`` and the ``label_vec`` ranges)."""
+
+    type_name = 'seq_slice'
+    type_id = kSeqSlice
+    takes_token_ids = True
+
+    def __init__(self, name: str = ''):
+        super().__init__(name=name)
+        self.offset, self.length = 0, 0
+
+    def set_param(self, name, val):
+        super().set_param(name, val)
+        if name == 'offset':
+            self.offset = int(val)
+        if name == 'seq_len':
+            self.length = int(val)
+
+    def infer_shapes(self, in_specs):
+        (spec,) = in_specs
+        if not spec.is_mat or self.offset + self.length > spec.x \
+                or self.length <= 0:
+            raise ValueError(
+                f'seq_slice: [{self.offset}, {self.offset + self.length}) '
+                f'of a matrix node of {spec.x} ids')
+        return [NodeSpec(1, 1, self.length)]
+
+    def forward(self, params, inputs, ctx):
+        return [inputs[0][:, self.offset:self.offset + self.length]]
+
+
+@register_layer
+class EmbeddingLayer(SequenceLayer):
+    """``(batch, seq)`` ids -> ``(batch, 1, seq, nhidden)``.  The table has
+    ``vocab_held`` rows: a sliced vocabulary is a smaller vocabulary, and
+    ``vocab_published`` is kept for the record."""
+
+    type_name = 'embedding'
+    type_id = kEmbedding
+    param_fields = ('wmat',)
+    takes_token_ids = True
+
+    def __init__(self, name: str = ''):
+        super().__init__(name=name)
+        self.vocab_held, self.vocab_published = 0, 0
+
+    def set_param(self, name, val):
+        super().set_param(name, val)
+        if name == 'vocab_held':
+            self.vocab_held = int(val)
+        if name == 'vocab_published':
+            self.vocab_published = int(val)
+
+    def infer_shapes(self, in_specs):
+        (spec,) = in_specs
+        if not spec.is_mat:
+            raise ValueError('embedding: input must be a matrix of ids')
+        if self.vocab_held <= 0 or self.param.num_hidden <= 0:
+            raise ValueError('embedding: set vocab_held and nhidden')
+        return [NodeSpec(self.param.num_hidden, 1, spec.x)]
+
+    def init_params(self, rng, in_specs, dtype=jnp.float32) -> Params:
+        return {'wmat': self._w(rng, 0, (self.vocab_held,
+                                         self.param.num_hidden), dtype)}
+
+    def forward(self, params, inputs, ctx):
+        ids = inputs[0].astype(jnp.int32)
+        table = params['wmat'].astype(ctx.compute_dtype)
+        return [jnp.take(table, ids, axis=0)[:, None]]
+
+
+@register_layer
+class RMSNormLayer(SequenceLayer):
+    type_name = 'rmsnorm'
+    type_id = kRMSNorm
+    param_fields = ('gamma',)
+
+    def infer_shapes(self, in_specs):
+        return [self._seq_spec(in_specs[0], 'rmsnorm')]
+
+    def init_params(self, rng, in_specs, dtype=jnp.float32) -> Params:
+        return {'gamma': jnp.ones((in_specs[0].c,), dtype)}
+
+    def forward(self, params, inputs, ctx):
+        return [rms_norm(inputs[0], params['gamma'], self.eps)]
+
+
+@register_layer
+class LatentAttentionLayer(SequenceLayer):
+    """Multi-head latent attention in the expanded (training) form, with its
+    pre-norm and its residual: ``h + MLA(RMSNorm(h))``, no biases.
+
+    ``c_q = RMSNorm(x W_qa)``; ``[q_nope | q_rope] = c_q W_qb``;
+    ``[c_kv | k_r] = x W_kva``; ``[k_nope | v] = RMSNorm(c_kv) W_kvb``;
+    ``q_rope`` and ``k_r`` (one head, shared by all) rotated; scores
+    ``(q_nope . k_nope + q_rope . k_rope) / sqrt(nope + rope)``, causal
+    softmax, ``out = concat(P v) W_o``.  No cache, no absorption."""
+
+    type_name = 'mla'
+    type_id = kMLA
+    param_fields = ('norm', 'wq_a', 'q_norm', 'wq_b', 'wkv_a', 'kv_norm',
+                    'wkv_b', 'wo')
+    recompute = True
+
+    def __init__(self, name: str = ''):
+        super().__init__(name=name)
+        self.nhead = 0
+        self.q_lora_rank = self.kv_lora_rank = 0
+        self.nope = self.rope_dim = self.v_dim = 0
+        self.rope_theta = 10000.0
+
+    def set_param(self, name, val):
+        super().set_param(name, val)
+        if name == 'nhead':
+            self.nhead = int(val)
+        if name == 'q_lora_rank':
+            self.q_lora_rank = int(val)
+        if name == 'kv_lora_rank':
+            self.kv_lora_rank = int(val)
+        if name == 'qk_nope_head_dim':
+            self.nope = int(val)
+        if name == 'qk_rope_head_dim':
+            self.rope_dim = int(val)
+        if name == 'v_head_dim':
+            self.v_dim = int(val)
+        if name == 'rope_theta':
+            self.rope_theta = float(val)
+
+    def infer_shapes(self, in_specs):
+        if min(self.nhead, self.q_lora_rank, self.kv_lora_rank, self.nope,
+               self.rope_dim, self.v_dim) <= 0 or self.rope_dim % 2:
+            raise ValueError(
+                'mla: set nhead, q_lora_rank, kv_lora_rank, '
+                'qk_nope_head_dim, qk_rope_head_dim (even) and v_head_dim')
+        return [self._seq_spec(in_specs[0], 'mla')]
+
+    def init_params(self, rng, in_specs, dtype=jnp.float32) -> Params:
+        d, h = in_specs[0].c, self.nhead
+        return {
+            'norm': jnp.ones((d,), dtype),
+            'wq_a': self._w(rng, 0, (d, self.q_lora_rank), dtype),
+            'q_norm': jnp.ones((self.q_lora_rank,), dtype),
+            'wq_b': self._w(rng, 1, (self.q_lora_rank,
+                                     h * (self.nope + self.rope_dim)), dtype),
+            'wkv_a': self._w(rng, 2, (d, self.kv_lora_rank + self.rope_dim),
+                             dtype),
+            'kv_norm': jnp.ones((self.kv_lora_rank,), dtype),
+            'wkv_b': self._w(rng, 3, (self.kv_lora_rank,
+                                      h * (self.nope + self.v_dim)), dtype),
+            'wo': self._w(rng, 4, (h * self.v_dim, d), dtype),
+        }
+
+    def forward(self, params, inputs, ctx):
+        h = inputs[0][:, 0]                                  # (b, s, d)
+        b, s, _ = h.shape
+        dt, nh = h.dtype, self.nhead
+        dot = lambda a, w: jnp.dot(                          # noqa: E731
+            a, w.astype(dt), preferred_element_type=jnp.float32).astype(dt)
+        x = rms_norm(h, params['norm'], self.eps)
+        c_q = rms_norm(dot(x, params['wq_a']), params['q_norm'], self.eps)
+        q = dot(c_q, params['wq_b']).reshape(b, s, nh,
+                                             self.nope + self.rope_dim)
+        ckv = dot(x, params['wkv_a'])
+        c_kv, k_r = ckv[..., :self.kv_lora_rank], ckv[..., self.kv_lora_rank:]
+        kv = dot(rms_norm(c_kv, params['kv_norm'], self.eps),
+                 params['wkv_b']).reshape(b, s, nh, self.nope + self.v_dim)
+        q_rope = rope(q[..., self.nope:], self.rope_theta)
+        k_rope = rope(k_r[:, :, None, :], self.rope_theta)
+        q = jnp.concatenate([q[..., :self.nope], q_rope], axis=-1)
+        k = jnp.concatenate(
+            [kv[..., :self.nope],
+             jnp.broadcast_to(k_rope, (b, s, nh, self.rope_dim))], axis=-1)
+        v = kv[..., self.nope:]
+        o = causal_attention(
+            q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
+            v.transpose(0, 2, 1, 3),
+            1.0 / math.sqrt(self.nope + self.rope_dim), ctx.spmd_devices)
+        o = o.transpose(0, 2, 1, 3).reshape(b, s, nh * self.v_dim)
+        out = jnp.dot(o, params['wo'].astype(dt),
+                      preferred_element_type=jnp.float32)
+        return [(h.astype(jnp.float32) + out).astype(dt)[:, None]]
+
+
+@register_layer
+class SwiGLULayer(SequenceLayer):
+    """The dense gated FFN with its pre-norm and residual:
+    ``h + W_down(silu(W_gate x) * (W_up x))``, ``x = RMSNorm(h)``."""
+
+    type_name = 'swiglu'
+    type_id = kSwiGLU
+    param_fields = ('norm', 'wgate', 'wup', 'wdown')
+    recompute = True
+
+    def infer_shapes(self, in_specs):
+        if self.param.num_hidden <= 0:
+            raise ValueError('swiglu: set nhidden (the FFN width)')
+        return [self._seq_spec(in_specs[0], 'swiglu')]
+
+    def init_params(self, rng, in_specs, dtype=jnp.float32) -> Params:
+        d, f = in_specs[0].c, self.param.num_hidden
+        return {'norm': jnp.ones((d,), dtype),
+                'wgate': self._w(rng, 0, (d, f), dtype),
+                'wup': self._w(rng, 1, (d, f), dtype),
+                'wdown': self._w(rng, 2, (f, d), dtype)}
+
+    def forward(self, params, inputs, ctx):
+        h = inputs[0]
+        x = rms_norm(h, params['norm'], self.eps)
+        y = swiglu(x, params['wgate'], params['wup'], params['wdown'])
+        return [(h.astype(jnp.float32) + y).astype(h.dtype)]
+
+
+@register_layer
+class MoELayer(SequenceLayer):
+    """Routed experts of which this chip holds ``experts_held`` from
+    ``expert_first`` on, a shared expert, pre-norm and residual inside.
+
+    ``s = sigmoid(x W_r)`` over all ``experts_published`` experts, float32;
+    chosen = top-``experts_per_token`` of ``s + b``; ``w_e =
+    routed_scaling_factor * s_e / sum_chosen s`` (the sum over all chosen,
+    held here or not); ``y = sum_{e chosen and held} w_e SwiGLU_e(x) +
+    SwiGLU_shared(x)``.  What the experts held elsewhere would add is left
+    out; with ``experts_held = experts_published`` this is the whole layer.
+    No capacity: nothing is dropped at any imbalance
+    (``parallel/moe.held_experts_ffn``).
+
+    Besides its output the layer counts, for the step's statistics, the
+    assignments that landed on held experts and the largest held expert's
+    load over the mean."""
+
+    type_name = 'moe'
+    type_id = kMoE
+    param_fields = ('norm', 'router', 'router_bias', 'wgate', 'wup', 'wdown',
+                    'sgate', 'sup', 'sdown')
+    recompute = True
+    has_stats = True
+
+    def __init__(self, name: str = ''):
+        super().__init__(name=name)
+        self.experts_published = self.experts_held = 0
+        self.expert_first = 0
+        self.experts_per_token = 1
+        self.routed_scaling_factor = 1.0
+        self.shared_experts = 1
+
+    def set_param(self, name, val):
+        super().set_param(name, val)
+        if name == 'experts_published':
+            self.experts_published = int(val)
+        if name == 'experts_held':
+            self.experts_held = int(val)
+        if name == 'expert_first':
+            self.expert_first = int(val)
+        if name == 'experts_per_token':
+            self.experts_per_token = int(val)
+        if name == 'routed_scaling_factor':
+            self.routed_scaling_factor = float(val)
+        if name == 'shared_experts':
+            self.shared_experts = int(val)
+
+    def infer_shapes(self, in_specs):
+        pub, held = self.experts_published, self.experts_held
+        if self.param.num_hidden <= 0 or pub <= 0 or not 0 < held <= pub \
+                or not 0 <= self.expert_first <= pub - held \
+                or not 0 < self.experts_per_token <= pub:
+            raise ValueError(
+                'moe: set nhidden (the expert width), experts_published, '
+                'experts_held <= experts_published, expert_first and '
+                'experts_per_token')
+        return [self._seq_spec(in_specs[0], 'moe')]
+
+    def init_params(self, rng, in_specs, dtype=jnp.float32) -> Params:
+        d, f = in_specs[0].c, self.param.num_hidden
+        e, sf = self.experts_held, f * self.shared_experts
+        p = {'norm': jnp.ones((d,), dtype),
+             'router': self._w(rng, 0, (d, self.experts_published), dtype),
+             # the choice's correction bias: zero, and nothing updates it
+             # (it reaches the choice alone, so its gradient is zero)
+             'router_bias': jnp.zeros((self.experts_published,), dtype),
+             'wgate': self._w(rng, 1, (e, d, f), dtype),
+             'wup': self._w(rng, 2, (e, d, f), dtype),
+             'wdown': self._w(rng, 3, (e, f, d), dtype)}
+        if sf:
+            p.update(sgate=self._w(rng, 4, (d, sf), dtype),
+                     sup=self._w(rng, 5, (d, sf), dtype),
+                     sdown=self._w(rng, 6, (sf, d), dtype))
+        return p
+
+    def forward_with_stats(self, params, inputs, ctx):
+        h = inputs[0]
+        b, _, s, d = h.shape
+        x = rms_norm(h, params['norm'], self.eps).reshape(b * s, d)
+        idx, weights = moe_ops.sigmoid_topk_route(
+            x, params['router'], params['router_bias'],
+            self.experts_per_token, self.routed_scaling_factor)
+        y, sizes = moe_ops.held_experts_ffn(
+            x, idx, weights, params['wgate'], params['wup'], params['wdown'],
+            self.expert_first)
+        if 'sgate' in params:
+            y = y + swiglu(x, params['sgate'], params['sup'], params['sdown'])
+        out = (h.astype(jnp.float32) + y.reshape(h.shape)).astype(h.dtype)
+        sizes = sizes.astype(jnp.float32)
+        local = jnp.sum(sizes)
+        stats = {
+            'moe.local_assignment_share':
+                local / float(b * s * self.experts_per_token),
+            'moe.load_max_over_mean':
+                jnp.max(sizes) * self.experts_held / jnp.maximum(local, 1.0)}
+        return [out], stats
+
+    def forward(self, params, inputs, ctx):
+        return self.forward_with_stats(params, inputs, ctx)[0]
+
+
+@register_layer
+class MTPJoinLayer(SequenceLayer):
+    """The joint of a multi-token-prediction module (DeepSeek-V3 report,
+    2.2): ``[RMSNorm_e(Emb(t_{i+1})) ; RMSNorm_h(h_i)] W_eh``, inputs the
+    next tokens' embeddings and the main stack's output before its final
+    norm, in that order."""
+
+    type_name = 'mtp_join'
+    type_id = kMTPJoin
+    param_fields = ('enorm', 'hnorm', 'wmat')
+
+    def infer_shapes(self, in_specs):
+        if len(in_specs) != 2 or in_specs[0] != in_specs[1]:
+            raise ValueError('mtp_join: two sequence nodes of one shape '
+                             '(embeddings of the next tokens, hidden)')
+        return [self._seq_spec(in_specs[0], 'mtp_join')]
+
+    def init_params(self, rng, in_specs, dtype=jnp.float32) -> Params:
+        d = in_specs[0].c
+        return {'enorm': jnp.ones((d,), dtype),
+                'hnorm': jnp.ones((d,), dtype),
+                'wmat': self._w(rng, 0, (2 * d, d), dtype)}
+
+    def forward(self, params, inputs, ctx):
+        emb, h = inputs
+        x = jnp.concatenate([rms_norm(emb, params['enorm'], self.eps),
+                             rms_norm(h, params['hnorm'], self.eps)],
+                            axis=-1)
+        return [jnp.dot(x, params['wmat'].astype(x.dtype),
+                        preferred_element_type=jnp.float32).astype(x.dtype)]
+
+
+@register_layer
+class LMHeadLossLayer(LossLayerBase):
+    """The output head and its loss in one layer: ``(batch, 1, seq, d)`` ->
+    probabilities ``(batch, 1, seq, vocab_held)`` through one untied,
+    bias-free ``wmat``, and softmax cross-entropy a token, mean over the
+    sequence's tokens, then the loss layers' common ``grad_scale /
+    (batch_size * update_period)`` over the batch.
+
+    The loss never forms a sequence's whole logits: ``chunk_tokens`` tokens
+    at a time, each chunk's logits recomputed in the backward pass, so what
+    is live is one ``(chunk, vocab)`` float32 slab and its gradient.  The
+    probabilities of :meth:`forward` are whole, and exist only where
+    somebody reads the node.
+
+    One input a head, all through the same ``wmat``: the main stack's
+    output, then each multi-token-prediction module's in depth order.
+    Input ``k`` is scored against columns ``[k * seq, (k + 1) * seq)`` of
+    the ``target`` label field and weighs ``head_weight[k]`` in the loss."""
+
+    type_name = 'lm_head_loss'
+    type_id = kLMHeadLoss
+    param_fields = ('wmat',)
+
+    def __init__(self, name: str = ''):
+        super().__init__(name=name)
+        self.vocab_held, self.vocab_published = 0, 0
+        self.head_weight = []
+        self.chunk_tokens = 1024
+
+    def set_param(self, name, val):
+        super().set_param(name, val)
+        if name in ('vocab_held', 'vocab_published', 'chunk_tokens'):
+            setattr(self, name, int(val))
+        if name == 'head_weight':
+            self.head_weight = [float(t) for t in val.split(',')]
+
+    def infer_shapes(self, in_specs):
+        if self.vocab_held <= 0:
+            raise ValueError('lm_head_loss: set vocab_held')
+        if any(s != in_specs[0] or s.y != 1 for s in in_specs):
+            raise ValueError('lm_head_loss: sequence nodes of one shape')
+        self.head_weight = self.head_weight or [1.0] * len(in_specs)
+        if len(self.head_weight) != len(in_specs):
+            raise ValueError('lm_head_loss: one head_weight an input')
+        return [NodeSpec(self.vocab_held, 1, s.x) for s in in_specs]
+
+    def init_params(self, rng, in_specs, dtype=jnp.float32) -> Params:
+        d = in_specs[0].c
+        return {'wmat': self.param.rand_init_weight(
+            jax.random.fold_in(rng, 0), (d, self.vocab_held), d,
+            self.vocab_held, dtype)}
+
+    def forward(self, params, inputs, ctx):
+        return [jax.nn.softmax(
+            jnp.dot(x, params['wmat'].astype(x.dtype),
+                    preferred_element_type=jnp.float32), axis=-1)
+            for x in inputs]
+
+    def loss(self, params, inputs, labels, ctx, mask=None):
+        s = inputs[0].shape[2]
+        per_inst = sum(
+            weight * self._nll_mean(x, params['wmat'],
+                                    labels[:, k * s:(k + 1) * s])
+            for k, (x, weight) in enumerate(zip(inputs, self.head_weight)))
+        if mask is not None:
+            per_inst = per_inst * mask
+        return jnp.sum(per_inst) * self.scale
+
+    def _nll_mean(self, hidden, w_head, labels):
+        """(batch,) mean cross-entropy over a sequence's tokens, a chunk of
+        tokens at a time."""
+        b, _, s, d = hidden.shape
+        chunk = math.gcd(s, self.chunk_tokens)
+        x = hidden.reshape(b, s // chunk, chunk, d).swapaxes(0, 1)
+        y = labels.astype(jnp.int32).reshape(b, s // chunk,
+                                             chunk).swapaxes(0, 1)
+        w = w_head.astype(hidden.dtype)
+
+        @jax.checkpoint
+        def nll_sum(xc, yc):                       # (b, chunk, d) -> (b,)
+            logits = jnp.dot(xc, w, preferred_element_type=jnp.float32)
+            logp = jax.nn.log_softmax(logits, axis=-1)
+            return -jnp.sum(jnp.take_along_axis(logp, yc[..., None],
+                                                axis=-1)[..., 0], axis=-1)
+
+        return jnp.sum(jax.lax.map(lambda a: nll_sum(*a), (x, y)),
+                       axis=0) / s

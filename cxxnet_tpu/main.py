@@ -738,6 +738,13 @@ class LearnTask:
             else:
                 n, _ = self._round(plan, tracer, batch_counter, start)
                 batch_counter += n
+            if self.test_io == 0 and self.net_trainer.params is not None:
+                # the round ends when the device has finished its last
+                # step, not when the host has dispatched it: with
+                # eval_train = 0 nothing else waits, and steps/sec (and
+                # the MFU gauge) would read above the chip's peak
+                import jax
+                jax.block_until_ready(self.net_trainer.params)
             dt_round = time.monotonic() - t_round
             # settle the one-step-deferred divergence gate (no-op unless
             # nan_action=halt / nan_breaker armed the check)

@@ -72,7 +72,10 @@ def layer_fields(type_id: int):
         return ('wmat', 'bias')
     if type_id in (lbase.kPRelu, lbase.kBias):
         return ('bias',)
-    return ()
+    # a layer with no reference file to match: every field it declares, in
+    # that order
+    cls = lbase.LAYER_REGISTRY.get(type_id)
+    return tuple(cls.param_fields) if cls is not None else ()
 
 
 def to_disk_layout(type_id: int, field: str, arr: np.ndarray,

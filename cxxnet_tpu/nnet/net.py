@@ -89,6 +89,12 @@ class Net:
         self._build_sibling_fusion()
         self._build_blockdiag_fusion()
         self._build_convact_fusion()
+        # sequence nets (layers/sequence.py): node 0 holds integer token ids
+        # when every layer that reads it says so
+        readers0 = [self.layers[i] for i, info in enumerate(cfg.layers)
+                    if 0 in info.nindex_in]
+        self.takes_token_ids = bool(readers0) and all(
+            l.takes_token_ids for l in readers0)
 
     def _layer_scopes(self) -> List[str]:
         """One ``jax.named_scope`` name per conf layer, from the conf
@@ -651,7 +657,13 @@ class Net:
         """Host batches arrive NCHW (c,y,x per instance); convert to the
         on-device layout (NHWC images, flat matrices) and activation dtype.
         Integer (uint8 pixel) batches are welcome — shipping raw bytes and
-        casting on device quarters host->device traffic."""
+        casting on device quarters host->device traffic.  Token ids (a
+        net whose first layers take them) stay the integers they are: a
+        compute-type cast would fold ids above 256 together."""
+        if self.takes_token_ids:
+            if not jnp.issubdtype(batch.dtype, jnp.integer):
+                batch = batch.astype(jnp.int32)
+            return batch.reshape(batch.shape[0], -1)
         batch = batch.astype(compute_dtype)
         if batch.ndim == 2:
             spec = self.node_specs[0]
@@ -685,7 +697,7 @@ class Net:
     def forward(self, params: Params, batch, ctx: ForwardContext,
                 labels: Optional[LabelInfo] = None, loss_mask=None,
                 extra_data=None, capture=None,
-                identity_layers=frozenset()):
+                identity_layers=frozenset(), stats=None):
         """Run the graph.  Returns (node_values, total_loss).
 
         ``node_values[j]`` holds every node's final value (post loss-layer
@@ -700,6 +712,9 @@ class Net:
         replaces the listed 1-in layers with a pass-through (how the
         fold pass retires a folded BN without rewriting the graph
         indices the params tree is keyed by).
+
+        ``stats``: a dict that receives what layers count beside their
+        outputs (``Layer.has_stats``), keyed ``<scope>/<name>``.
         """
         cfg = self.cfg
         values: List[Optional[jax.Array]] = [None] * cfg.num_nodes
@@ -768,11 +783,31 @@ class Net:
                                 params, values, members)):
                             fused_bd[m] = v
                     outs = [fused_bd[i]]
+                elif layer.has_stats or (layer.recompute and ctx.is_train):
+                    outs = self._sequence_layer_outputs(layer, lp, ins, lctx,
+                                                        stats, i)
                 else:
                     outs = layer.forward(lp, ins, lctx)
             for j, v in zip(info.nindex_out, outs):
                 values[j] = v
         return values, total_loss
+
+    def _sequence_layer_outputs(self, layer, lp, ins, lctx, stats, i: int):
+        """A sequence layer's outputs: checkpointed in a training step
+        where the layer asks for it (its inputs are what is saved, its
+        inside is recomputed in the backward pass), its statistics handed
+        to ``stats`` under the layer's scope."""
+        def run(lp, ins):
+            if layer.has_stats:
+                return layer.forward_with_stats(lp, ins, lctx)
+            return layer.forward(lp, ins, lctx), {}
+        if layer.recompute and lctx.is_train:
+            run = jax.checkpoint(run)
+        outs, found = run(lp, ins)
+        if stats is not None:
+            for name, value in found.items():
+                stats[f'{self.layer_scopes[i]}/{name}'] = value
+        return outs
 
     def node_index(self, name: str) -> int:
         """Resolve a node by name or ``top[-k]`` syntax

@@ -24,6 +24,7 @@ Reference semantics preserved:
 
 from __future__ import annotations
 
+import collections
 import os
 import re
 import struct
@@ -60,6 +61,18 @@ def _apply_input_norm(data, norm):
         return data
     mean, scale = norm
     return (data.astype(jnp.float32) - mean) * scale
+
+
+def _over_layers(stats):
+    """Per-layer step statistics (``<scope>/<name>``, ``Net.forward``)
+    folded over the layers: the worst layer of a ``*max_over_mean``, the
+    mean of anything else."""
+    by_name = {}
+    for key, value in stats.items():
+        by_name.setdefault(key.split('/', 1)[1], []).append(value)
+    return {name: (jnp.max(jnp.stack(v)) if name.endswith('max_over_mean')
+                   else jnp.mean(jnp.stack(v)))
+            for name, v in by_name.items()}
 
 
 def parse_devices(val: str) -> List[int]:
@@ -131,6 +144,9 @@ class NetTrainer:
         self._pending_loss = None  # (step, device loss) deferred one step
         self._loss_listeners: List = []   # add_loss_listener
         self._step_avals = None    # the first dispatched step's arguments
+        # what the newest steps counted beside their loss (device scalars,
+        # nothing fetched in the step loop): read by step_stats()
+        self._step_stats = collections.deque(maxlen=512)
         self.compute_dtype = jnp.float32
         self.dev = ''              # the dev= value; '' = default device
         self.metric = MetricSet()
@@ -345,10 +361,12 @@ class NetTrainer:
                                  max_round=max_round,
                                  compute_dtype=compute_dtype,
                                  spmd_devices=spmd)
+            stats = {}
             values, loss = net.forward(params, data, ctx,
                                        labels=net.make_label_info(label),
-                                       loss_mask=mask, extra_data=extra)
-            return loss, [values[i] for i in eval_ids]
+                                       loss_mask=mask, extra_data=extra,
+                                       stats=stats)
+            return loss, ([values[i] for i in eval_ids], _over_layers(stats))
 
         return loss_fn
 
@@ -376,7 +394,7 @@ class NetTrainer:
 
         def train_step(params, opt_state, grad_acc, data, label, extra, mask,
                        rng, epoch, rnd, do_update, norm=()):
-            (loss, evals), grads = jax.value_and_grad(
+            (loss, (evals, stats)), grads = jax.value_and_grad(
                 loss_fn, has_aux=True)(params, data, label, extra, mask,
                                        rng, rnd, norm)
             # the scopes below and Net.forward's one per conf layer are
@@ -401,7 +419,7 @@ class NetTrainer:
                         updater_type, hypers, params, grad_acc, opt_state,
                         epoch)
                     grad_acc = jax.tree.map(jnp.zeros_like, grad_acc)
-            return params, opt_state, grad_acc, loss, evals
+            return params, opt_state, grad_acc, loss, evals, stats
 
         net = self.net
         compute_dtype = self.compute_dtype
@@ -409,21 +427,25 @@ class NetTrainer:
 
         spmd = self._mesh.devices.size
 
-        def forward_step(params, data, extra, rnd, norm=()):
+        def forward_step(params, data, extra, rnd, nodes, norm=()):
             data = _apply_input_norm(data, norm)
             ctx = ForwardContext(is_train=False, rng=None, round=rnd,
                                  max_round=max_round,
                                  compute_dtype=compute_dtype,
                                  spmd_devices=spmd)
             values, _ = net.forward(params, data, ctx, extra_data=extra)
-            return values
+            # the nodes asked for and no other: what nobody reads is
+            # never computed (every node of an 8k-token sequence would not
+            # fit beside the optimizer state)
+            return [values[i] for i in nodes]
 
         # ledger-routed jit (obs/programs.py): the plain jax.jit C++
         # dispatch, plus a /programs row per compiled signature
         self._train_step_fn = self._prog_step.jit(
             train_step, static_argnames=('do_update',),
             donate_argnums=(0, 1, 2))
-        self._forward_fn = self._prog_forward.jit(forward_step)
+        self._forward_fn = self._prog_forward.jit(
+            forward_step, static_argnames=('nodes',))
         self._stack_jit = None     # mesh may have changed: rebuild lazily
 
     def compile_multi_step(self, n_steps: int, train_eval: bool = False):
@@ -491,7 +513,7 @@ class NetTrainer:
                 mask = jax.lax.dynamic_index_in_dim(
                     mask_stack, t % nstack, keepdims=False)
                 rng = jax.random.fold_in(base_rng, 1 + (sc0 + t) * 131 + rnd)
-                (loss, evals), grads = jax.value_and_grad(
+                (loss, (evals, _)), grads = jax.value_and_grad(
                     loss_fn, has_aux=True)(params, data, label, (), mask,
                                            rng, rnd, norm)
                 if nan_skip:
@@ -610,7 +632,7 @@ class NetTrainer:
 
         def grad_step(params, data, label, extra, mask, rng, rnd,
                       norm=()):
-            (loss, _evals), grads = jax.value_and_grad(
+            (loss, _aux), grads = jax.value_and_grad(
                 loss_fn, has_aux=True)(params, data, label, extra, mask,
                                        rng, rnd, norm)
             return loss, grads
@@ -910,11 +932,13 @@ class NetTrainer:
                                                sharding=x.sharding),
                 (data, label, extra, mask, norm)), do_update)
         with span('train.launch', 'train', k=1, update=self.sample_counter):
-            (self.params, self.opt_state, self.grad_acc, loss, evals) = \
-                self._train_step_fn(self.params, self.opt_state,
-                                    self.grad_acc, data, label, extra, mask,
-                                    rng, self.epoch_counter, self.round,
-                                    do_update=do_update, norm=norm)
+            (self.params, self.opt_state, self.grad_acc, loss, evals,
+             stats) = self._train_step_fn(
+                 self.params, self.opt_state, self.grad_acc, data, label,
+                 extra, mask, rng, self.epoch_counter, self.round,
+                 do_update=do_update, norm=norm)
+        if stats:
+            self._step_stats.append(dict(stats, loss=loss))
         self._observe_loss(loss)
         for listener in self._loss_listeners:
             listener(loss)
@@ -949,6 +973,23 @@ class NetTrainer:
             mask, jax.random.fold_in(self._rng, 0), self.epoch_counter,
             self.round, do_update=do_update, norm=norm)
 
+    def step_stats(self, clear: bool = True) -> List[Dict[str, float]]:
+        """What the newest steps (at most 512) counted beside their loss
+        - a net with layers that count (``moe``: the share of assignments
+        that landed on held experts, the largest held expert's load over
+        the mean) - as one ``{name: value, 'loss': value}`` a step, oldest
+        first.  The step loop only keeps the device scalars; the fetch is
+        here, and each row goes to the hub as a ``train.step_stats``
+        event.  ``[]`` for a net whose layers count nothing."""
+        rows = [{k: float(v) for k, v in row.items()}
+                for row in jax.device_get(list(self._step_stats))]
+        if clear:
+            self._step_stats.clear()
+            from ..obs import record_event
+            for row in rows:
+                record_event('train.step_stats', 'train', **row)
+        return rows
+
     def add_loss_listener(self, listener) -> None:
         """Call ``listener(loss)`` with every dispatched training step's
         loss, still a device scalar (nothing is fetched here: a listener
@@ -957,6 +998,9 @@ class NetTrainer:
         step order.  For harnesses and monitors; the trainer's own gate
         is :meth:`_observe_loss`."""
         self._loss_listeners.append(listener)
+
+    def remove_loss_listener(self, listener) -> None:
+        self._loss_listeners.remove(listener)
 
     def _observe_loss(self, loss) -> None:
         """Host-side divergence gate over the step's loss.
@@ -1082,7 +1126,7 @@ class NetTrainer:
         do_update = (self.sample_counter + 1) % self.update_period == 0
         rng = jax.random.fold_in(self._rng, 1 + self.sample_counter * 131 +
                                  self.round)
-        (self.params, self.opt_state, self.grad_acc, _, _) = \
+        (self.params, self.opt_state, self.grad_acc, _, _, _) = \
             self._train_step_fn(self.params, self.opt_state, self.grad_acc,
                                 data, label, (), None, rng,
                                 self.epoch_counter, self.round,
@@ -1143,11 +1187,10 @@ class NetTrainer:
         extra = tuple(self._shard_batch(e) for e in batch.extra_data)
         norm = self._norm_args(batch)
         # raw uncentered pixels: same no-bf16-precast rule as stage_batch
-        values = self._forward_fn(self.params,
-                                  self._shard_batch(batch.data,
-                                                    cast=not norm),
-                                  extra, self.round, norm=norm)
-        return [values[i] for i in node_ids]
+        return self._forward_fn(self.params,
+                                self._shard_batch(batch.data, cast=not norm),
+                                extra, self.round, nodes=tuple(node_ids),
+                                norm=norm)
 
     def _forward_nodes(self, batch, node_ids: List[int]) -> List[np.ndarray]:
         return [np.asarray(v)
@@ -1164,6 +1207,10 @@ class NetTrainer:
         if self.eval_train and len(self.train_metric):
             ret += self.train_metric.print('train')
             self.train_metric.clear()
+        rows = self.step_stats()
+        for key in sorted(rows[0]) if rows else ():
+            mean = sum(r[key] for r in rows) / len(rows)
+            ret += f'\ttrain-{key}:{mean:g}'
         if data_iter is None:
             return ret
         self.metric.clear()
@@ -1218,9 +1265,9 @@ class NetTrainer:
                                   cast=not norm)
             ex = tuple(self._shard_batch(pad_rows(e[off:off + take], b))
                        for e in extras)
-            values = self._forward_fn(self.params, d, ex, self.round,
-                                      norm=norm)
-            outs.append(np.asarray(values[nid])[:take])
+            (value,) = self._forward_fn(self.params, d, ex, self.round,
+                                        nodes=(nid,), norm=norm)
+            outs.append(np.asarray(value)[:take])
         if not outs:
             return np.empty((0,), np.float32)
         return np.concatenate(outs, axis=0)

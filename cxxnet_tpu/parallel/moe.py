@@ -104,3 +104,81 @@ def moe_ffn_reference(x, gate_w, w1, w2, capacity_factor: float = 2.0):
     Returns (out, aux) like moe_ffn_local."""
     return moe_ffn_local(x, gate_w, w1, w2, axis_name=None,
                          capacity_factor=capacity_factor)
+
+
+# --- top-k routing over experts of which this chip holds a share -------------
+# What an expert-parallel group asks of each chip, without the exchange: the
+# router keeps its published width, every token picks k of all the experts,
+# and the chip computes its own experts' part of the result for the tokens
+# routed to them.  No capacity, so no token is dropped at any imbalance: the
+# assignments are sorted by held expert into one (tokens * k)-row buffer
+# (those of experts held elsewhere go last) and the products run grouped over
+# it (``lax.ragged_dot``, which skips the rows past the groups: on the v5e
+# its time follows the sum of the group sizes, not the rows; PERF.md PR 29).
+
+def sigmoid_topk_route(x, router_w, router_bias, k: int, scaling: float):
+    """``noaux_tc`` routing with sigmoid scores: ``x``: (T, D),
+    ``router_w``: (D, E), ``router_bias``: (E,), the correction bias that
+    enters the choice and not the weights.  Returns ``idx`` (T, k) int32
+    and ``weights`` (T, k) float32 = ``scaling * s_e / sum_chosen s``.
+    Scores in float32 at the highest precision: a choice between two
+    nearly equal scores should not hang on a bf16 pass."""
+    s = jax.nn.sigmoid(jnp.dot(x.astype(jnp.float32),
+                               router_w.astype(jnp.float32),
+                               precision=lax.Precision.HIGHEST))
+    _, idx = lax.top_k(s + router_bias.astype(jnp.float32), k)
+    chosen = jnp.take_along_axis(s, idx, axis=1)
+    weights = scaling * chosen / jnp.sum(chosen, axis=-1, keepdims=True)
+    return idx.astype(jnp.int32), weights
+
+
+def sort_by_held_expert(idx, first: int, held: int):
+    """Order of the ``T * k`` assignments (row-major over ``idx``) by the
+    held expert they go to, those of experts not held here last; and the
+    number of assignments of each held expert.  Stable, so equal keys keep
+    token order."""
+    local = idx.reshape(-1) - first
+    key = jnp.where((local >= 0) & (local < held), local, held)
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    sizes = jnp.bincount(key, length=held + 1)[:held].astype(jnp.int32)
+    return order, sizes
+
+
+def grouped_swiglu(xs, w_gate, w_up, w_down, sizes):
+    """SwiGLU of each held expert over its rows of ``xs`` (sorted by
+    expert, ``sizes`` rows each): ``w_gate, w_up``: (E, D, F), ``w_down``:
+    (E, F, D).  Rows past the groups come back unspecified."""
+    dt = xs.dtype
+    g = lax.ragged_dot(xs, w_gate.astype(dt), sizes,
+                       preferred_element_type=jnp.float32)
+    u = lax.ragged_dot(xs, w_up.astype(dt), sizes,
+                       preferred_element_type=jnp.float32)
+    h = (jax.nn.silu(g) * u).astype(dt)
+    return lax.ragged_dot(h, w_down.astype(dt), sizes,
+                          preferred_element_type=jnp.float32).astype(dt)
+
+
+def combine_sorted(ys, order, weights, valid):
+    """Scatter the sorted rows' results back to their tokens, each scaled
+    by its routing weight: (T, D) float32.  Rows that are not ``valid``
+    (the assignments of experts held elsewhere) count as zero."""
+    t, k = weights.shape
+    w = jnp.where(valid, weights.reshape(-1)[order], 0.0)
+    contrib = jnp.where(valid[:, None], ys.astype(jnp.float32), 0.0) \
+        * w[:, None]
+    return jnp.zeros((t, ys.shape[1]), jnp.float32).at[order // k].add(
+        contrib)
+
+
+def held_experts_ffn(x, idx, weights, w_gate, w_up, w_down, first: int):
+    """The held experts' part of a top-k MoE layer's result for tokens
+    ``x`` (T, D): (T, D) float32, and the held experts' loads.  The rows
+    past the groups are masked on the way in and on the way out, so that
+    what the grouped products leave there reaches neither the result nor,
+    in the backward pass, the tokens' gradient."""
+    held, k = w_gate.shape[0], idx.shape[1]
+    order, sizes = sort_by_held_expert(idx, first, held)
+    valid = jnp.arange(order.shape[0]) < jnp.sum(sizes)
+    xs = jnp.where(valid[:, None], x[order // k], jnp.zeros((), x.dtype))
+    ys = grouped_swiglu(xs, w_gate, w_up, w_down, sizes)
+    return combine_sorted(ys, order, weights, valid), sizes

@@ -1,0 +1,494 @@
+"""GLM-4.7-Flash's layers as conf layer types (layers/sequence.py) against
+the plain reference (benchmark/references/glm_moe_lite.py) at the tiny
+twin's size: both heads' probabilities, both losses, the gradient of every
+leaf, the chip's share against the whole layer, routing under imbalance, and
+what the multi-token-prediction head shares with the main model."""
+
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+from benchmark import confnet, tokens                          # noqa: E402
+from benchmark.references import glm_moe_lite as R             # noqa: E402
+from cxxnet_tpu.io.data import DataBatch                       # noqa: E402
+from cxxnet_tpu.layers import ForwardContext, NodeSpec         # noqa: E402
+from cxxnet_tpu.layers.sequence import MoELayer                # noqa: E402
+from cxxnet_tpu.nnet.trainer import NetTrainer                 # noqa: E402
+from cxxnet_tpu.utils.config import parse_config_file          # noqa: E402
+
+TINY = os.path.join(ROOT, 'example', 'LM', 'tiny-glm.conf')
+BIG = os.path.join(ROOT, 'example', 'LM', 'GLM-4.7-Flash.ep8.conf')
+DATA = {'successors': 4, 'p_likely': 0.9}
+
+
+def _pairs(path, **over):
+    text = open(path).read()
+    pairs = confnet.drop_sections(confnet.parse_conf(text),
+                                  ('data', 'eval', 'pred'))
+    return pairs + [(k, str(v)) for k, v in over.items()]
+
+
+def _trainer(pairs):
+    tr = NetTrainer(pairs)
+    tr.init_model()
+    return tr
+
+
+def _batch(graph, seed=3, rows=2):
+    ids = tokens.token_rows(seed, rows, graph.seq + 2, graph.vocab, DATA)
+    s = graph.seq
+    label = np.concatenate([ids[:, 1:s + 1], ids[:, 2:s + 2]], 1)
+    return ids, DataBatch(ids[:, None, None, :s + 1],
+                          label.astype(np.float32))
+
+
+@pytest.fixture(scope='module')
+def tiny():
+    """The tiny twin in float32: trainer, graph, a batch, the program's
+    probabilities, loss and gradients, and the reference's."""
+    pairs = _pairs(TINY, seed=5, silent=1)
+    tr = _trainer(pairs)
+    graph = R.build_graph(pairs)
+    ids, batch = _batch(graph)
+    params = jax.device_get(tr.params)
+    staged = tr.stage_batch(batch)
+    loss, grads = tr.compile_grad_step()(
+        tr.params, staged[0], staged[1], (), staged[3],
+        jax.random.PRNGKey(0), 0)
+    probs = {n: tr.extract_feature(batch, n).reshape(2, graph.seq, -1)
+             for n in graph.loss_nodes()}
+    want = R.forward(graph, params, batch.data)
+    total, each, rgrads = R.loss_and_grads(graph, params, batch.data,
+                                           batch.label)
+    return dict(tr=tr, graph=graph, batch=batch, ids=ids, params=params,
+                loss=float(loss), grads=jax.device_get(grads), probs=probs,
+                want=want, total=total, each=each, rgrads=rgrads,
+                pairs=pairs)
+
+
+def test_graph_and_leaves(tiny):
+    tr, graph = tiny['tr'], tiny['graph']
+    assert graph.loss_nodes() == ['logits', 'mtp_logits']
+    assert (graph.seq, graph.vocab, graph.width) == (32, 96, 64)
+    assert tr.net.takes_token_ids
+    # the shared embedding holds no leaves of its own, and both heads are
+    # inputs of the one layer that holds the head's weight
+    share = [i for i, l in enumerate(graph.layers) if l.primary != i]
+    assert [graph.layers[i].type for i in share] == ['embedding']
+    assert all(str(i) not in tr.params for i in share)
+    (head,) = graph.of_type('lm_head_loss')
+    assert head.ins == ['hn', 'mn'] and list(tr.params[str(head.index)]) \
+        == ['wmat']
+
+
+@pytest.mark.parametrize('node', ['logits', 'mtp_logits'])
+def test_probabilities_match_reference(tiny, node):
+    got, want = tiny['probs'][node], tiny['want'][node]
+    assert got.shape == want.shape == (2, 32, 96)
+    np.testing.assert_allclose(np.log(got), np.log(want), atol=2e-5)
+
+
+@pytest.mark.parametrize('node', ['logits', 'mtp_logits'])
+def test_each_loss_matches_reference(tiny, node):
+    graph, batch = tiny['graph'], tiny['batch']
+    (a,) = [h.label_first for h in graph.heads() if h.node == node]
+    y = batch.label[:, a:a + graph.seq].astype(int)[..., None]
+    mine = -np.mean(np.take_along_axis(np.log(tiny['probs'][node]), y, -1))
+    assert abs(mine - tiny['each'][node]) < 1e-5 * abs(tiny['each'][node])
+
+
+def test_step_loss_is_the_weighted_sum(tiny):
+    # batch of 2, loss layers scale by grad_scale / batch_size: the step's
+    # loss is the mean over the batch of main + 0.3 * mtp
+    assert abs(tiny['loss'] - tiny['total']) < 1e-5 * tiny['total']
+    each = tiny['each']
+    assert abs(each['logits'] + 0.3 * each['mtp_logits'] - tiny['total']) \
+        < 1e-5
+
+
+def _leaves():
+    pairs = _pairs(TINY)
+    tr = NetTrainer(pairs + [('dev', 'cpu')])
+    tr.init_net()
+    shapes = jax.eval_shape(tr.net.init_params, jax.random.PRNGKey(0))
+    return [(k, f) for k in sorted(shapes, key=int) for f in sorted(shapes[k])]
+
+
+@pytest.mark.parametrize('layer,field', _leaves())
+def test_gradient_of_every_leaf(tiny, layer, field):
+    got = np.asarray(tiny['grads'][layer][field])
+    want = np.asarray(tiny['rgrads'][int(layer)][field])
+    assert got.shape == want.shape
+    if field == 'router_bias':
+        # it reaches the choice alone: no gradient on either side
+        assert not got.any() and not want.any()
+        return
+    scale = max(float(np.abs(want).max()), 1e-8)
+    assert float(np.abs(got - want).max()) <= 2e-4 * scale, (layer, field)
+
+
+@pytest.mark.parametrize('node', ['logits', 'mtp_logits'])
+def test_bfloat16_program_is_inside_a_band(tiny, node):
+    """bf16 products on float32 masters: log-probabilities within 0.05 of
+    the float32 reference's spread at this size, and well off exact."""
+    tr = _trainer(tiny['pairs'] + [('compute_type', 'bfloat16')])
+    got = tr.extract_feature(tiny['batch'], node).reshape(2, 32, -1)
+    want = np.log(tiny['want'][node])
+    err = np.abs(np.log(got) - want).max() / want.std()
+    assert 1e-4 < err < 0.05, err
+
+
+@pytest.mark.parametrize('chunk', [8, 32])
+def test_chunked_loss_equals_the_loss_over_whole_logits(tiny, chunk):
+    """The head-and-loss layer's loss, a chunk of tokens at a time (and in
+    one chunk), against the cross-entropy of its own whole probabilities:
+    value and the gradient of both inputs and the weight."""
+    from cxxnet_tpu.layers.sequence import LMHeadLossLayer
+    layer = LMHeadLossLayer('head')
+    for key, val in dict(vocab_held=96, head_weight='1,0.3', batch_size=2,
+                         chunk_tokens=chunk).items():
+        layer.set_param(key, str(val))
+    layer.infer_shapes([NodeSpec(64, 1, 32)] * 2)
+    ks = jax.random.split(jax.random.PRNGKey(5), 3)
+    ins = [jax.random.normal(k, (2, 1, 32, 64)) for k in ks[:2]]
+    w = 0.1 * jax.random.normal(ks[2], (64, 96))
+    labels = jnp.asarray(tiny['batch'].label)
+    ctx = ForwardContext(is_train=True)
+
+    def chunked(w, ins):
+        return layer.loss({'wmat': w}, ins, labels, ctx)
+
+    def whole(w, ins):
+        probs = layer.forward({'wmat': w}, ins, ctx)
+        nll = [-jnp.mean(jnp.log(jnp.take_along_axis(
+            p[:, 0], labels[:, k * 32:(k + 1) * 32, None].astype(int),
+            axis=-1))) for k, p in enumerate(probs)]
+        return nll[0] + 0.3 * nll[1]
+    got = jax.value_and_grad(chunked, argnums=(0, 1))(w, ins)
+    want = jax.value_and_grad(whole, argnums=(0, 1))(w, ins)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(a, b, atol=1e-6, rtol=1e-4)
+
+
+@pytest.mark.parametrize('fault', sorted(
+    k for k, v in R.PROBE.items() if v.loss_tokens != 'all'))
+def test_a_fault_in_the_steps_loss_leaves_the_limits(tiny, fault):
+    """The timed program's own step against the reference: its loss and
+    the change of the leaves behind the heads agree as the model is, and a
+    reference whose loss drops or masks tokens (what a fault in the chunked
+    loss would be, seen from the other side) comes out not correct."""
+    graph, ids = tiny['graph'], tiny['ids']
+    tr = _trainer(tiny['pairs'])
+    sides = {v: R.reference_side(graph, tr.params, ids, tiny['probs'], v)
+             for v in (R.MODEL, R.PROBE[fault])}
+    step = R.program_step(tr, graph, ids)
+    found, ok = R.judge(graph, sides[R.MODEL], step)
+    assert ok and found['loss'] < 1e-6, found
+    assert max(found['update'].values()) < 1e-3, found
+    assert sorted(found['update']) == sorted(
+        f'{k}.{f}' for k, f in R.tail_leaves(graph))
+    found, ok = R.judge(graph, sides[R.PROBE[fault]], step)
+    assert not ok and found['loss'] > R.STEP_LOSS_TOLERANCE, found
+    assert max(found['update'].values()) > R.UPDATE_TOLERANCE, found
+
+
+def test_a_state_left_unchanged_reads_one(tiny):
+    graph, ids = tiny['graph'], tiny['ids']
+    tr = _trainer(tiny['pairs'])
+    side = R.reference_side(graph, tr.params, ids, tiny['probs'])
+    step = R.program_step(tr, graph, ids)
+    found, ok = R.judge(graph, side, dict(step, after=step['w']))
+    assert not ok
+    np.testing.assert_allclose(list(found['update'].values()), 1.0)
+
+
+# --- the chip's share against the whole layer -------------------------------
+
+def _moe_layer(first, held, published=8, k=2, bias=None):
+    layer = MoELayer('e')
+    for key, val in dict(nhidden=48, experts_published=published,
+                         experts_held=held, expert_first=first,
+                         experts_per_token=k, routed_scaling_factor=1.8,
+                         init_sigma=0.2).items():
+        layer.set_param(key, str(val))
+    layer.infer_shapes([NodeSpec(64, 1, 32)])
+    return layer
+
+
+def _whole_moe_params(rng):
+    whole = _moe_layer(0, 8)
+    return whole, jax.device_get(whole.init_params(
+        rng, [NodeSpec(64, 1, 32)]))
+
+
+def _share(p, first, held):
+    q = dict(p)
+    for f in ('wgate', 'wup', 'wdown'):
+        q[f] = p[f][first:first + held]
+    return q
+
+
+def _ref_moe(layer_cfg, h, p):
+    l = R.Layer(0, 'moe', '', [], [], {k: str(v) for k, v in
+                                        layer_cfg.items()}, 0)
+    with jax.default_matmul_precision('highest'):
+        out, _ = R.moe(l, jnp.asarray(h), {k: jnp.asarray(v)
+                                           for k, v in p.items()})
+    return np.asarray(out)
+
+
+CFG8 = dict(nhidden=48, experts_published=8, experts_held=8, expert_first=0,
+            experts_per_token=2, routed_scaling_factor=1.8)
+
+
+def test_the_shares_add_up_to_the_whole_layer():
+    """Outputs of the four shares (2 held of 8 each), the shared expert and
+    the residual counted once, add up to the uncut reference's layer."""
+    _, p = _whole_moe_params(jax.random.PRNGKey(1))
+    h = np.asarray(jax.random.normal(jax.random.PRNGKey(2), (2, 1, 32, 64)))
+    ctx = ForwardContext(is_train=False)
+    no_shared = {k: v for k, v in p.items() if not k.startswith('s')}
+    total = np.zeros_like(h)
+    shares = []
+    for first in (0, 2, 4, 6):
+        out, stats = _moe_layer(first, 2).forward_with_stats(
+            _share(no_shared, first, 2), [jnp.asarray(h)], ctx)
+        total += np.asarray(out[0]) - h            # the routed part alone
+        shares.append(float(stats['moe.local_assignment_share']))
+    assert abs(sum(shares) - 1.0) < 1e-6           # every assignment, once
+    shared_only = {**p, 'wgate': p['wgate'][:1] * 0, 'wup': p['wup'][:1] * 0,
+                   'wdown': p['wdown'][:1] * 0}
+    shared = np.asarray(_moe_layer(0, 1).forward(
+        shared_only, [jnp.asarray(h)], ctx)[0]) - h
+    want = _ref_moe(CFG8, h[:, 0], p)
+    np.testing.assert_allclose((h + total + shared)[:, 0], want, atol=2e-5)
+
+
+@pytest.mark.parametrize('favoured', [0, 1, 5])
+def test_nothing_is_dropped_when_every_token_picks_one_expert(favoured):
+    """A correction bias that sends every token to one expert (and its
+    second choice wherever): the layer has no capacity to overflow."""
+    _, p = _whole_moe_params(jax.random.PRNGKey(3))
+    p['router_bias'] = np.zeros(8, np.float32)
+    p['router_bias'][favoured] = 10.0
+    h = np.asarray(jax.random.normal(jax.random.PRNGKey(4), (2, 1, 32, 64)))
+    out, stats = _moe_layer(0, 2).forward_with_stats(
+        _share(p, 0, 2), [jnp.asarray(h)], ForwardContext(is_train=False))
+    want = _ref_moe({**CFG8, 'experts_held': 2}, h[:, 0], _share(p, 0, 2))
+    np.testing.assert_allclose(np.asarray(out[0])[:, 0], want, atol=2e-5)
+    if favoured < 2:
+        # all 64 tokens landed on the favoured held expert: its load is at
+        # least the 64 first choices, of 128 assignments
+        assert float(stats['moe.local_assignment_share']) >= 0.5
+        assert float(stats['moe.load_max_over_mean']) > 1.0
+
+
+def test_mtp_head_shares_embedding_and_head_with_the_main_model(tiny):
+    """One leaf each, two gradients summed: the leaf's gradient is the main
+    head's plus 0.3 times the multi-token-prediction head's."""
+    graph, params, batch = tiny['graph'], tiny['params'], tiny['batch']
+
+    def only(node, scale):
+        g = R.build_graph(tiny['pairs'])
+        for l in g.of_type('lm_head_loss'):
+            l.cfg = dict(l.cfg, head_weight=','.join(
+                str(scale if out == node else 0.0) for out in l.outs))
+        return R.loss_and_grads(g, params, batch.data, batch.label)[2]
+    main, mtp = only('logits', 1.0), only('mtp_logits', 1.0)
+    emb = graph.of_type('embedding')[0].index
+    head = graph.of_type('lm_head_loss')[0].index
+    for layer in (emb, head):
+        both = main[layer]['wmat'] + 0.3 * mtp[layer]['wmat']
+        assert np.abs(mtp[layer]['wmat']).max() > 0
+        got = tiny['grads'][str(layer)]['wmat']
+        assert np.abs(got - both).max() <= 2e-4 * np.abs(both).max()
+
+
+# --- the trainer's part ------------------------------------------------------
+
+def test_token_ids_reach_the_embedding_as_integers():
+    """bf16 compute, ids above 256: a cast to the wire type would fold
+    them together."""
+    pairs = [(k, v) for k, v in _pairs(TINY, compute_type='bfloat16')]
+    pairs = [(k, '1000' if k == 'vocab_held' else v) for k, v in pairs]
+    tr = _trainer(pairs)
+    ids = np.arange(700, 700 + 2 * 33, dtype=np.int32).reshape(2, 33)
+    batch = DataBatch(ids[:, None, None, :], np.zeros((2, 64), np.float32))
+    staged = tr.stage_batch(batch)
+    assert staged[0].dtype == jnp.int32
+    got = tr.extract_feature(batch, 'ids')
+    np.testing.assert_array_equal(got, ids[:, :32])
+    table = np.asarray(tr.params['2']['wmat'])
+    emb = tr.extract_feature(batch, 'e_next').reshape(2, 32, 64)
+    np.testing.assert_allclose(
+        emb, table[ids[:, 1:]].astype(jnp.bfloat16).astype(np.float32))
+
+
+def test_training_through_the_step_loop_learns_and_counts():
+    tr = _trainer(_pairs(TINY, seed=1, silent=1))
+    graph = R.build_graph(_pairs(TINY))
+    staged = [tr.stage_batch(_batch(graph, seed=s)[1]) for s in range(4)]
+    losses = []
+    tr.add_loss_listener(losses.append)
+    for step in range(24):
+        tr.update_staged(staged[step % 4])
+    rows = tr.step_stats()
+    assert len(rows) == 24 and tr.step_stats() == []
+    assert rows[-1]['loss'] < rows[0]['loss'] - 0.5
+    assert abs(rows[0]['loss'] - float(losses[0])) < 1e-6
+    for r in rows:
+        assert 0.0 <= r['moe.local_assignment_share'] <= 1.0
+        assert r['moe.load_max_over_mean'] >= 1.0
+    line = tr.evaluate(None, 'train')
+    assert line == ''                      # drained above
+    assert tr.train_step_flops() > 0
+    text = tr.step_program_text()
+    assert 'l06_moe_moe1' in text and 'l03_mla_attn0' in text
+
+
+def test_recomputation_keeps_the_layer_scope():
+    """The checkpointed layers' forward, recomputation and backward all
+    carry the conf layer's scope, which is what the trace is split by."""
+    tr = _trainer(_pairs(TINY, seed=1, silent=1))
+    graph = R.build_graph(_pairs(TINY))
+    staged = tr.stage_batch(_batch(graph)[1])
+    text = tr._train_step_fn._jit.lower(
+        tr.params, tr.opt_state, tr.grad_acc, staged[0], staged[1], (),
+        staged[3], jax.random.PRNGKey(0), 0, 0,
+        do_update=True).as_text(debug_info=True)
+    for scope in ('jvp(l05_mla_attn1)', 'transpose(jvp(l05_mla_attn1))',
+                  'jvp(l06_moe_moe1)', 'transpose(jvp(l06_moe_moe1))',
+                  'transpose(jvp(l13_lm_head_loss_head))'):
+        assert scope in text, scope
+    assert 'checkpoint' in text or 'remat' in text
+
+
+def test_published_conf_counts_its_parameters():
+    """The example conf's leaves, by shape alone: 706,518,848 parameters,
+    the counts of ISSUE 29's table."""
+    tr = NetTrainer(_pairs(BIG) + [('dev', 'cpu')])
+    tr.init_net()
+    shapes = jax.eval_shape(tr.net.init_params, jax.random.PRNGKey(0))
+    count = lambda d: sum(int(np.prod(a.shape)) for a in d.values())  # noqa
+    graph = R.build_graph(_pairs(BIG))
+    by_type = {}
+    for k, d in shapes.items():
+        by_type.setdefault(graph.layers[int(k)].type, []).append(count(d))
+    assert set(by_type['mla']) == {21_759_232 + 2048}      # + its pre-norm
+    assert set(by_type['moe']) == {8 * 9_437_184 + 9_437_184 + 131_136
+                                   + 2048}
+    assert by_type['swiglu'] == [3 * 2048 * 10240 + 2048]
+    assert by_type['embedding'] == by_type['lm_head_loss'] == [39_649_280]
+    assert sum(count(d) for d in shapes.values()) == 706_518_848
+
+
+def test_train_flops_by_hand():
+    """29.7 TFLOP a step of one 8,192-token sequence, by hand."""
+    graph = R.build_graph(_pairs(BIG))
+    s, d = 8192, 2048
+    attn_proj = (d * 768 + 768 * 20 * 256 + d * 576 + 512 * 20 * 448
+                 + 20 * 256 * d)
+    attn_core = (s * (s + 1) // 2) * 20 * (256 + 256)
+    expert = 3 * d * 1536
+    moe = s * (d * 64 + expert) + (s * 4 * 8 / 64) * expert
+    hand = (6 * (s * attn_proj + attn_core) + s * 3 * d * 10240 + 5 * moe
+            + s * 2 * d * d + 2 * s * d * 19360)
+    assert sum(R.forward_macs(graph).values()) == hand
+    assert abs(R.train_flops_per_sequence(graph) - 29.7e12) < 0.05e12
+
+
+# --- attention, the iterator, the model file ----------------------------------
+
+@pytest.mark.parametrize('dv', [16, 24])
+def test_blocked_attention_equals_the_full_masked_softmax(dv):
+    """The XLA path over blocks of queries (each recomputed in the backward
+    pass) against one full masked softmax: values and all three gradients,
+    value dims equal and unequal to the key dims."""
+    from cxxnet_tpu.ops.attention import causal_attention_xla
+    ks = jax.random.split(jax.random.PRNGKey(7), 3)
+    q = jax.random.normal(ks[0], (2, 3, 64, 16))
+    k = jax.random.normal(ks[1], (2, 3, 64, 16))
+    v = jax.random.normal(ks[2], (2, 3, 64, dv))
+
+    def loss(block):
+        return lambda q, k, v: jnp.sum(jnp.sin(
+            causal_attention_xla(q, k, v, 0.25, block_q=block)))
+    full = jax.value_and_grad(loss(64), argnums=(0, 1, 2))(q, k, v)
+    blocked = jax.value_and_grad(loss(16), argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(jax.tree.leaves(full), jax.tree.leaves(blocked)):
+        np.testing.assert_allclose(a, b, atol=2e-5, rtol=1e-5)
+    # causal: the first position sees itself alone
+    out = causal_attention_xla(q, k, v, 0.25, block_q=16)
+    np.testing.assert_allclose(out[:, :, 0], v[:, :, 0], atol=1e-6)
+
+
+def test_synth_tokens_iterator_feeds_the_conf():
+    from cxxnet_tpu.io.data import create_iterator
+    it = create_iterator([('iter', 'synth_tokens'), ('seq_len', '32'),
+                          ('vocab', '96'), ('num_batches', '3'),
+                          ('batch_size', '2'), ('seed_data', '9'),
+                          ('silent', '1'), ('iter', 'end')])
+    it.init()
+    batches = list(it)
+    assert len(batches) == 3 and len(list(it)) == 3        # every round
+    b = batches[0]
+    assert b.data.shape == (2, 1, 1, 33) and b.data.dtype == np.int32
+    assert b.label.shape == (2, 64) and b.label.dtype == np.float32
+    ids = b.data[:, 0, 0]
+    np.testing.assert_array_equal(b.label[:, :32], ids[:, 1:33])
+    np.testing.assert_array_equal(b.label[:, 32:63], ids[:, 2:33])
+    assert 0 <= ids.min() and ids.max() < 96
+    # a chain, not noise: most tokens are one of their predecessor's four
+    # likely successors, so far fewer distinct pairs than positions
+    again = create_iterator([('iter', 'synth_tokens'), ('seq_len', '32'),
+                             ('vocab', '96'), ('num_batches', '3'),
+                             ('batch_size', '2'), ('seed_data', '9'),
+                             ('silent', '1'), ('iter', 'end')])
+    again.init()
+    np.testing.assert_array_equal(next(iter(again)).data, b.data)
+
+
+def test_model_file_round_trip(tmp_path):
+    """save_model writes every leaf of the sequence layers and load_model
+    reads them back: the same probabilities after the trip."""
+    pairs = _pairs(TINY, seed=2, silent=1)
+    tr = _trainer(pairs)
+    graph = R.build_graph(pairs)
+    batch = _batch(graph)[1]
+    before = tr.extract_feature(batch, 'mtp_logits')
+    path = tmp_path / 'tiny.model'
+    with open(path, 'wb') as f:
+        tr.save_model(f)
+    other = NetTrainer(pairs)
+    with open(path, 'rb') as f:
+        other.load_model(f)
+    for k, d in tr.params.items():
+        for name, a in d.items():
+            np.testing.assert_array_equal(np.asarray(other.params[k][name]),
+                                          np.asarray(a))
+    np.testing.assert_array_equal(other.extract_feature(batch, 'mtp_logits'),
+                                  before)
+
+
+def test_cli_trains_the_tiny_twin(tmp_path, capfd):
+    """``python -m cxxnet_tpu.main`` on the tiny conf: ``task = train``
+    through LearnTask, the round's line carries a falling train-loss."""
+    import re
+    from cxxnet_tpu.main import LearnTask
+    cwd = os.getcwd()
+    os.chdir(tmp_path)
+    try:
+        LearnTask().run([TINY, 'num_round=3', 'silent=1'])
+    finally:
+        os.chdir(cwd)
+    err = capfd.readouterr().err
+    losses = [float(x) for x in re.findall(r'train-loss:([0-9.]+)', err)]
+    assert len(losses) == 3 and losses[-1] < losses[0] - 0.5, err
+    assert 'train-moe.local_assignment_share:' in err

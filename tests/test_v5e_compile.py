@@ -57,3 +57,94 @@ def test_lrn_is_no_custom_call_on_the_v5e(one_chip, shape):
     entry = hlo[hlo.index('ENTRY'):]
     dims = ','.join(map(str, shape))
     assert not re.findall(r'= \(?f32\[%s\]' % dims, entry)
+
+
+# --- the sequence layers' kernels at GLM-4.7-Flash's widths (PR 29) ----------
+
+def _steer_onto_the_chip_path(monkeypatch):
+    """``ops/attention`` asks ``jax.default_backend()``, which is the CPU
+    here: the test steers it, the program has no option for it."""
+    from cxxnet_tpu.ops import attention
+    monkeypatch.setattr(attention, '_use_flash',
+                        lambda q, k, v, spmd: spmd == 1)
+
+
+def test_blocked_attention_compiles_at_the_published_heads(one_chip,
+                                                           monkeypatch):
+    """20 heads of 192 + 64 query/key and 256 value dims over 8,192
+    positions: the Pallas flash kernels, forward and both backward ones,
+    are taken by Mosaic at the block sizes ``ops/attention`` gives them, and
+    no (heads, seq, seq) array is in the program."""
+    from cxxnet_tpu.ops.attention import causal_attention
+    _steer_onto_the_chip_path(monkeypatch)
+
+    def loss(q, k, v):
+        with jax.named_scope('l03_mla'):
+            o = causal_attention(q, k, v, 1.0 / 16.0)
+        return jnp.sum(o.astype(jnp.float32))
+
+    x = jax.ShapeDtypeStruct((1, 20, 8192, 256), jnp.bfloat16,
+                             sharding=one_chip)
+    hlo = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        x, x, x).compile().as_text()
+    assert hlo.count('tpu_custom_call') >= 3
+    assert '8192,8192' not in hlo
+
+
+def test_grouped_expert_products_compile_at_the_published_widths(one_chip):
+    """8 held experts of 2048 x 1536 over the worst case's 32,768 sorted
+    assignments: the three grouped products and their gradients."""
+    from cxxnet_tpu.parallel import moe
+
+    def loss(xs, wg, wu, wd, sizes):
+        return jnp.sum(moe.grouped_swiglu(xs, wg, wu, wd,
+                                          sizes).astype(jnp.float32))
+
+    s = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dt, sharding=one_chip)
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3))).lower(
+        s((32768, 2048), jnp.bfloat16), s((8, 2048, 1536), jnp.float32),
+        s((8, 2048, 1536), jnp.float32), s((8, 1536, 2048), jnp.float32),
+        s((8,), jnp.int32)).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 << 30
+
+
+def test_the_whole_step_fits_the_chip(one_chip, monkeypatch):
+    """The example conf's training step, compiled for one v5e chip from
+    shapes alone: 706.5 M parameters at 16 bytes each plus the step's
+    temporaries stay under the chip's 16.9e9 bytes with room for the
+    forward program."""
+    import os
+    import numpy as np
+    from cxxnet_tpu.nnet.trainer import NetTrainer
+    from cxxnet_tpu.utils.config import parse_config_file
+    _steer_onto_the_chip_path(monkeypatch)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    pairs, skipping = [], False
+    for k, v in parse_config_file(os.path.join(
+            root, 'example', 'LM', 'GLM-4.7-Flash.ep8.conf')):
+        skipping = skipping or k == 'data'
+        if not skipping and k != 'dev':
+            pairs.append((k, v))
+        skipping = skipping and (k, v) != ('iter', 'end')
+    tr = NetTrainer(pairs + [('dev', 'cpu')])
+    tr.init_net()
+    s = lambda a: jax.ShapeDtypeStruct(  # noqa: E731
+        a.shape, a.dtype, sharding=one_chip)
+    params = jax.tree.map(s, jax.eval_shape(tr.net.init_params,
+                                            jax.random.PRNGKey(0)))
+    seq = 8192
+    arg = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dt, sharding=one_chip)
+    compiled = tr._train_step_fn._jit.lower(
+        params, {'m1': params, 'm2': params}, params,
+        arg((1, 1, 1, seq + 1), jnp.int32), arg((1, 2 * seq), jnp.float32),
+        (), arg((1,), jnp.float32), arg((2,), jnp.uint32), 0, 0,
+        do_update=True).compile()
+    m = compiled.memory_analysis()
+    state = sum(int(np.prod(a.shape)) for a in jax.tree.leaves(params)) * 16
+    assert state == 706_518_848 * 16
+    live = (m.argument_size_in_bytes + m.output_size_in_bytes
+            - m.alias_size_in_bytes + m.temp_size_in_bytes)
+    assert m.alias_size_in_bytes >= state          # the state is donated
+    assert live < 14.6e9, live / 2 ** 30           # 16.9e9 on the chip
