@@ -23,6 +23,7 @@ the loss are float32 inside.
 
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
@@ -445,6 +446,90 @@ class MTPJoinLayer(SequenceLayer):
                         preferred_element_type=jnp.float32).astype(x.dtype)]
 
 
+def _to_chunks(a, chunk: int):
+    """``(batch, seq, ...)`` -> ``(seq / chunk, batch, chunk, ...)``."""
+    b, s = a.shape[:2]
+    return a.reshape(b, s // chunk, chunk, *a.shape[2:]).swapaxes(0, 1)
+
+
+def _from_chunks(a):
+    """``(n, batch, chunk, ...)`` -> ``(batch, n * chunk, ...)``."""
+    n, b, chunk = a.shape[:3]
+    return a.swapaxes(0, 1).reshape(b, n * chunk, *a.shape[3:])
+
+
+def _is_label(logits, labels):
+    """Where ``logits``' last index is the row's label: a compare against an
+    iota, which fuses into whatever reads it (a gather of one scalar a row
+    and its scatter do not)."""
+    return jax.lax.broadcasted_iota(
+        jnp.int32, logits.shape, logits.ndim - 1) == labels[..., None]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def chunked_nll_mean(hidden, wmat, labels, chunk: int):
+    """``(batch,)`` mean over a sequence's tokens of the softmax
+    cross-entropy of ``hidden . wmat`` against ``labels``, ``chunk`` tokens
+    at a time: ``hidden`` ``(batch, 1, seq, d)``, ``wmat`` ``(d, vocab)``,
+    ``labels`` ``(batch, seq)`` int32, ``chunk`` a divisor of ``seq``.
+
+    Products in ``hidden``'s dtype with float32 accumulation, softmax in
+    float32.  Forward and backward are written out (no autodiff through a
+    log-softmax and a gather): what is saved beside the three arguments is
+    each token's log-sum-exp ``(batch, seq)`` float32; the backward pass
+    recomputes a chunk's logits by the one product, writes ``(softmax -
+    onehot) * g / seq`` once in ``hidden``'s dtype for the two products that
+    read it, and sums the weight's gradient over chunks in float32."""
+    return _chunked_nll_fwd(hidden, wmat, labels, chunk)[0]
+
+
+def _chunked_nll_fwd(hidden, wmat, labels, chunk):
+    w = wmat.astype(hidden.dtype)
+
+    def one(args):                       # (b, chunk, d), (b, chunk)
+        xc, yc = args
+        logits = jnp.dot(xc, w, preferred_element_type=jnp.float32)
+        top = jnp.max(logits, axis=-1)
+        lse = top + jnp.log(jnp.sum(jnp.exp(logits - top[..., None]),
+                                    axis=-1))
+        target = jnp.sum(jnp.where(_is_label(logits, yc), logits, 0.0),
+                         axis=-1)
+        return lse, jnp.sum(lse - target, axis=-1)
+
+    lse, nll = jax.lax.map(one, (_to_chunks(hidden[:, 0], chunk),
+                                 _to_chunks(labels, chunk)))
+    return (jnp.sum(nll, axis=0) / hidden.shape[2],
+            (hidden, wmat, labels, _from_chunks(lse)))
+
+
+def _chunked_nll_bwd(chunk, saved, g):
+    hidden, wmat, labels, lse = saved
+    w = wmat.astype(hidden.dtype)
+    scale = (g / hidden.shape[2]).astype(jnp.float32)[:, None, None]
+
+    def one(dw, args):
+        xc, yc, lc = args
+        logits = jnp.dot(xc, w, preferred_element_type=jnp.float32)
+        p = jnp.exp(logits - lc[..., None])
+        dlogits = (jnp.where(_is_label(logits, yc), p - 1.0, p)
+                   * scale).astype(xc.dtype)
+        dx = jax.lax.dot_general(dlogits, w, (((2,), (1,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+        dw = dw + jax.lax.dot_general(xc, dlogits,
+                                      (((0, 1), (0, 1)), ((), ())),
+                                      preferred_element_type=jnp.float32)
+        return dw, dx.astype(xc.dtype)
+
+    dw, dx = jax.lax.scan(
+        one, jnp.zeros(wmat.shape, jnp.float32),
+        (_to_chunks(hidden[:, 0], chunk), _to_chunks(labels, chunk),
+         _to_chunks(lse, chunk)))
+    return (_from_chunks(dx)[:, None], dw.astype(wmat.dtype), None)
+
+
+chunked_nll_mean.defvjp(_chunked_nll_fwd, _chunked_nll_bwd)
+
+
 @register_layer
 class LMHeadLossLayer(LossLayerBase):
     """The output head and its loss in one layer: ``(batch, 1, seq, d)`` ->
@@ -453,11 +538,17 @@ class LMHeadLossLayer(LossLayerBase):
     sequence's tokens, then the loss layers' common ``grad_scale /
     (batch_size * update_period)`` over the batch.
 
-    The loss never forms a sequence's whole logits: ``chunk_tokens`` tokens
-    at a time, each chunk's logits recomputed in the backward pass, so what
-    is live is one ``(chunk, vocab)`` float32 slab and its gradient.  The
-    probabilities of :meth:`forward` are whole, and exist only where
-    somebody reads the node.
+    The loss never forms a sequence's whole logits
+    (:func:`chunked_nll_mean`): ``gcd(seq, chunk_tokens)`` tokens at a
+    time, forward and backward written by hand.  Saved between the passes: the layer's input,
+    the labels, ``wmat`` and each token's float32 log-sum-exp.  Recomputed
+    in the backward pass: a chunk's logits, by the one product.  The
+    label's logit is picked by a compare against the vocabulary's index,
+    forward and backward (no gather, no scatter), and a chunk's gradient
+    ``(softmax - onehot) * g / seq`` is written once, in the activations'
+    dtype, for the two products that read it; ``wmat``'s gradient is summed
+    over chunks in float32.  The probabilities of :meth:`forward` are
+    whole, and exist only where somebody reads the node.
 
     One input a head, all through the same ``wmat``: the main stack's
     output, then each multi-token-prediction module's in depth order.
@@ -516,19 +607,6 @@ class LMHeadLossLayer(LossLayerBase):
     def _nll_mean(self, hidden, w_head, labels):
         """(batch,) mean cross-entropy over a sequence's tokens, a chunk of
         tokens at a time."""
-        b, _, s, d = hidden.shape
-        chunk = math.gcd(s, self.chunk_tokens)
-        x = hidden.reshape(b, s // chunk, chunk, d).swapaxes(0, 1)
-        y = labels.astype(jnp.int32).reshape(b, s // chunk,
-                                             chunk).swapaxes(0, 1)
-        w = w_head.astype(hidden.dtype)
-
-        @jax.checkpoint
-        def nll_sum(xc, yc):                       # (b, chunk, d) -> (b,)
-            logits = jnp.dot(xc, w, preferred_element_type=jnp.float32)
-            logp = jax.nn.log_softmax(logits, axis=-1)
-            return -jnp.sum(jnp.take_along_axis(logp, yc[..., None],
-                                                axis=-1)[..., 0], axis=-1)
-
-        return jnp.sum(jax.lax.map(lambda a: nll_sum(*a), (x, y)),
-                       axis=0) / s
+        chunk = math.gcd(hidden.shape[2], self.chunk_tokens)
+        return chunked_nll_mean(hidden, w_head, labels.astype(jnp.int32),
+                                chunk)

@@ -59,6 +59,59 @@ def test_lrn_is_no_custom_call_on_the_v5e(one_chip, shape):
     assert not re.findall(r'= \(?f32\[%s\]' % dims, entry)
 
 
+def _instructions(lines):
+    """(opcode, result type) of each instruction line of a computation."""
+    for line in lines:
+        m = re.match(r'\s+(?:ROOT )?%[\w.\-]+ = (.*?) ([a-z][a-z\-]*)\(', line)
+        if m:
+            yield m.group(2), m.group(1)
+
+
+def test_head_loss_is_products_and_fused_passes_on_the_v5e(one_chip):
+    """``lm_head_loss`` at the benchmark cell's shape (one 8,192-token
+    sequence of 2,048 bf16, 19,360 vocabulary rows, 1,024 tokens a chunk),
+    loss and gradients: no gather, scatter or sort anywhere; a chunk's
+    forward hands one float32 ``(1024, 19360)`` array from one instruction
+    to the next (the logits, to the pass that sums them) and its backward
+    none (the logits' product writes their gradient, in bf16)."""
+    from cxxnet_tpu.layers import ForwardContext, NodeSpec
+    from cxxnet_tpu.layers.sequence import LMHeadLossLayer
+    layer = LMHeadLossLayer('head')
+    for key, val in dict(vocab_held=19360, batch_size=1,
+                         chunk_tokens=1024).items():
+        layer.set_param(key, str(val))
+    layer.infer_shapes([NodeSpec(2048, 1, 8192)])
+
+    def loss(w, x, labels):
+        with jax.named_scope('l19_lm_head_loss'):
+            return layer.loss({'wmat': w}, [x], labels,
+                              ForwardContext(is_train=True))
+
+    s = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dt, sharding=one_chip)
+    hlo = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+        s((2048, 19360), jnp.float32), s((1, 1, 8192, 2048), jnp.bfloat16),
+        s((1, 8192), jnp.float32)).compile().as_text()
+    assert 'transpose(jvp(l19_lm_head_loss))' in hlo
+    comps, name = {}, None
+    for line in hlo.splitlines():
+        m = re.match(r'(?:ENTRY )?%([\w.\-]+) \(.*\{$', line)
+        if m:
+            name = 'ENTRY' if line.startswith('ENTRY') else m.group(1)
+        comps.setdefault(name, []).append(line)
+    assert not [op for lines in comps.values() for op, _ in
+                _instructions(lines) if op in ('gather', 'scatter', 'sort')]
+    bodies = re.findall(r'body=%([\w.\-]+)', hlo)
+    assert len(bodies) == 2                 # the chunks forward, and backward
+    handed = sorted(sum(
+        'f32[1024,19360]' in kind or 'f32[1,1024,19360]' in kind
+        for op, kind in _instructions(comps[where])
+        if op not in ('get-tuple-element', 'bitcast', 'tuple', 'parameter'))
+        for where in bodies + ['ENTRY'])
+    assert handed == [0, 0, 1], handed
+    assert 'bf16[1024,19360]' in hlo
+
+
 # --- the sequence layers' kernels at GLM-4.7-Flash's widths (PR 29) ----------
 
 def _steer_onto_the_chip_path(monkeypatch):
