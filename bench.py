@@ -1672,14 +1672,8 @@ random_type = xavier
 
 
 def bench_cnn_fused() -> int:
-    """graftfuse A/B (doc/kernels.md), three legs in ONE receipt:
+    """graftfuse A/B (doc/kernels.md), two legs in ONE receipt:
 
-    * **train** — fused Pallas conv+bias+relu blocks (``fuse=1``) vs the
-      unfused XLA composition (``fuse=0``), steps/sec by the K-vs-1 scan
-      quotient; final params after identical update streams are
-      twin-asserted within the fused block's pinned tolerance
-      (``ops/pallas_cnn``) IN the bench — a speedup over diverging math
-      is not a speedup;
     * **inference** — a real ``PredictEngine`` with ``fold_bn=1``
       (conv+BN folded at build time, nnet/fold.py) vs the unfolded
       engine, rows/sec; scores twin-asserted within the fold pass's
@@ -1690,16 +1684,14 @@ def bench_cnn_fused() -> int:
       split, with final params bitwise-asserted against the unsplit
       trainer — the split bounds peak HBM, it never changes the math.
 
-    On a cpu host the fused leg runs the Pallas block in interpret mode
-    — the twins are real correctness proofs, the speedups are not chip
-    numbers (the receipt's ``platform`` stamp + self-heal handle that).
+    On a cpu host the twins are real correctness proofs, the speedups
+    are not chip numbers (the receipt's ``platform`` stamp says which).
     """
     import jax
 
     from cxxnet_tpu.nnet.fold import FOLD_ATOL, FOLD_RTOL
     from cxxnet_tpu.nnet.trainer import NetTrainer
     from cxxnet_tpu.obs.programs import get_ledger
-    from cxxnet_tpu.ops.pallas_cnn import _FUSED_ATOL, _FUSED_RTOL
     from cxxnet_tpu.serve.engine import PredictEngine
     from cxxnet_tpu.utils.config import parse_config_string
 
@@ -1724,15 +1716,6 @@ def bench_cnn_fused() -> int:
         for _ in range(n):
             tr.update_on_device(d, lb)
 
-    def param_maxerr(a: NetTrainer, b: NetTrainer) -> float:
-        err = 0.0
-        for lk, fields in a.params.items():
-            for f in fields:
-                err = max(err, float(np.max(np.abs(
-                    np.asarray(a.params[lk][f], np.float32)
-                    - np.asarray(b.params[lk][f], np.float32)))))
-        return err
-
     def steps_per_sec(tr: NetTrainer) -> float:
         dstack = tr.shard_batch_stack(np.stack([data, data]))
         lstack = tr.shard_batch_stack(np.stack([label, label]),
@@ -1748,24 +1731,7 @@ def bench_cnn_fused() -> int:
             lambda: run(m1, 1), lambda: run(mk, steps), steps)
         return 1.0 / per_step
 
-    # ---- leg 1: fused vs unfused training --------------------------------
-    tr_on, tr_off = make('fuse = 1\n'), make('fuse = 0\n')
-    if not tr_on.net._convact_pairs:
-        raise AssertionError('fuse=1 conf paired no conv+relu blocks — '
-                             'the A/B would measure nothing')
-    train_steps(tr_on, 4)
-    train_steps(tr_off, 4)
-    train_err = param_maxerr(tr_on, tr_off)
-    train_twin = bool(np.allclose(0.0, train_err,
-                                  rtol=_FUSED_RTOL, atol=_FUSED_ATOL))
-    if not train_twin:
-        raise AssertionError(
-            f'fused training diverged from unfused: param maxerr '
-            f'{train_err} > pinned {_FUSED_ATOL}')
-    rate_on, rate_off = steps_per_sec(tr_on), steps_per_sec(tr_off)
-    train_speedup = rate_on / rate_off
-
-    # ---- leg 2: conv+BN folded vs plain inference ------------------------
+    # ---- leg 1: conv+BN folded vs plain inference ------------------------
     calib = rng.randn(batch, 3, 12, 12).astype(np.float32)
     srv = NetTrainer(parse_config_string(
         _CNN_FOLD_CONF + f'batch_size = {batch}\n' + _extra_conf()))
@@ -1805,7 +1771,7 @@ def bench_cnn_fused() -> int:
     rows_plain = rows_per_sec(eng_plain)
     infer_speedup = rows_fold / rows_plain
 
-    # ---- leg 3: micro_batch sweep ----------------------------------------
+    # ---- leg 2: micro_batch sweep ----------------------------------------
     splits = [s for s in (1, 2, 4, 8) if batch % s == 0]
     sweep, base_snap = [], None
 
@@ -1816,7 +1782,7 @@ def bench_cnn_fused() -> int:
                 for lk, fields in tr.params.items()}
 
     for split in splits:
-        tr = make(f'fuse = 0\nmicro_batch = {split}\n')
+        tr = make(f'micro_batch = {split}\n')
         train_steps(tr, 3)
         if split == splits[0]:
             base_snap, mb_err = snap(tr), 0.0
@@ -1841,21 +1807,10 @@ def bench_cnn_fused() -> int:
 
     payload = {
         'metric': 'cnn_fused_speedup',
-        # the headline is the BEST leg: the claim is "at least one
-        # fusion wins", each leg's own number rides next to its twin
-        'value': round(max(train_speedup, infer_speedup), 4),
+        'value': round(infer_speedup, 4),
         'unit': 'x',
         'platform': plat,
         'vs_baseline': None,
-        'train': {
-            'speedup': round(train_speedup, 4),
-            'fused_steps_per_sec': round(rate_on, 2),
-            'unfused_steps_per_sec': round(rate_off, 2),
-            'fused_pairs': len(tr_on.net._convact_pairs),
-            'twin_ok': train_twin,
-            'param_max_abs_err': train_err,
-            'rtol': _FUSED_RTOL, 'atol': _FUSED_ATOL,
-        },
         'inference': {
             'speedup': round(infer_speedup, 4),
             'folded_rows_per_sec': round(rows_fold, 2),

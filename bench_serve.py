@@ -18,15 +18,14 @@ next to the training BENCH_*.json ledger.  Two modes:
    "shed": {"expired": E, "pages": P, "rejected": R},
    "gen_cache": {"hit": H, "miss": M}, "slots": S, "pages": PG, ...}
 
-``decode_matrix`` — the serve.flash_decode x serve.dtype A/B grid over
-ONE fixed seeded workload (doc/serving.md "Flash paged decode" /
-"Quantized inference").  Every leg's streams are twin-asserted in-bench
-against offline ``generate`` over that leg's own stored tree (the
-BENCH_SCAN_r01 discipline: a receipt is only emitted for outputs proven
-correct)::
+``decode_matrix`` — the serve.dtype grid over ONE fixed seeded workload
+(doc/serving.md "Quantized inference").  Every leg's streams are
+twin-asserted in-bench against offline ``generate`` over that leg's own
+stored tree (the BENCH_SCAN_r01 discipline: a receipt is only emitted
+for outputs proven correct)::
 
   {"metric": "decode_int8_resident_reduction", "value": X, "unit": "x",
-   "legs": [{"attention": "gather|flash", "dtype": "f32|bf16|int8",
+   "legs": [{"dtype": "f32|bf16|int8",
              "tokens_per_sec": T, "token_p50_ms": P50,
              "token_p99_ms": P99, "resident_bytes": B,
              "twin_checked": N}, ...], "model": {...}}
@@ -263,7 +262,7 @@ def bench_decode(args) -> dict:
 
 
 def bench_decode_matrix(args) -> dict:
-    """A/B grid: gather-vs-flash attention x f32/bf16/int8 serving tier,
+    """The f32/bf16/int8 serving tiers,
     ONE fixed seeded workload per leg so tokens/sec, per-token quantiles
     and resident_bytes compare like for like.  Twin-asserted in-bench."""
     import jax
@@ -283,13 +282,12 @@ def bench_decode_matrix(args) -> dict:
                            (1, int(rng.randint(1, args.max_prompt))))
                .astype(np.int32) for _ in range(n_req)]
 
-    def run_leg(attention: str, dtype: str) -> dict:
+    def run_leg(dtype: str) -> dict:
         svc = DecodeService(
             params, cfg, slots=args.slots, pages=args.pages,
             page_size=args.page_size, max_prompt=args.max_prompt,
             max_new_bound=args.max_new, max_queue=2 * n_req,
-            deadline=600.0, dtype=dtype,
-            flash_decode=1 if attention == 'flash' else 0)
+            deadline=600.0, dtype=dtype)
         try:
             warm = svc.submit_async(prompts[0], args.max_new)
             svc.batcher.wait(warm)            # compile outside the clock
@@ -311,7 +309,7 @@ def bench_decode_matrix(args) -> dict:
                     svc.engine.cfg))[0]
                 got = np.asarray(reqs[i].result)
                 assert (got == off[:len(got)]).all(), (
-                    f'{attention}/{dtype} stream {i} diverged from its '
+                    f'{dtype} stream {i} diverged from its '
                     f'offline twin')
                 checked += 1
             def q(p):
@@ -322,7 +320,7 @@ def bench_decode_matrix(args) -> dict:
                 return round(float(np.quantile(np.asarray(gaps), p)), 4)
 
             return {
-                'attention': attention, 'dtype': dtype,
+                'dtype': dtype,
                 'tokens_per_sec': round(toks / wall, 2),
                 'token_p50_ms': q(0.5),
                 'token_p99_ms': q(0.99),
@@ -333,12 +331,9 @@ def bench_decode_matrix(args) -> dict:
         finally:
             svc.close(60)
 
-    legs = [run_leg(attention, dtype)
-            for attention in ('gather', 'flash')
-            for dtype in ('f32', 'bf16', 'int8')]
-    by = {(l['attention'], l['dtype']): l for l in legs}
-    reduction = (by[('gather', 'f32')]['resident_bytes']
-                 / by[('gather', 'int8')]['resident_bytes'])
+    legs = [run_leg(dtype) for dtype in ('f32', 'bf16', 'int8')]
+    by = {l['dtype']: l for l in legs}
+    reduction = by['f32']['resident_bytes'] / by['int8']['resident_bytes']
     return {
         'metric': 'decode_int8_resident_reduction',
         'value': round(reduction, 2),
@@ -540,8 +535,7 @@ def bench_spec(args) -> dict:
     if out['platform'] == 'cpu':
         # random-init models make any CHEAPER draft disagree with the
         # target (acceptance ~0), and on compute-bound CPU the verify
-        # window saves no arithmetic — the same receipt-reading rule as
-        # BENCH_SERVE_r03's flash rows: cpu legs prove token-equality
+        # window saves no arithmetic: cpu legs prove token-equality
         # and report acceptance; the speed claim is the on-chip one
         # (one K-window pass costs ~one step of HBM weight traffic)
         out['note'] = ('cpu legs prove correctness + acceptance '

@@ -52,8 +52,7 @@ RULES = ('tracer-hygiene',)
 TARGET_FILES = ('cxxnet_tpu/nnet/trainer.py',
                 'cxxnet_tpu/nnet/execution.py',
                 'cxxnet_tpu/serve/decode.py',
-                'cxxnet_tpu/ops/pallas_kernels.py',
-                'cxxnet_tpu/ops/pallas_cnn.py')
+                'cxxnet_tpu/ops/pallas_kernels.py')
 
 #: function-argument positions per wrapper.  lax combinators demand a
 #: `lax` qualifier (``jax.tree.map`` is NOT ``lax.map``); jit/pmap/vmap

@@ -303,8 +303,7 @@ def lm_serve_start(cfg: str):
     params from ``model_in`` (a ``%04d.lm`` tree) or ``seed`` init,
     engine shape ``slots``/``pages``/``page_size``/``max_prompt``/
     ``max_new``/``eos``, batcher knobs ``max_queue``/``max_wait``/
-    ``deadline``, serving tier ``dtype`` (``f32``/``bf16``/``int8``),
-    attention leg ``flash_decode`` (``auto``/``0``/``1``), prefix
+    ``deadline``, serving tier ``dtype`` (``f32``/``bf16``/``int8``), prefix
     sharing ``prefix_share`` (index page cap, 0 = off), greedy
     speculative decoding ``spec_k`` + ``draft.*`` draft-model keys, and
     the graftcache KV tiers ``kv_host_mb``/``kv_disk_mb``/``kv_dir``/

@@ -146,7 +146,7 @@ class LayerParam:
     # μ-cuDNN-style conv microbatching (beyond reference): split the
     # conv's batch axis into this many sequential slices to bound the
     # layer's live workspace; bitwise-equal to unsplit by construction
-    # (ops/pallas_cnn.microbatched_conv) and priced by grafttune's
+    # (layers/conv.microbatched_conv) and priced by grafttune's
     # LedgerGate as a mem_inv knob
     micro_batch: int = 1
 

@@ -14,8 +14,10 @@ Output spatial size matches the reference exactly:
 
 from __future__ import annotations
 
+import functools
 from typing import List
 
+import jax
 import jax.numpy as jnp
 from jax import lax
 
@@ -104,6 +106,74 @@ def _conv_im2col_mb(x, w, strides, pad, groups):
     """im2col adapter for microbatching; the routing gate guarantees
     ``groups == 1`` (im2col targets ungrouped convs)."""
     return conv_im2col(x, w, strides, pad)
+
+
+# --- μ-cuDNN-style convolution microbatching ------------------------------
+#
+# Splitting a convolution's *batch* axis into ``micro_batch`` sequential
+# slices bounds the layer's live workspace (im2col patch tensors, wide
+# activation intermediates) at the cost of dispatching k smaller convs.
+# The forward and dx run per-slice under ``lax.map``; **dw is computed by
+# the one full-batch transpose op**, because a slice-accumulated dw sums in
+# a different order and is NOT bitwise-equal to the unsplit step (measured —
+# see doc/kernels.md).  Under jit the unused full-batch primal is DCE'd, so
+# the anchor costs one conv-transpose, exactly like the unsplit step.  This
+# makes the microbatched step a **bitwise twin** of the unsplit one at every
+# declared split — the property grafttune's LedgerGate relies on when it
+# prices ``micro_batch`` from ``memory_analysis`` peak bytes
+# (tune/space.py, ``mem_inv``).
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4, 5, 6))
+def microbatched_conv(x, w, strides, padding, groups, split, conv_fn):
+    """Run ``conv_fn`` over ``split`` sequential batch slices.
+
+    ``conv_fn(x, w, strides, padding, groups)`` is a module-level
+    callable (hashable, so the trace caches); the batch must divide
+    evenly — callers gate on ``batch % split == 0`` and fall through to
+    the unsplit op otherwise.  Bitwise contract: forward and dx are
+    per-example-independent, so the slice loop reproduces the unsplit
+    values exactly; dw is the one full-batch transpose op (see the
+    comment above) — the whole step is a bitwise twin of ``split=1``.
+    """
+    return _mb_fwd_impl(x, w, strides, padding, groups, split, conv_fn)
+
+
+def _mb_fwd_impl(x, w, strides, padding, groups, split, conv_fn):
+    n = x.shape[0]
+    xs = x.reshape((split, n // split) + x.shape[1:])
+    ys = lax.map(lambda xt: conv_fn(xt, w, strides, padding, groups), xs)
+    return ys.reshape((n,) + ys.shape[2:])
+
+
+def _mb_fwd(x, w, strides, padding, groups, split, conv_fn):
+    y = _mb_fwd_impl(x, w, strides, padding, groups, split, conv_fn)
+    return y, (x, w)
+
+
+def _mb_bwd(strides, padding, groups, split, conv_fn, res, g):
+    x, w = res
+    n = x.shape[0]
+    xs = x.reshape((split, n // split) + x.shape[1:])
+    gs = g.reshape((split, n // split) + g.shape[1:])
+
+    def _slice_dx(pair):
+        xt, gt = pair
+        _, vjp = jax.vjp(
+            lambda xx: conv_fn(xx, w, strides, padding, groups), xt)
+        return vjp(gt)[0]
+
+    dx = lax.map(_slice_dx, (xs, gs)).reshape(x.shape)
+    # dw anchors on the ONE full-batch transpose op: a slice-accumulated
+    # dw reduces in a different order and is NOT bitwise-equal to the
+    # unsplit step (measured; doc/kernels.md).  Under jit the unused
+    # primal recompute is DCE'd away.
+    _, vjp_w = jax.vjp(
+        lambda ww: conv_fn(x, ww, strides, padding, groups), w)
+    dw = vjp_w(g)[0]
+    return dx, dw
+
+
+microbatched_conv.defvjp(_mb_fwd, _mb_bwd)
 
 
 def conv_split(x, w, strides, pad, groups):
@@ -210,7 +280,6 @@ class ConvolutionLayer(Layer):
         mode = self._lowering()
         split = self._micro_split(mode, x.shape[0])
         if split > 1:
-            from ..ops.pallas_cnn import microbatched_conv
             fn = _conv_im2col_mb if mode == 'im2col' else _conv_native_mb
             out = microbatched_conv(x, w, strides, pad, p.num_group,
                                     split, fn)

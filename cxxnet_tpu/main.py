@@ -126,7 +126,6 @@ class LearnTask:
         self.serve_dtype = 'f32'       # serve.dtype: f32 | bf16 | int8
         self.serve_fold_bn = 0         # serve.fold_bn: 1 = fold conv+BN
                                        # at engine build (doc/kernels.md)
-        self.serve_flash = 'auto'      # serve.flash_decode: auto | 0 | 1
         self.serve_prefix_share = 0    # serve.prefix_share index pages (0=off)
         # graftcache: tiered KV prefix cache (doc/serving.md "Tiered KV
         # cache"); tiers need serve.prefix_share > 0
@@ -240,7 +239,6 @@ class LearnTask:
             'serve.mem_budget': ('serve_mem_budget', int),
             'serve.dtype': ('serve_dtype', str),
             'serve.fold_bn': ('serve_fold_bn', int),
-            'serve.flash_decode': ('serve_flash', str),
             'serve.prefix_share': ('serve_prefix_share', int),
             'serve.kv_host_mb': ('serve_kv_host_mb', int),
             'serve.kv_disk_mb': ('serve_kv_disk_mb', int),
@@ -1281,7 +1279,7 @@ class LearnTask:
             # bulk drive: throughput-bound, not latency-bound (the same
             # reasoning as the predict drive's bulk_deadline)
             deadline=max(self.serve_deadline, 60.0),
-            dtype=self.serve_dtype, flash_decode=self.serve_flash,
+            dtype=self.serve_dtype,
             prefix_share=self.serve_prefix_share,
             spec_k=self.serve_spec_k, draft=draft,
             kv_host_mb=self.serve_kv_host_mb,
@@ -1308,8 +1306,7 @@ class LearnTask:
             print(f'serve: decode engine up — {self.serve_slots} slots, '
                   f'{self.serve_pages}x{self.serve_page_size}-token KV '
                   f'pages (slot cache {svc.engine.cache_len}, '
-                  f'dtype={svc.engine.serve_dtype}, '
-                  f'attention={"flash" if svc.engine.use_flash else "gather"}'
+                  f'dtype={svc.engine.serve_dtype}'
                   f', prefix_share={self.serve_prefix_share}'
                   f', spec_k={svc.engine._spec_k}'
                   + (f', shard=tp:{svc.engine._tp} over '
@@ -1520,12 +1517,10 @@ class LearnTask:
         """Apply a candidate ``micro_batch`` to every layer of the LIVE
         trainer and rebuild its step programs: the knob is read at trace
         time (layers/conv.py ``_micro_split``), so an already-compiled
-        program would never see the change.  Re-running the convact
-        fusion pass keeps its micro_batch>1 exclusion honest."""
+        program would never see the change."""
         tr = self.net_trainer
         for layer in tr.net.layers:
             layer.param.micro_batch = int(value)
-        tr.net._build_convact_fusion()
         tr._compile_steps()
 
     def _rebuild_train_iterator(self, nworker: int):
@@ -1616,7 +1611,7 @@ class LearnTask:
                 max_queue=cand.get('max_queue', self.serve_max_queue),
                 max_wait=self.serve_max_wait,
                 deadline=max(self.serve_deadline, 60.0),
-                dtype=self.serve_dtype, flash_decode=self.serve_flash,
+                dtype=self.serve_dtype,
                 prefix_share=self.serve_prefix_share,
                 spec_k=cand.get('spec_k', self.serve_spec_k),
                 draft=draft)

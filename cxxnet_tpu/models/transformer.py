@@ -715,67 +715,10 @@ def verify_step(params, cfg: TransformerConfig, toks, kc, vc, t, w):
     tq = t[:, None] + jnp.arange(K)[None, :]               # (b, K)
     live = ((ar[None, None, :] <= tq[:, :, None])
             & (ar[None, None, :] >= w[:, None, None]))[:, None]  # (b,1,K,T)
-    state = {'kc': kc, 'vc': vc}
     knews, vnews = [], []
     bi = jnp.arange(b)[:, None]
-
-    def attend(i, p, q, k, v):
-        kc = state['kc'].at[i, bi, tq].set(k)
-        vc = state['vc'].at[i, bi, tq].set(v)
-        state['kc'], state['vc'] = kc, vc
-        ki, vi = kc[i], vc[i]
-        s_ = jnp.einsum('bqhd,bkhd->bhqk', q, ki) * scale
-        s_ = jnp.where(live, s_, -jnp.inf)
-        knews.append(k)
-        vnews.append(v)
-        return jnp.einsum(
-            'bhqk,bkhd->bqhd',
-            jax.nn.softmax(s_.astype(jnp.float32),
-                           axis=-1).astype(ki.dtype), vi)
-
-    logits = _window_tokens(params, cfg, toks, attend)
-    return (logits, state['kc'], state['vc'], jnp.stack(knews),
-            jnp.stack(vnews))
-
-
-def verify_step_paged(params, cfg: TransformerConfig, toks, kpool, vpool,
-                      table, t, w):
-    """:func:`verify_step` straight over the PAGED pool — the flash twin
-    (``serve.flash_decode``): each stage scatters its K new K/V rows
-    into their physical pages and hands attention to
-    ``ops.pallas_kernels.paged_flash_verify``, which reads the pages in
-    place with the same per-query live masking.  Returns
-    ``(logits, kpool, vpool)`` (the new rows are already in the pool).
-    Bitwise-equal to gather + :func:`verify_step` (pinned in
-    tests/test_serve_spec.py)."""
-    from ..ops.pallas_kernels import paged_flash_verify
-    b, K = toks.shape
-    ps = kpool.shape[2]
-    hd = cfg.d_model // cfg.num_heads
-    scale = 1.0 / math.sqrt(hd)
-    tq = t[:, None] + jnp.arange(K)[None, :]               # (b, K)
-    page = table[jnp.arange(b)[:, None], tq // ps]
-    off = tq % ps
-    state = {'k': kpool, 'v': vpool}
-
-    def attend(i, p, q, k, v):
-        kp = state['k'].at[i, page, off].set(k)
-        vp = state['v'].at[i, page, off].set(v)
-        state['k'], state['v'] = kp, vp
-        return paged_flash_verify(q, kp[i], vp[i], table, t, w, scale)
-
-    logits = _window_tokens(params, cfg, toks, attend)
-    return logits, state['k'], state['v']
-
-
-def _window_tokens(params, cfg: TransformerConfig, toks, attend):
-    """The (b, K)-window block walk shared by :func:`verify_step` and
-    :func:`verify_step_paged` — :func:`_decode_token`'s body widened to
-    K tokens (same projection/FFN/head call sites, ``attend`` supplies
-    the cache write + attention per stage), with the head applied to
-    EVERY window position instead of just the last."""
-    b, K = toks.shape
-    hd = cfg.d_model // cfg.num_heads
+    # decode_step's block walk widened to K tokens: the same projection,
+    # FFN and head call sites, the head applied to EVERY window position
     h = _rep(qtake(params['embed'], toks))
     for i in range(cfg.num_stages):
         p = jax.tree.map(lambda a, i=i: a[i], params['stages'])
@@ -783,37 +726,23 @@ def _window_tokens(params, cfg: TransformerConfig, toks, attend):
         q = qdot(y, p['wq']).reshape(b, K, cfg.num_heads, hd)
         k = qdot(y, p['wk']).reshape(b, K, cfg.num_heads, hd)
         v = qdot(y, p['wv']).reshape(b, K, cfg.num_heads, hd)
-        attn = attend(i, p, q, k, v)
+        kc = kc.at[i, bi, tq].set(k)
+        vc = vc.at[i, bi, tq].set(v)
+        ki, vi = kc[i], vc[i]
+        s_ = jnp.einsum('bqhd,bkhd->bhqk', q, ki) * scale
+        s_ = jnp.where(live, s_, -jnp.inf)
+        knews.append(k)
+        vnews.append(v)
+        attn = jnp.einsum(
+            'bhqk,bkhd->bqhd',
+            jax.nn.softmax(s_.astype(jnp.float32),
+                           axis=-1).astype(ki.dtype), vi)
         h = h + _rep(qdot(_rep(attn.reshape(b, K, cfg.d_model)),
                           p['wo']))
         y2 = _layer_norm(h, p['ln2_scale'], p['ln2_bias'])
         h = h + _gen_ffn(cfg, p, y2, gather=True)
-    return _rep(qdot(h, params['head'])).astype(jnp.float32)
-
-
-def _decode_token(params, cfg: TransformerConfig, tok, attend):
-    """THE per-token block walk — embed -> [ln1 -> qkv -> attend -> out
-    proj -> ln2 -> ffn] per stage -> head.  ``attend(i, p, q, k, v)``
-    supplies stage ``i``'s cache write + attention ((b, 1, heads, hd) in
-    and out); :func:`decode_step` (dense cache) and
-    :func:`decode_step_paged` (page pool + flash kernel) are both thin
-    attend-closures over this one body, so the cache layouts cannot
-    drift from each other or from the shared projection math."""
-    b = tok.shape[0]
-    hd = cfg.d_model // cfg.num_heads
-    h = _rep(qtake(params['embed'], tok[:, None]))
-    for i in range(cfg.num_stages):
-        p = jax.tree.map(lambda a, i=i: a[i], params['stages'])
-        y = _layer_norm(h, p['ln1_scale'], p['ln1_bias'])
-        q = qdot(y, p['wq']).reshape(b, 1, cfg.num_heads, hd)
-        k = qdot(y, p['wk']).reshape(b, 1, cfg.num_heads, hd)
-        v = qdot(y, p['wv']).reshape(b, 1, cfg.num_heads, hd)
-        attn = attend(i, p, q, k, v)
-        h = h + _rep(qdot(_rep(attn.reshape(b, 1, cfg.d_model)),
-                          p['wo']))
-        y2 = _layer_norm(h, p['ln2_scale'], p['ln2_bias'])
-        h = h + _gen_ffn(cfg, p, y2, gather=True)
-    return _rep(qdot(h[:, -1], params['head'])).astype(jnp.float32)
+    logits = _rep(qdot(h, params['head'])).astype(jnp.float32)
+    return logits, kc, vc, jnp.stack(knews), jnp.stack(vnews)
 
 
 def decode_step(params, cfg: TransformerConfig, tok, kc, vc, t, w):
@@ -849,11 +778,16 @@ def decode_step(params, cfg: TransformerConfig, tok, kc, vc, t, w):
     else:
         # cache slots [0, w) hold bucket-pad K/V: never attended
         live = ((ar <= t) & (ar >= w))[None, None, None, :]
-    state = {'kc': kc, 'vc': vc}
     knews, vnews = [], []
-
-    def attend(i, p, q, k, v):
-        kc, vc = state['kc'], state['vc']
+    # THE per-token block walk: embed -> [ln1 -> qkv -> cache write ->
+    # attend -> out proj -> ln2 -> ffn] per stage -> head
+    h = _rep(qtake(params['embed'], tok[:, None]))
+    for i in range(cfg.num_stages):
+        p = jax.tree.map(lambda a, i=i: a[i], params['stages'])
+        y = _layer_norm(h, p['ln1_scale'], p['ln1_bias'])
+        q = qdot(y, p['wq']).reshape(b, 1, cfg.num_heads, hd)
+        k = qdot(y, p['wk']).reshape(b, 1, cfg.num_heads, hd)
+        v = qdot(y, p['wv']).reshape(b, 1, cfg.num_heads, hd)
         if per_row:
             kc = kc.at[i, jnp.arange(b), t].set(k[:, 0])
             vc = vc.at[i, jnp.arange(b), t].set(v[:, 0])
@@ -862,54 +796,22 @@ def decode_step(params, cfg: TransformerConfig, tok, kc, vc, t, w):
                 kc, k[None], (i, 0, t, 0, 0))
             vc = jax.lax.dynamic_update_slice(
                 vc, v[None], (i, 0, t, 0, 0))
-        state['kc'], state['vc'] = kc, vc
         ki, vi = kc[i], vc[i]
         # (b, heads, 1, total) scores over the cache
         s_ = jnp.einsum('bqhd,bkhd->bhqk', q, ki) * scale
         s_ = jnp.where(live, s_, -jnp.inf)
         knews.append(k[:, 0])
         vnews.append(v[:, 0])
-        return jnp.einsum(
+        attn = jnp.einsum(
             'bhqk,bkhd->bqhd',
             jax.nn.softmax(s_.astype(jnp.float32),
                            axis=-1).astype(ki.dtype), vi)
-
-    logits = _decode_token(params, cfg, tok, attend)
-    return (logits, state['kc'], state['vc'], jnp.stack(knews),
-            jnp.stack(vnews))
-
-
-def decode_step_paged(params, cfg: TransformerConfig, tok, kpool, vpool,
-                      table, t, w):
-    """One decode step straight over the PAGED pool — the flash twin of
-    :func:`decode_step` (``serve.flash_decode``, doc/serving.md "Flash
-    paged decode").  Instead of gathering every slot's pages into a
-    dense cache, each stage scatters the new K/V row into its physical
-    page and hands attention to ``ops.pallas_kernels.paged_flash_decode``,
-    which reads the pages in place via the page table.  ``t``/``w`` are
-    (b,) per-slot vectors (this is an engine-only entry; ``generate``
-    keeps the dense scan).  Returns ``(logits, kpool, vpool)`` — the new
-    rows are already in the pool, so there is no knew/vnew leg.
-    Bitwise-equal to gather + :func:`decode_step` by construction of the
-    kernel's final softmax (pinned in tests/test_serve_decode.py)."""
-    from ..ops.pallas_kernels import paged_flash_decode
-    b = tok.shape[0]
-    ps = kpool.shape[2]
-    hd = cfg.d_model // cfg.num_heads
-    scale = 1.0 / math.sqrt(hd)
-    page = table[jnp.arange(b), t // ps]
-    off = t % ps
-    state = {'k': kpool, 'v': vpool}
-
-    def attend(i, p, q, k, v):
-        kp = state['k'].at[i, page, off].set(k[:, 0])
-        vp = state['v'].at[i, page, off].set(v[:, 0])
-        state['k'], state['v'] = kp, vp
-        return paged_flash_decode(q[:, 0], kp[i], vp[i], table, t, w,
-                                  scale)[:, None]
-
-    logits = _decode_token(params, cfg, tok, attend)
-    return logits, state['k'], state['v']
+        h = h + _rep(qdot(_rep(attn.reshape(b, 1, cfg.d_model)),
+                          p['wo']))
+        y2 = _layer_norm(h, p['ln2_scale'], p['ln2_bias'])
+        h = h + _gen_ffn(cfg, p, y2, gather=True)
+    logits = _rep(qdot(h[:, -1], params['head'])).astype(jnp.float32)
+    return logits, kc, vc, jnp.stack(knews), jnp.stack(vnews)
 
 
 def _build_generate(cfg: TransformerConfig, b: int, s0: int,

@@ -88,7 +88,6 @@ class Net:
         self._infer_shapes()
         self._build_sibling_fusion()
         self._build_blockdiag_fusion()
-        self._build_convact_fusion()
         # sequence nets (layers/sequence.py): node 0 holds integer token ids
         # when every layer that reads it says so
         readers0 = [self.layers[i] for i, info in enumerate(cfg.layers)
@@ -525,85 +524,6 @@ class Net:
         splits = np.cumsum(couts)[:-1]
         return jnp.split(out, splits, axis=-1)
 
-    # --- vertical conv+bias+act fusion ------------------------------------
-    def _build_convact_fusion(self) -> None:
-        """Pair eligible conv layers with their exclusive in-place relu
-        reader for the fused Pallas conv+bias+act block
-        (``ops/pallas_cnn.py``; ``fuse = auto|1|0`` net param, default
-        auto — the tri-state ``pallas_mode()`` gate decides at trace
-        time via ``conv_use_fused``).
-
-        Pairing is static and conservative: the conv must be an
-        ungrouped-or-grouped 1-in/1-out conv on the native lowering with
-        ``micro_batch=1`` (microbatching and the fused block are
-        mutually exclusive — the fused kernel has its own tiling), not a
-        member of a sibling/blockdiag group, and its output
-        (node, version) must be read by exactly ONE layer: a 1-in/1-out
-        relu that rewrites the node **in place** (``layer[a->a]``).  The
-        in-place restriction keeps ``node_values`` observably identical
-        to the unfused run — a non-in-place relu would leave the conv's
-        node holding an activated value the unfused graph never writes
-        there.  ``fuse=1`` additionally routes unpaired eligible convs
-        through the fused block with an identity activation
-        (``_convact_solo``) — the forced mode IS the CPU validation
-        path, so it exercises the bias fusion alone too.
-        """
-        from ..layers.common import ReluLayer
-        from ..layers.conv import ConvolutionLayer
-        fuse, tp = 'auto', 1
-        for name, val in self.cfg.defcfg:
-            if name == 'fuse':
-                fuse = str(val).strip()
-            if name == 'tensor_parallel':
-                tp = int(val)
-        self._fuse_knob = fuse
-        self._convact_pairs: Dict[int, int] = {}   # conv idx -> relu idx
-        self._convact_solo: set = set()
-        if fuse == '0' or tp > 1:
-            # under GSPMD a pallas_call is an opaque custom call with no
-            # sharding rule — same scoping as fullc_use_pallas
-            return
-        reads, writes = self._node_version_maps()
-        readers: Dict[tuple, List[int]] = {}
-        for i, rs in enumerate(reads):
-            for nv in rs:
-                readers.setdefault(nv, []).append(i)
-        for i, layer in enumerate(self.layers):
-            if not isinstance(layer, ConvolutionLayer):
-                continue
-            info = self.cfg.layers[i]
-            if (i in self._sibling_groups or i in self._blockdiag_groups
-                    or len(info.nindex_in) != 1
-                    or len(info.nindex_out) != 1
-                    or layer._lowering() != 'native'
-                    or layer.param.micro_batch > 1):
-                continue
-            out_nv = next(iter(writes[i]))
-            rd = readers.get(out_nv, [])
-            if len(rd) != 1:
-                if fuse == '1':
-                    self._convact_solo.add(i)
-                continue
-            r = rd[0]
-            rinfo = self.cfg.layers[r]
-            if (isinstance(self.layers[r], ReluLayer)
-                    and len(rinfo.nindex_in) == 1
-                    and rinfo.nindex_out == rinfo.nindex_in):
-                self._convact_pairs[i] = r
-            elif fuse == '1':
-                self._convact_solo.add(i)
-
-    def _fused_convact_outputs(self, lp, x, i: int, act: str):
-        """One fused Pallas conv+bias+act dispatch for layer ``i``."""
-        from ..ops.pallas_cnn import fused_conv_bias_act
-        p = self.layers[i].param
-        w = lp['wmat'].astype(x.dtype)
-        b = lp['bias'].astype(x.dtype) if p.no_bias == 0 else None
-        out = fused_conv_bias_act(
-            x, w, b, (p.stride, p.stride),
-            ((p.pad_y, p.pad_y), (p.pad_x, p.pad_x)), p.num_group, act)
-        return [out.astype(x.dtype)]
-
     # --- shape inference --------------------------------------------------
     def _infer_shapes(self) -> None:
         cfg = self.cfg
@@ -738,11 +658,6 @@ class Net:
         total_loss = jnp.asarray(0.0, jnp.float32)
         fused: Dict[int, jax.Array] = {}
         fused_bd: Dict[int, jax.Array] = {}
-        fused_act: set = set()   # relus whose act ran inside their conv
-        use_fused = bool(self._convact_pairs or self._convact_solo)
-        if use_fused:
-            from ..ops.pallas_cnn import conv_use_fused
-            use_fused = conv_use_fused(self._fuse_knob)
         for i in self._exec_order:
             info = cfg.layers[i]
             layer = self.layers[i]
@@ -761,14 +676,6 @@ class Net:
                         lp, ins, labels.field(layer.target), lctx, loss_mask)
                 if i in identity_layers:
                     outs = [ins[0]]
-                elif i in fused_act:
-                    outs = [ins[0]]   # activation already applied in the conv
-                elif use_fused and i in self._convact_pairs:
-                    outs = self._fused_convact_outputs(lp, ins[0], i, 'relu')
-                    fused_act.add(self._convact_pairs[i])
-                elif use_fused and i in self._convact_solo:
-                    outs = self._fused_convact_outputs(lp, ins[0], i,
-                                                       'identity')
                 elif i in self._sibling_groups:
                     if i not in fused:   # first member: run the fused conv
                         members = self._sibling_groups[i]

@@ -29,17 +29,15 @@ TRUE quantized footprint the budgeter sees.
 Execution: consumers route matmuls through :func:`qdot` and embedding
 gathers through :func:`qtake` — ``models/transformer.py`` does at every
 inference matmul site (``_stage_attn``, ``_gen_ffn``,
-``_nodrop_moe_ffn``'s gate, ``prefill_kv``'s head, and the
-``_decode_token`` block walk).  For a plain array ``qdot(x, w)`` IS
+``_nodrop_moe_ffn``'s gate, ``prefill_kv``'s head, and the block walks
+of ``decode_step`` and ``verify_step``).  For a plain array ``qdot(x, w)`` IS
 ``x @ w`` (the
 training path is bitwise untouched); for a :class:`QuantLeaf` it runs
 W8A8: dynamic per-row symmetric activation quantization, an int8 x int8
-matmul with exact int32 accumulation — the Pallas MXU kernel
-(``ops.pallas_kernels.pallas_int8_matmul``) when Pallas is forced on,
-``lax.dot_general`` otherwise, BITWISE-identical either way (integer
-adds carry no rounding) — and one f32 rescale.  Determinism is the
-point: a quantized model's outputs are a pure function of its int8
-weights, identical across Pallas modes and join orders, so the decode
+``lax.dot_general`` with exact int32 accumulation (integer adds carry no
+rounding) and one f32 rescale.  Determinism is the point: a quantized
+model's outputs are a pure function of its int8 weights, identical
+across join orders, so the decode
 engine's streams still have an EXACT offline twin
 (``transformer.generate`` over the same quantized tree); the accuracy
 delta vs f32 is policed separately by the tolerance twins
@@ -250,12 +248,7 @@ def tree_nbytes(tree) -> int:
 
 
 def _int8_mm(aq, bq):
-    """int8 x int8 -> int32, Pallas MXU kernel when forced on, XLA
-    ``dot_general`` otherwise — bitwise-identical either way (exact
-    integer accumulation; pinned in tests/test_quantize.py)."""
-    from ..ops import pallas_kernels as PK
-    if PK.pallas_enabled():
-        return PK.pallas_int8_matmul(aq, bq)
+    """int8 x int8 -> int32, exact integer accumulation."""
     return jax.lax.dot_general(aq, bq, (((1,), (0,)), ((), ())),
                                preferred_element_type=jnp.int32)
 

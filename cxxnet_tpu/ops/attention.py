@@ -14,7 +14,10 @@ operands and never holds the ``(heads, seq, seq)`` score matrix in HBM:
   queries, each block recomputed in the backward pass, so that what is live
   is one ``(heads, block, seq)`` slab.
 
-Both compute the scores and the softmax in float32.
+Both compute the scores and the softmax in float32.  ``_use_flash`` chooses
+between them from what it can observe - the backend, the mesh's size, the
+operands' shapes - and ``use_pallas``; nothing else in the tree selects an
+attention kernel, and this is the only flash attention a step can reach.
 """
 
 from __future__ import annotations

@@ -115,30 +115,21 @@ def ring_attention(q, k, v, mesh: Mesh, axis_name: str = 'data',
     return fn(q, k, v)
 
 
-def _ulysses_local(q, k, v, axis_name: str, causal: bool,
-                   use_flash: bool):
+def _ulysses_local(q, k, v, axis_name: str, causal: bool):
     """seq-sharded -> all_to_all -> head-sharded dense attention -> back."""
-    n = lax.psum(1, axis_name)
     # (b, s/n, h, d) -> (b, s, h/n, d): gather sequence, scatter heads
     q = lax.all_to_all(q, axis_name, split_axis=2, concat_axis=1, tiled=True)
     k = lax.all_to_all(k, axis_name, split_axis=2, concat_axis=1, tiled=True)
     v = lax.all_to_all(v, axis_name, split_axis=2, concat_axis=1, tiled=True)
-    from ..ops import pallas_kernels as pk
-    if use_flash:
-        # fused online-softmax kernel: O(seq) memory for the local dense
-        # attention after the head scatter
-        out = pk.flash_attention(q, k, v, causal=causal)
-    else:
-        scale = 1.0 / math.sqrt(q.shape[-1])
-        mask = None
-        if causal:
-            s = q.shape[1]
-            mask = jnp.tril(jnp.ones((s, s), bool))[None, None]
-        out = _local_attention(q, k, v, scale, mask)
+    scale = 1.0 / math.sqrt(q.shape[-1])
+    mask = None
+    if causal:
+        s = q.shape[1]
+        mask = jnp.tril(jnp.ones((s, s), bool))[None, None]
+    out = _local_attention(q, k, v, scale, mask)
     # (b, s, h/n, d) -> (b, s/n, h, d)
-    out = lax.all_to_all(out, axis_name, split_axis=1, concat_axis=2,
-                         tiled=True)
-    return out
+    return lax.all_to_all(out, axis_name, split_axis=1, concat_axis=2,
+                          tiled=True)
 
 
 def ulysses_attention(q, k, v, mesh: Mesh, axis_name: str = 'data',
@@ -147,18 +138,9 @@ def ulysses_attention(q, k, v, mesh: Mesh, axis_name: str = 'data',
     axis size."""
     if q.shape[2] % mesh.shape[axis_name]:
         raise ValueError('ulysses: heads must divide the mesh axis')
-    from ..ops.pallas_kernels import attn_use_flash
-    # post-gather local shape: full seq, heads split over the axis
-    use_flash = attn_use_flash(
-        q.shape[1], batch=q.shape[0],
-        heads=max(1, q.shape[2] // mesh.shape[axis_name]))
     spec = P(None, axis_name, None, None)
-    local = functools.partial(_ulysses_local, axis_name=axis_name,
-                              causal=causal, use_flash=use_flash)
-    wrap = functools.partial(shard_map, local, mesh=mesh,
-                             in_specs=(spec, spec, spec), out_specs=spec)
-    # pallas_call doesn't propagate varying-manual-axes through its
-    # interpreter yet; jax's own error message prescribes disabling the
-    # replication check
-    fn = wrap(check_vma=False) if use_flash else wrap()
+    fn = shard_map(
+        functools.partial(_ulysses_local, axis_name=axis_name,
+                          causal=causal),
+        mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec)
     return fn(q, k, v)

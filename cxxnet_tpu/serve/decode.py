@@ -54,15 +54,11 @@ Two serving multipliers ride the same pool (ROADMAP item 2):
   target decoding alone — the bitwise-twin discipline holds with a
   draft bolted on, on every ``serve.dtype`` tier.
 
-The attention itself has two legs behind ``serve.flash_decode``
-(doc/serving.md "Flash paged decode"): the gather path materializes each
-slot's pages into a dense (T, heads, hd) view per step, while the Pallas
-**paged flash-decode kernel** (``ops.pallas_kernels.paged_flash_decode``)
-reads the pages in place via the page table — bitwise-equal outputs,
-pinned by twin tests on the CPU ``interpret=True`` path.  ``dtype``
-selects the quantized-inference tier (``serve.dtype``, doc/serving.md
-"Quantized inference"): ``bf16`` casts params/pool/compute to bfloat16,
-``int8`` additionally stores matmul weights as per-channel int8
+The attention gathers each slot's pages into a dense (T, heads, hd) view
+per step and runs the shared ``transformer.decode_step`` math on it.
+``dtype`` selects the quantized-inference tier (``serve.dtype``,
+doc/serving.md "Quantized inference"): ``bf16`` casts
+params/pool/compute to bfloat16, ``int8`` additionally stores matmul weights as per-channel int8
 (``nnet/quantize.py``) consumed through the W8A8 ``qdot`` leg — either
 way the stream still has an EXACT offline twin (``transformer.generate``
 over the engine's own stored tree + compute config).
@@ -86,7 +82,6 @@ from ..models import transformer as T
 from ..nnet import quantize
 from ..parallel import mesh as mesh_mod
 from ..obs import format_report, record_event, span
-from ..ops import pallas_kernels as PK
 from ..runtime import faults as _faults
 from ..runtime.faults import (DeadlineExceededError, DecodePagesExhaustedError,
                               DecodeSlotsExhaustedError,
@@ -150,9 +145,6 @@ class DecodeEngine:
     either way :attr:`params`/:attr:`cfg` remain the stream oracle:
     ``transformer.generate(engine.params, ..., engine.cfg)`` is
     bitwise-equal to the engine's streams on every tier.
-    ``flash_decode`` (``serve.flash_decode``) picks the attention leg:
-    ``1``/``0`` force the Pallas paged flash-decode kernel / the dense
-    gather; ``'auto'``/None defer to ``pallas_mode()``.
     ``kv_host_mb``/``kv_disk_mb``/``kv_dir``/``kv_share_dir``
     (``serve.kv_*``) attach the graftcache tier hierarchy behind the
     prefix index: evicted index entries demote host → disk instead of
@@ -176,7 +168,7 @@ class DecodeEngine:
                  page_size: int = 16, max_prompt: int = 64,
                  max_new_bound: int = 64, eos_id: Optional[int] = None,
                  stats: Optional[StatSet] = None, name: str = 'lm',
-                 dtype: str = 'f32', flash_decode=None,
+                 dtype: str = 'f32',
                  prefix_share: int = 0, spec_k: int = 0, draft=None,
                  kv_host_mb: int = 0, kv_disk_mb: int = 0,
                  kv_dir: Optional[str] = None,
@@ -212,8 +204,6 @@ class DecodeEngine:
         self.serve_dtype = quantize.parse_serve_dtype(dtype)
         if self.serve_dtype != 'f32':
             cfg = dataclasses.replace(cfg, dtype=jnp.bfloat16)
-        # serve.flash_decode tri-state over the global pallas_mode() gate
-        self.use_flash = PK.decode_use_flash(flash_decode)
         # --- tensor-parallel decode (serve.shard, doc/serving.md
         # "Sharded serving"): a 1xN ('data', 'model') mesh; every matmul
         # weight column-shards its LAST axis over 'model' and the K/V
@@ -241,9 +231,6 @@ class DecodeEngine:
                                  '(the bitwise-twin contract excludes '
                                  'single-row steps)')
             self._mesh = mesh_mod.decode_mesh(self._tp)
-            # pallas kernels do not run SPMD over sharded operands
-            # without shard_map — the gather leg is the sharded path
-            self.use_flash = False
         self.cfg = cfg
         self.name = name
         self.slots = int(slots)
@@ -455,20 +442,6 @@ class DecodeEngine:
 
         mesh = self._mesh
 
-        if self.use_flash:
-            def step(params, kpool, vpool, table, pos, w, tok, r, temp):
-                # flash leg: K/V rows scatter into their physical pages
-                # and the Pallas kernel reads them in place — no dense
-                # cache is ever materialized (bitwise-equal to the
-                # gather leg below; twin test pins it)
-                logits, kpool, vpool = T.decode_step_paged(
-                    params, cfg, tok, kpool, vpool, table, pos, w)
-                nxt = self._pick_slots(logits, r, temp)
-                return kpool, vpool, nxt
-
-            return self._prog_step.jit(step, donate_argnums=(1, 2),
-                                       key='flash', fixed=True)
-
         def step(params, kpool, vpool, table, pos, w, tok, r, temp):
             # gather each slot's pages into the dense cache layout the
             # shared decode_step math expects (gather is an exact copy:
@@ -567,8 +540,8 @@ class DecodeEngine:
         """Jitted speculative round at window width ``K``: K-1 greedy
         draft proposals (sequential ``decode_step``s over the dense
         draft cache) + ONE target ``verify_step`` over the (slots, K)
-        window, its new K/V rows scattered into the page pool (the
-        flash leg verifies in place).  Returns the consumed window and
+        window, its new K/V rows scattered into the page pool.  Returns
+        the consumed window and
         the target's per-position greedy picks; acceptance is host-side
         (variable per slot)."""
         fn = self._spec_fns.get(K)
@@ -577,7 +550,6 @@ class DecodeEngine:
             cfg, dcfg = self.cfg, self._draft_cfg
             S, ps, Tlen = self.slots, self.page_size, self.cache_len
             hd = cfg.d_model // cfg.num_heads
-            use_flash = self.use_flash
             mesh = self._mesh
 
             def spec(params, dparams, kpool, vpool, kdc, vdc, table,
@@ -593,26 +565,22 @@ class DecodeEngine:
                     dtok = jnp.argmax(dlogits, axis=-1).astype(jnp.int32)
                     window.append(dtok)
                 toks = jnp.stack(window, axis=1)            # (S, K)
-                if use_flash:
-                    logits, kpool, vpool = T.verify_step_paged(
-                        params, cfg, toks, kpool, vpool, table, pos, w)
-                else:
-                    with T.shard_scope(mesh):
-                        st = kpool.shape[0]
-                        kc = kpool[:, table].reshape(st, S, Tlen,
-                                                     cfg.num_heads, hd)
-                        vc = vpool[:, table].reshape(st, S, Tlen,
-                                                     cfg.num_heads, hd)
-                        logits, _, _, knew, vnew = T.verify_step(
-                            params, cfg, toks, kc, vc, pos, w)
-                        tq = pos[:, None] + jnp.arange(K)[None, :]
-                        page = table[jnp.arange(S)[:, None], tq // ps]
-                        off = tq % ps
-                        si = jnp.arange(st)[:, None, None]
-                        kpool = kpool.at[si, page[None],
-                                         off[None]].set(knew)
-                        vpool = vpool.at[si, page[None],
-                                         off[None]].set(vnew)
+                with T.shard_scope(mesh):
+                    st = kpool.shape[0]
+                    kc = kpool[:, table].reshape(st, S, Tlen,
+                                                 cfg.num_heads, hd)
+                    vc = vpool[:, table].reshape(st, S, Tlen,
+                                                 cfg.num_heads, hd)
+                    logits, _, _, knew, vnew = T.verify_step(
+                        params, cfg, toks, kc, vc, pos, w)
+                    tq = pos[:, None] + jnp.arange(K)[None, :]
+                    page = table[jnp.arange(S)[:, None], tq // ps]
+                    off = tq % ps
+                    si = jnp.arange(st)[:, None, None]
+                    kpool = kpool.at[si, page[None],
+                                     off[None]].set(knew)
+                    vpool = vpool.at[si, page[None],
+                                     off[None]].set(vnew)
                 tgt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
                 return kpool, vpool, kdc, vdc, toks, tgt
 
@@ -1873,7 +1841,7 @@ class DecodeService:
                  max_new_bound: int = 64, eos_id: Optional[int] = None,
                  max_queue: int = 64, max_wait: float = 0.002,
                  deadline: float = 30.0, dtype: str = 'f32',
-                 flash_decode=None, prefix_share: int = 0,
+                 prefix_share: int = 0,
                  spec_k: int = 0, draft=None, kv_host_mb: int = 0,
                  kv_disk_mb: int = 0, kv_dir: Optional[str] = None,
                  kv_share_dir: Optional[str] = None, shard: str = '',
@@ -1884,7 +1852,7 @@ class DecodeService:
             params, cfg, slots=slots, pages=pages, page_size=page_size,
             max_prompt=max_prompt, max_new_bound=max_new_bound,
             eos_id=eos_id, stats=stats, dtype=dtype,
-            flash_decode=flash_decode, prefix_share=prefix_share,
+            prefix_share=prefix_share,
             spec_k=spec_k, draft=draft, kv_host_mb=kv_host_mb,
             kv_disk_mb=kv_disk_mb, kv_dir=kv_dir,
             kv_share_dir=kv_share_dir, shard=shard,

@@ -52,7 +52,7 @@ KNOBS: Dict[str, KnobDecl] = {d.name: d for d in (
     KnobDecl('page_size', 1, 128, 16, mem=True),
     KnobDecl('spec_k', 0, 8, 0, mem=False, spec=True),
     KnobDecl('max_queue', 1, 1024, 64, mem=False),
-    # μ-cuDNN-style convolution microbatching (ops/pallas_cnn.py): a
+    # μ-cuDNN-style convolution microbatching (layers/conv.py): a
     # LARGER split shrinks the conv workspace, so it prices inversely
     KnobDecl('micro_batch', 1, 64, 1, mem=False, mem_inv=True),
 )}

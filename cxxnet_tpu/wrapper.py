@@ -595,8 +595,7 @@ class LMServe:
     params from ``model_in`` (a ``%04d.lm`` tree) or ``seed`` init,
     engine shape ``slots``/``pages``/``page_size``/``max_prompt``/
     ``max_new``/``eos``, batcher knobs ``max_queue``/``max_wait``/
-    ``deadline``, serving tier ``dtype`` (``f32``/``bf16``/``int8``),
-    attention leg ``flash_decode`` (``auto``/``0``/``1``), prefix
+    ``deadline``, serving tier ``dtype`` (``f32``/``bf16``/``int8``), prefix
     sharing ``prefix_share`` (index page cap, 0 = off; doc/serving.md
     "Prefix sharing"), and greedy speculative decoding ``spec_k`` plus
     ``draft.*`` keys (``draft.d_model=16;draft.stages=1;draft.seed=1``
@@ -653,8 +652,6 @@ class LMServe:
                 eos = None if int(val) < 0 else int(val)
             elif key == 'dtype':
                 svc_kw['dtype'] = val
-            elif key == 'flash_decode':
-                svc_kw['flash_decode'] = val
             elif key in ('kv_dir', 'kv_share_dir'):
                 svc_kw[key] = val
             elif key == 'shard':

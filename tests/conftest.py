@@ -141,9 +141,7 @@ def pytest_configure(config):
         '(tier-1: runs under -m "not slow"; select with -m tune)')
     config.addinivalue_line(
         'markers',
-        'cnn_fused: graftfuse suite — fused Pallas conv+bias+act '
-        'blocks (interpret-mode bitwise/pinned-tolerance twins vs the '
-        'XLA composition, fwd+grad, every stride/pad/group leg), '
+        'cnn_fused: graftfuse suite — '
         'inference conv+BN folding through a real PredictEngine '
         '(hot-swap re-fold + double-fold identity guard), μ-cuDNN '
         'conv microbatching bitwise at every declared split with '

@@ -1,12 +1,8 @@
-"""graftfuse suite (doc/kernels.md): the fused Pallas conv+bias+act
-block, inference conv+BN folding, and μ-cuDNN convolution microbatching.
+"""graftfuse suite (doc/kernels.md): inference conv+BN folding and
+μ-cuDNN convolution microbatching.
 
-Three contracts, each pinned here:
+Two contracts, each pinned here:
 
-* the fused block equals the XLA reference composition within the
-  tolerances pinned in ``ops/pallas_cnn`` (``_FUSED_RTOL``/``_FUSED_ATOL``
-  — pinned-tolerance, never silently looser), forward AND gradients,
-  on every stride/pad/group/bias/activation leg, in interpret mode;
 * a ``fold_bn=1`` PredictEngine serves scores equal (``FOLD_RTOL``/
   ``FOLD_ATOL``) to the unfolded engine on the calibration batch, and
   keeps that equality through hot swaps (re-fold) and re-placed trees
@@ -22,124 +18,18 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from cxxnet_tpu.layers.conv import _conv_im2col_mb, _conv_native_mb
+from cxxnet_tpu.layers.conv import (_conv_im2col_mb, _conv_native_mb,
+                                    microbatched_conv)
 from cxxnet_tpu.nnet.fold import FOLD_ATOL, FOLD_RTOL
 from cxxnet_tpu.nnet.trainer import NetTrainer
 from cxxnet_tpu.obs.programs import get_ledger
-from cxxnet_tpu.ops.pallas_cnn import (_FUSED_ATOL, _FUSED_RTOL, _conv_ref,
-                                       conv_use_fused, fused_conv_bias_act,
-                                       microbatched_conv)
 from cxxnet_tpu.serve.engine import PredictEngine
 from cxxnet_tpu.utils.config import parse_config_string
 
 pytestmark = pytest.mark.cnn_fused
 
 
-def _ref_composition(x, w, b, strides, pad, groups, act):
-    y = _conv_ref(x, w, strides, pad, groups)
-    if b is not None:
-        y = y + b
-    return jnp.maximum(y, 0.0) if act == 'relu' else y
-
-
-def _leg_data(key, cin, cout, groups, hw=9):
-    kx, kw_, kb = jax.random.split(jax.random.PRNGKey(key), 3)
-    x = jax.random.normal(kx, (4, hw, hw, cin), jnp.float32)
-    w = jax.random.normal(kw_, (3, 3, cin // groups, cout), jnp.float32)
-    b = jax.random.normal(kb, (cout,), jnp.float32)
-    return x, w, b
-
-
-# --- the fused block's twins (fwd + grad, every leg) -----------------------
-
-@pytest.mark.parametrize(
-    'stride,pad,groups,act,bias',
-    [(1, 1, 1, 'relu', True),        # the paired-layer fast path
-     (1, 1, 1, 'relu', False),       # no_bias conv
-     (1, 1, 1, 'identity', True),    # fuse=1 solo conv (no relu reader)
-     (2, 1, 1, 'relu', True),        # strided
-     (1, 0, 1, 'relu', True),        # valid padding
-     (2, 2, 1, 'identity', False),   # strided + wide pad, bare conv
-     (1, 1, 2, 'relu', True),        # grouped
-     (2, 1, 4, 'identity', True)],   # grouped + strided
-    ids=['base', 'nobias', 'identity', 'stride2', 'pad0',
-         's2p2bare', 'group2', 'group4s2'])
-def test_fused_block_matches_reference(stride, pad, groups, act, bias):
-    x, w, b = _leg_data(7 * stride + pad + groups, 4 * groups, 8, groups)
-    b = b if bias else None
-    strides, padding = (stride, stride), ((pad, pad), (pad, pad))
-
-    y_fused = fused_conv_bias_act(x, w, b, strides, padding, groups, act)
-    y_ref = _ref_composition(x, w, b, strides, padding, groups, act)
-    np.testing.assert_allclose(np.asarray(y_fused), np.asarray(y_ref),
-                               rtol=_FUSED_RTOL, atol=_FUSED_ATOL)
-
-    def loss_fused(x, w, b):
-        return jnp.sum(jnp.cos(
-            fused_conv_bias_act(x, w, b, strides, padding, groups, act)))
-
-    def loss_ref(x, w, b):
-        return jnp.sum(jnp.cos(
-            _ref_composition(x, w, b, strides, padding, groups, act)))
-
-    args = (x, w) if b is None else (x, w, b)
-    nums = (0, 1) if b is None else (0, 1, 2)
-    gf = jax.grad(loss_fused, argnums=nums)(*args, *(() if b is not None
-                                                     else (None,)))
-    gr = jax.grad(loss_ref, argnums=nums)(*args, *(() if b is not None
-                                                   else (None,)))
-    for a, r in zip(gf, gr):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(r),
-                                   rtol=_FUSED_RTOL, atol=_FUSED_ATOL)
-
-
-def test_fused_relu_grad_matches_reference_at_exact_ties():
-    """The reference relu is ``jnp.maximum(x, 0)``, whose XLA gradient
-    at an EXACT z==0 tie is 0.5 — and zero-padded integer images with a
-    zero-init bias tie densely at step 0, so the fused backward must
-    mirror that convention bitwise, not just a.e."""
-    # all-zero input + zero bias => every pre-activation is exactly 0
-    x = jnp.zeros((2, 5, 5, 3), jnp.float32)
-    w = jax.random.normal(jax.random.PRNGKey(3), (3, 3, 3, 4), jnp.float32)
-    b = jnp.zeros((4,), jnp.float32)
-    strides, padding = (1, 1), ((1, 1), (1, 1))
-
-    def loss_fused(x, w, b):
-        return jnp.sum(
-            fused_conv_bias_act(x, w, b, strides, padding, 1, 'relu')
-            * jnp.arange(1.0, 5.0))
-
-    def loss_ref(x, w, b):
-        return jnp.sum(
-            _ref_composition(x, w, b, strides, padding, 1, 'relu')
-            * jnp.arange(1.0, 5.0))
-
-    gf = jax.grad(loss_fused, argnums=(0, 1, 2))(x, w, b)
-    gr = jax.grad(loss_ref, argnums=(0, 1, 2))(x, w, b)
-    for a, r in zip(gf, gr):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(r))
-    # the tie convention is the half-gradient, not a dead unit
-    assert float(jnp.abs(gf[2]).max()) > 0.0
-
-
-def test_conv_use_fused_gate_tristate(monkeypatch):
-    """``fuse=1`` forces the block on (the CPU validation path),
-    ``fuse=0`` kills it, and auto never picks it, on any backend: Mosaic
-    refuses the kernel on the chip (doc/kernels.md), so only a forced
-    spelling (``fuse=1`` / ``use_pallas=1``) reaches it."""
-    from cxxnet_tpu.ops import pallas_kernels as pk
-    monkeypatch.delenv('CXXNET_PALLAS', raising=False)
-    assert conv_use_fused('1') is True
-    assert conv_use_fused('0') is False
-    monkeypatch.setattr(pk, '_interpret', lambda: False)   # "on a TPU"
-    assert conv_use_fused('auto') is False
-    assert conv_use_fused(None) is False
-    monkeypatch.setenv('CXXNET_PALLAS', '1')
-    assert conv_use_fused('auto') is True
-    assert conv_use_fused('0') is False             # explicit key wins
-
-
-# --- net-level fusion pass -------------------------------------------------
+# --- the small CNN the trainer-level tests share ---------------------------
 
 _CNN_CONF = """
 netconfig = start
@@ -189,40 +79,6 @@ def _param_maxerr(a, b):
         np.asarray(a.params[lk][f], np.float32)
         - np.asarray(b.params[lk][f], np.float32))))
         for lk in a.params for f in a.params[lk])
-
-
-def test_fusion_pass_pairs_inplace_relus():
-    tr = _trainer('fuse = 1\n')
-    assert tr.net._convact_pairs == {0: 1, 3: 4}
-    assert tr.net._convact_solo == set()
-    tr0 = _trainer('fuse = 0\n')
-    assert tr0.net._convact_pairs == {}
-    assert tr0.net._convact_solo == set()
-
-
-def test_fusion_excluded_under_microbatching():
-    """The fused block has its own tiling — ``micro_batch>1`` convs must
-    fall out of the pairing (they take the microbatched path instead)."""
-    tr = _trainer('fuse = 1\nmicro_batch = 2\n')
-    assert tr.net._convact_pairs == {}
-    assert tr.net._convact_solo == set()
-
-
-def test_fused_training_twin():
-    """fuse=1 and fuse=0 trainers fed the identical update stream stay
-    within the fused block's pinned tolerance — on the f32 cpu interpret
-    path they are in practice bitwise (err 0.0), and any drift past the
-    pinned envelope is a bug, not a tolerance to widen."""
-    rng = np.random.RandomState(0)
-    data, label = _batch(rng)
-    t_on, t_off = _trainer('fuse = 1\n'), _trainer('fuse = 0\n')
-    for t in (t_on, t_off):
-        d = t._shard_batch(data)
-        lb = t._shard_batch(label, cast=False)
-        for _ in range(3):
-            t.update_on_device(d, lb)
-    err = _param_maxerr(t_on, t_off)
-    assert err <= _FUSED_ATOL, f'fused training drifted: {err}'
 
 
 # --- conv+BN folding through a real PredictEngine --------------------------
@@ -352,14 +208,42 @@ def test_micro_batch_trainer_step_bitwise(split):
     ``micro_batch=k`` is bitwise-equal to the unsplit step."""
     rng = np.random.RandomState(1)
     data, label = _batch(rng)
-    t1 = _trainer('fuse = 0\nmicro_batch = 1\n')
-    tk = _trainer(f'fuse = 0\nmicro_batch = {split}\n')
+    t1 = _trainer('micro_batch = 1\n')
+    tk = _trainer(f'micro_batch = {split}\n')
     for t in (t1, tk):
         d = t._shard_batch(data)
         lb = t._shard_batch(label, cast=False)
         for _ in range(3):
             t.update_on_device(d, lb)
     assert _param_maxerr(t1, tk) == 0.0
+
+
+def test_autotune_setter_rebuilds_the_step_and_stays_bitwise():
+    """``LearnTask._set_micro_batch`` is what ``task = autotune`` calls
+    between candidates: it re-splits every layer of the LIVE trainer and
+    recompiles its steps, so the next step runs the new split (a new jit,
+    ``micro_batch`` read at trace time) and the weights stay bitwise equal
+    to a trainer that never split; the ``finally`` of ``_autotune_train``
+    sets it back the same way."""
+    from cxxnet_tpu.main import LearnTask
+    rng = np.random.RandomState(4)
+    data, label = _batch(rng)
+    ref = _trainer('micro_batch = 1\n')
+    task = LearnTask()
+    task.net_trainer = live = _trainer('micro_batch = 1\n')
+
+    def step(t):
+        t.update_on_device(t._shard_batch(data),
+                           t._shard_batch(label, cast=False))
+
+    step(ref), step(live)
+    for value in (2, 1):
+        before = live._train_step_fn
+        task._set_micro_batch(value)
+        assert live._train_step_fn is not before
+        assert {lyr.param.micro_batch for lyr in live.net.layers} == {value}
+        step(ref), step(live)
+        assert _param_maxerr(ref, live) == 0.0
 
 
 def test_micro_batch_composes_with_steps_per_dispatch():
@@ -391,10 +275,10 @@ def test_micro_batch_composes_with_steps_per_dispatch():
         tr.update_n_on_device(fn, dstack, lstack, n_steps)
         return tr
 
-    seq_1 = seq_run('fuse = 0\nmicro_batch = 1\n')
-    seq_k = seq_run('fuse = 0\nmicro_batch = 2\n')
-    scan_1 = scan_run('fuse = 0\nmicro_batch = 1\n')
-    scan_k = scan_run('fuse = 0\nmicro_batch = 2\n')
+    seq_1 = seq_run('micro_batch = 1\n')
+    seq_k = seq_run('micro_batch = 2\n')
+    scan_1 = scan_run('micro_batch = 1\n')
+    scan_k = scan_run('micro_batch = 2\n')
     assert _param_maxerr(seq_1, seq_k) == 0.0
     assert _param_maxerr(scan_1, scan_k) == 0.0
     assert scan_1.epoch_counter == scan_k.epoch_counter == n_steps
@@ -410,7 +294,7 @@ def test_micro_batch_bounds_ledger_peak_bytes():
     led = get_ledger()
     peaks = {}
     for split in (1, 4):
-        tr = _trainer(f'fuse = 0\nmicro_batch = {split}\n')
+        tr = _trainer(f'micro_batch = {split}\n')
         tr.update_on_device(tr._shard_batch(data),
                             tr._shard_batch(label, cast=False))
         entries = led.entries_for(tr._prog_step.name)
@@ -419,7 +303,7 @@ def test_micro_batch_bounds_ledger_peak_bytes():
     assert peaks[4] > 0
 
 
-# --- doc drift (satellite 5) -----------------------------------------------
+# --- doc drift -------------------------------------------------------------
 
 def _repo_doc(rel):
     import os
@@ -430,16 +314,15 @@ def _repo_doc(rel):
 
 def test_tasks_doc_documents_the_fusion_surface():
     text = _repo_doc('tasks.md')
-    assert '`fuse`' in text
+    assert '`fuse`' not in text
     assert '`micro_batch`' in text
     assert 'serve.fold_bn' in text
 
 
 def test_kernels_doc_exists_and_is_linked():
     """tasks.md/autotune.md link kernels.md for the fusion story — the
-    target must exist and cover the three graftfuse contracts."""
+    target must exist and cover the two graftfuse contracts."""
     text = _repo_doc('kernels.md')
-    for needle in ('fused_conv_bias_act', 'micro_batch', 'fold_bn',
-                   'bitwise', 'interpret'):
+    for needle in ('micro_batch', 'fold_bn', 'bitwise'):
         assert needle in text, f'doc/kernels.md missing {needle!r}'
     assert 'kernels.md' in _repo_doc('README.md')

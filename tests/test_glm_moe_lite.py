@@ -404,30 +404,7 @@ def test_train_flops_by_hand():
     assert abs(R.train_flops_per_sequence(graph) - 29.7e12) < 0.05e12
 
 
-# --- attention, the iterator, the model file ----------------------------------
-
-@pytest.mark.parametrize('dv', [16, 24])
-def test_blocked_attention_equals_the_full_masked_softmax(dv):
-    """The XLA path over blocks of queries (each recomputed in the backward
-    pass) against one full masked softmax: values and all three gradients,
-    value dims equal and unequal to the key dims."""
-    from cxxnet_tpu.ops.attention import causal_attention_xla
-    ks = jax.random.split(jax.random.PRNGKey(7), 3)
-    q = jax.random.normal(ks[0], (2, 3, 64, 16))
-    k = jax.random.normal(ks[1], (2, 3, 64, 16))
-    v = jax.random.normal(ks[2], (2, 3, 64, dv))
-
-    def loss(block):
-        return lambda q, k, v: jnp.sum(jnp.sin(
-            causal_attention_xla(q, k, v, 0.25, block_q=block)))
-    full = jax.value_and_grad(loss(64), argnums=(0, 1, 2))(q, k, v)
-    blocked = jax.value_and_grad(loss(16), argnums=(0, 1, 2))(q, k, v)
-    for a, b in zip(jax.tree.leaves(full), jax.tree.leaves(blocked)):
-        np.testing.assert_allclose(a, b, atol=2e-5, rtol=1e-5)
-    # causal: the first position sees itself alone
-    out = causal_attention_xla(q, k, v, 0.25, block_q=16)
-    np.testing.assert_allclose(out[:, :, 0], v[:, :, 0], atol=1e-6)
-
+# --- the iterator, the model file ---------------------------------------------
 
 def test_synth_tokens_iterator_feeds_the_conf():
     from cxxnet_tpu.io.data import create_iterator

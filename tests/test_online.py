@@ -411,7 +411,10 @@ def _request_source(seed=7):
 
 
 def _run_pipeline(tmp, batches, rounds=2, fault_plan=None, qps=200.0,
-                  save_every=10, log=None, **cfg_kw):
+                  save_every=10, log=None, settled=None, **cfg_kw):
+    """``settled(pipe)``: what the caller waits to see (up to 10 s) before
+    the pipeline closes.  ``run`` returns once the last checkpoint is
+    written; the watcher swaps it in on its own clock."""
     tr = NetTrainer(parse_config_string(MLP_CONF))
     tr.init_model()
     base = dict(model_dir=os.path.join(tmp, 'm'),
@@ -426,6 +429,10 @@ def _run_pipeline(tmp, batches, rounds=2, fault_plan=None, qps=200.0,
                           failure_log=log)
     try:
         summary = pipe.run(num_rounds=rounds, out=_io.StringIO())
+        deadline = time.monotonic() + 10.0
+        while settled is not None and not settled(pipe) \
+                and time.monotonic() < deadline:
+            time.sleep(0.01)
     finally:
         pipe.close(timeout=10.0)
         faults.install_plan(prev)
@@ -551,7 +558,8 @@ def test_online_save_failure_degrades_freshness_not_training(tmp_path,
     log = faults.FailureLog()
     pipe, summary, _tr = _run_pipeline(
         str(tmp_path), _make_batches(24, seed=5), rounds=1, log=log,
-        save_every=8)
+        save_every=8,
+        settled=lambda p: any(s > 8 for s in list(p.tracker._swap_t)))
     assert summary['steps'] == 24
     assert summary['dropped'] == 0
     assert summary['save_failures'] >= 1          # the lost 0008 publish

@@ -26,7 +26,6 @@ import jax.numpy as jnp
 
 from cxxnet_tpu.models import transformer as T
 from cxxnet_tpu.nnet import quantize as Q
-from cxxnet_tpu.ops import pallas_kernels as PK
 from cxxnet_tpu.serve import PredictEngine
 from cxxnet_tpu.serve.decode import DecodeEngine
 
@@ -117,42 +116,12 @@ def test_qdot_plain_array_is_native_matmul():
                                   np.asarray(x @ w))
 
 
-def test_int8_matmul_pallas_bitwise_equals_xla():
-    """Exact integer accumulation: the MXU-tiled kernel (interpret=True
-    on CPU) and lax.dot_general agree BITWISE — ragged shapes exercise
-    the padding."""
-    rng = np.random.RandomState(3)
-    for m, k, n in ((5, 33, 17), (128, 256, 128), (1, 7, 300)):
-        a = rng.randint(-127, 128, (m, k)).astype(np.int8)
-        b = rng.randint(-127, 128, (k, n)).astype(np.int8)
-        ref = jax.lax.dot_general(a, b, (((1,), (0,)), ((), ())),
-                                  preferred_element_type=jnp.int32)
-        out = PK.pallas_int8_matmul(jnp.asarray(a), jnp.asarray(b))
-        np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
-
-
-def test_qdot_quantized_pallas_mode_invariant(monkeypatch):
-    """serve.dtype=int8 outputs are a pure function of the int8 weights:
-    identical with CXXNET_PALLAS unset (XLA int8 dot) and =1 (Pallas
-    kernel, interpret on CPU)."""
-    rng = np.random.RandomState(4)
-    x = jnp.asarray(rng.randn(6, 32), jnp.bfloat16)
-    w = Q.quantize_leaf(rng.randn(32, 24).astype(np.float32),
-                        out_dtype=jnp.bfloat16)
-    monkeypatch.delenv('CXXNET_PALLAS', raising=False)
-    xla = np.asarray(Q.qdot(x, w), np.float32)
-    monkeypatch.setenv('CXXNET_PALLAS', '1')
-    pallas = np.asarray(Q.qdot(x, w), np.float32)
-    np.testing.assert_array_equal(xla, pallas)
-
-
 # --- DecodeEngine tiers ------------------------------------------------------
 
 class TestDecodeTiers:
-    def _streams(self, dtype, prompts, temps, keys, flash=0):
+    def _streams(self, dtype, prompts, temps, keys):
         eng = DecodeEngine(_params(), CFG, slots=4, pages=64, page_size=8,
-                           max_prompt=16, max_new_bound=32, dtype=dtype,
-                           flash_decode=flash)
+                           max_prompt=16, max_new_bound=32, dtype=dtype)
         try:
             reqs = [eng.submit_direct(p, max_new=10, temperature=tp,
                                       rng=k)
@@ -170,7 +139,7 @@ class TestDecodeTiers:
     def test_exact_stream_twins_every_tier(self):
         """EVERY serve.dtype tier keeps the bitwise-twin discipline: the
         engine's streams equal generate() over its own stored tree and
-        compute config — greedy and sampled, gather and flash legs."""
+        compute config — greedy and sampled."""
         rng = np.random.RandomState(5)
         prompts = [rng.randint(0, 64, (1, int(rng.randint(1, 12))))
                    .astype(np.int32) for _ in range(4)]
@@ -178,15 +147,13 @@ class TestDecodeTiers:
         keys = [None, None, jax.random.PRNGKey(9), jax.random.PRNGKey(10)]
         residents = {}
         for dtype in ('f32', 'bf16', 'int8'):
-            for flash in (0, 1):
-                outs, ref, cfg, resident = self._streams(
-                    dtype, prompts, temps, keys, flash=flash)
-                for o, p, tp, k in zip(outs, prompts, temps, keys):
-                    off = np.asarray(T.generate(ref, p, 10, cfg,
-                                                temperature=tp,
-                                                rng=k))[0]
-                    np.testing.assert_array_equal(o, off)
-                residents[dtype] = resident
+            outs, ref, cfg, resident = self._streams(
+                dtype, prompts, temps, keys)
+            for o, p, tp, k in zip(outs, prompts, temps, keys):
+                off = np.asarray(T.generate(ref, p, 10, cfg,
+                                            temperature=tp, rng=k))[0]
+                np.testing.assert_array_equal(o, off)
+            residents[dtype] = resident
         # resident-byte ladder: bf16 halves params+pool; int8 shrinks
         # further (params ~4x; the bf16 pool shares the ledger)
         assert residents['bf16'] < residents['f32'] * 0.55
@@ -375,14 +342,13 @@ def test_capi_serve_start_parses_dtype():
     assert stub.kw['buckets'] == '1,4'
 
 
-def test_capi_lm_serve_parses_dtype_and_flash(tmp_path):
+def test_capi_lm_serve_parses_dtype(tmp_path):
     from cxxnet_tpu import capi
     svc = capi.lm_serve_start(
         'vocab=64;d_model=32;heads=4;d_ff=48;stages=2;slots=2;pages=32;'
-        'page_size=8;max_prompt=12;max_new=6;dtype=bf16;flash_decode=1')
+        'page_size=8;max_prompt=12;max_new=6;dtype=bf16')
     try:
         assert svc.engine.serve_dtype == 'bf16'
-        assert svc.engine.use_flash
         assert svc.engine.cfg.dtype == jnp.bfloat16
         prompt = np.arange(5, dtype=np.int32)
         toks = capi.lm_serve_generate(svc, memoryview(prompt.tobytes()),

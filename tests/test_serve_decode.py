@@ -150,178 +150,6 @@ class TestPagedVsDense:
                                       np.asarray(pg[4]))
 
 
-# --- flash paged decode (ops.pallas_kernels.paged_flash_decode) -------------
-
-class TestFlashPagedDecode:
-    """serve.flash_decode: the Pallas kernel that reads KV pages in
-    place must be BITWISE-equal to the gather-then-dense path — at step
-    level and over whole streams — on the CPU interpret=True path."""
-
-    @pytest.mark.parametrize('w_pad', [0, 3])
-    def test_flash_step_bitwise_equal_dense(self, w_pad):
-        """decode_step_paged (scatter + in-place kernel) vs gather +
-        decode_step + scatter-back: logits AND both pools bitwise,
-        including the left-pad leg and per-slot mixed positions."""
-        params = _params()
-        rng = np.random.RandomState(11)
-        S, ps, pp = 3, 8, 4
-        Tlen = ps * pp
-        n_phys = S * pp + 2
-        hd = CFG.d_model // CFG.num_heads
-        kpool = rng.randn(CFG.num_stages, n_phys, ps, CFG.num_heads,
-                          hd).astype(np.float32)
-        vpool = rng.randn(CFG.num_stages, n_phys, ps, CFG.num_heads,
-                          hd).astype(np.float32)
-        phys = rng.permutation(np.arange(1, n_phys))[:S * pp]
-        table = phys.reshape(S, pp).astype(np.int32)
-        pos = np.asarray([5, 13, 20], np.int32)   # mid-stream, per-slot
-        w = np.full((S,), w_pad, np.int32)
-        tok = rng.randint(0, 64, (S,)).astype(np.int32)
-
-        def dense(p, kpool, vpool, table, tok, t, wv):
-            kc = kpool[:, table].reshape(CFG.num_stages, S, Tlen,
-                                         CFG.num_heads, hd)
-            vc = vpool[:, table].reshape(CFG.num_stages, S, Tlen,
-                                         CFG.num_heads, hd)
-            logits, _, _, knew, vnew = T.decode_step(p, CFG, tok, kc, vc,
-                                                     t, wv)
-            page = table[jax.numpy.arange(S), t // ps]
-            off = t % ps
-            si = jax.numpy.arange(CFG.num_stages)[:, None]
-            kpool = kpool.at[si, page[None, :], off[None, :]].set(knew)
-            vpool = vpool.at[si, page[None, :], off[None, :]].set(vnew)
-            return logits, kpool, vpool
-
-        dl, dk, dv = jax.jit(dense)(params, kpool, vpool, table, tok,
-                                    pos, w)
-        fl, fk, fv = jax.jit(
-            lambda p, kp, vp, tb, tk, t, wv: T.decode_step_paged(
-                p, CFG, tk, kp, vp, tb, t, wv))(
-            params, kpool, vpool, table, tok, pos, w)
-        np.testing.assert_array_equal(np.asarray(dl), np.asarray(fl))
-        np.testing.assert_array_equal(np.asarray(dk), np.asarray(fk))
-        np.testing.assert_array_equal(np.asarray(dv), np.asarray(fv))
-
-    def _twin_engines(self, **kw):
-        params = _params()
-        dense = DecodeEngine(params, CFG, slots=4, pages=64, page_size=8,
-                             max_prompt=16, max_new_bound=64,
-                             flash_decode=0, **kw)
-        flash = DecodeEngine(params, CFG, slots=4, pages=64, page_size=8,
-                             max_prompt=16, max_new_bound=64,
-                             flash_decode=1, **kw)
-        assert not dense.use_flash and flash.use_flash
-        return dense, flash
-
-    def test_flash_streams_bitwise_equal_gather(self):
-        """Greedy + sampled mixed-length staggered traffic: the flash
-        engine's streams equal the gather engine's AND the offline
-        generate twins, token for token."""
-        dense, flash = self._twin_engines()
-        try:
-            rng = np.random.RandomState(21)
-            prompts = [_prompt(rng) for _ in range(6)]
-            keys = [None, None, None] + [jax.random.PRNGKey(70 + i)
-                                         for i in range(3)]
-            temps = [0.0, 0.0, 0.0, 0.9, 0.9, 1.3]
-            outs = {}
-            for eng in (dense, flash):
-                reqs = []
-                for p, k, tp in zip(prompts, keys, temps):
-                    reqs.append(eng.submit_direct(p, max_new=7,
-                                                  temperature=tp, rng=k))
-                    time.sleep(0.005)   # staggered: later joins mid-run
-                outs[eng] = [_wait_ok(r) for r in reqs]
-            for i, (p, k, tp) in enumerate(zip(prompts, keys, temps)):
-                np.testing.assert_array_equal(outs[dense][i],
-                                              outs[flash][i])
-                _assert_twin(outs[flash][i],
-                             _offline(flash.params, p, 7,
-                                      temperature=tp, rng=k))
-        finally:
-            dense.close(30)
-            flash.close(30)
-
-    def test_flash_mid_stream_join(self):
-        """A request admitted while another stream is mid-decode joins at
-        a token boundary and still twins — on both legs, bitwise."""
-        dense, flash = self._twin_engines()
-        try:
-            rng = np.random.RandomState(22)
-            p1, p2 = _prompt(rng), _prompt(rng)
-            for eng in (dense, flash):
-                r1 = eng.submit_direct(p1, max_new=24)
-                while len(r1.tokens) < 4:     # provably mid-stream
-                    time.sleep(0.002)
-                r2 = eng.submit_direct(p2, max_new=6)
-                _assert_twin(_wait_ok(r1), _offline(eng.params, p1, 24))
-                _assert_twin(_wait_ok(r2), _offline(eng.params, p2, 6))
-        finally:
-            dense.close(30)
-            flash.close(30)
-
-    def test_flash_eos_reclaims_pages(self):
-        """EOS mid-stream on the flash leg: prefix twin holds and every
-        page returns to the pool."""
-        params = _params()
-        rng = np.random.RandomState(23)
-        p = _prompt(rng)
-        base = _offline(params, p, 12)
-        eos = int(base[2])
-        eng = DecodeEngine(params, CFG, slots=2, pages=32, page_size=8,
-                           max_prompt=16, max_new_bound=16, eos_id=eos,
-                           flash_decode=1)
-        try:
-            free0 = len(eng._free_pages)
-            got = _wait_ok(eng.submit_direct(p, max_new=12))
-            _assert_twin(got, _offline(params, p, 12, eos_id=eos))
-            assert got[-1] == eos and len(got) <= 12
-            deadline = time.time() + 5
-            while len(eng._free_pages) != free0 and time.time() < deadline:
-                time.sleep(0.01)
-            assert len(eng._free_pages) == free0
-        finally:
-            eng.close(30)
-
-    def test_flash_gate_tristate(self, monkeypatch):
-        """serve.flash_decode=1/0 forces; auto defers to pallas_mode():
-        on when CXXNET_PALLAS=1 and otherwise off on every backend —
-        Mosaic refuses the paged kernels on the chip (doc/serving.md)."""
-        from cxxnet_tpu.ops import pallas_kernels as PK
-        monkeypatch.delenv('CXXNET_PALLAS', raising=False)
-        assert PK.decode_use_flash(1) and PK.decode_use_flash('true')
-        assert not PK.decode_use_flash(0)
-        monkeypatch.setattr(PK, '_interpret', lambda: False)  # "on a TPU"
-        assert not PK.decode_use_flash('auto')
-        assert not PK.decode_use_flash(None)
-        monkeypatch.setenv('CXXNET_PALLAS', '1')
-        assert PK.decode_use_flash(None) and PK.decode_use_flash('auto')
-        assert not PK.decode_use_flash(0)           # explicit key wins
-        monkeypatch.setenv('CXXNET_PALLAS', '0')
-        assert not PK.decode_use_flash(None)
-        assert PK.decode_use_flash(1)               # explicit key wins
-
-    def test_resident_bytes_includes_kv_pool(self):
-        """The budgeter ledger entry is params + the FULL paged pool:
-        pages x page_size x stages x heads x head_dim x dtype, K and V —
-        pinned closed-form so the dominant allocation can never silently
-        fall out of eviction decisions again."""
-        params = _params()
-        eng = DecodeEngine(params, CFG, slots=2, pages=48, page_size=8,
-                           max_prompt=16, max_new_bound=16)
-        try:
-            hd = CFG.d_model // CFG.num_heads
-            itemsize = jax.numpy.dtype(CFG.dtype).itemsize
-            pool = 2 * CFG.num_stages * 48 * 8 * CFG.num_heads * hd \
-                * itemsize
-            pbytes = sum(np.asarray(l).nbytes
-                         for l in jax.tree.leaves(params))
-            assert eng.resident_bytes() == pool + pbytes
-            assert pool > pbytes   # the pool IS the dominant allocation
-        finally:
-            eng.close(30)
-
-
 # --- stream twins -----------------------------------------------------------
 
 class TestStreamTwins:
@@ -485,6 +313,26 @@ class TestSlotLifecycle:
         assert isinstance(r.error, DecodeSlotsExhaustedError)
         r = engine.submit_direct(_prompt(rng), max_new=1000)
         assert isinstance(r.error, DecodeSlotsExhaustedError)
+
+    def test_resident_bytes_includes_kv_pool(self):
+        """The budgeter ledger entry is params + the FULL paged pool:
+        pages x page_size x stages x heads x head_dim x dtype, K and V —
+        pinned closed-form so the dominant allocation can never silently
+        fall out of eviction decisions again."""
+        params = _params()
+        eng = DecodeEngine(params, CFG, slots=2, pages=48, page_size=8,
+                           max_prompt=16, max_new_bound=16)
+        try:
+            hd = CFG.d_model // CFG.num_heads
+            itemsize = jax.numpy.dtype(CFG.dtype).itemsize
+            pool = 2 * CFG.num_stages * 48 * 8 * CFG.num_heads * hd \
+                * itemsize
+            pbytes = sum(np.asarray(l).nbytes
+                         for l in jax.tree.leaves(params))
+            assert eng.resident_bytes() == pool + pbytes
+            assert pool > pbytes   # the pool IS the dominant allocation
+        finally:
+            eng.close(30)
 
 
 # --- hot swap ---------------------------------------------------------------
@@ -761,6 +609,33 @@ class TestSurfaces:
             assert 'decode-completed' in capi.lm_serve_stats(svc)
         finally:
             capi.lm_serve_stop(svc)
+
+    @pytest.mark.parametrize('surface', ['wrapper', 'capi'])
+    def test_lm_serve_refuses_a_flash_decode_option(self, surface):
+        """The engine has one attention leg: a spec that still asks for
+        the other is refused by name, as any unknown key is, before a
+        model is built."""
+        from cxxnet_tpu import capi, wrapper
+        start = {'wrapper': wrapper.LMServe.from_spec,
+                 'capi': capi.lm_serve_start}[surface]
+        with pytest.raises(ValueError,
+                           match="unknown lm_serve option: 'flash_decode'"):
+            start('vocab=64;d_model=32;heads=4;d_ff=48;stages=2;'
+                  'flash_decode=1')
+
+    def test_cli_parses_no_flash_decode_key(self):
+        """``main.py``'s key table, as the config-key lint extracts it,
+        holds no ``serve.flash_decode``."""
+        import os
+        from cxxnet_tpu.analysis import config_keys
+        from cxxnet_tpu.analysis.core import Repo
+        repo = Repo(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))))
+        keys = set(config_keys.parsed_keys(
+            repo.module('cxxnet_tpu/main.py')))
+        serve = {k for k in keys if k.startswith('serve.')}
+        assert {'serve.dtype', 'serve.mode', 'serve.spec_k'} <= serve
+        assert 'serve.flash_decode' not in serve
 
     def test_capi_net_serve_start_parses_fleet_options(self):
         from cxxnet_tpu import capi

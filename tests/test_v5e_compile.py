@@ -162,38 +162,65 @@ def test_grouped_expert_products_compile_at_the_published_widths(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 2 << 30
 
 
-def test_the_whole_step_fits_the_chip(one_chip, monkeypatch):
-    """The example conf's training step, compiled for one v5e chip from
-    shapes alone: 706.5 M parameters at 16 bytes each plus the step's
-    temporaries stay under the chip's 16.9e9 bytes with room for the
-    forward program."""
+def _conf_without_iterators(*path):
+    """A conf's pairs with the data, eval and pred sections and ``dev`` left
+    out: what builds the net and its step, nothing that reads a file."""
     import os
-    import numpy as np
-    from cxxnet_tpu.nnet.trainer import NetTrainer
     from cxxnet_tpu.utils.config import parse_config_file
-    _steer_onto_the_chip_path(monkeypatch)
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     pairs, skipping = [], False
-    for k, v in parse_config_file(os.path.join(
-            root, 'example', 'LM', 'GLM-4.7-Flash.ep8.conf')):
-        skipping = skipping or k == 'data'
+    for k, v in parse_config_file(os.path.join(root, 'example', *path)):
+        skipping = skipping or k in ('data', 'eval', 'pred')
         if not skipping and k != 'dev':
             pairs.append((k, v))
         skipping = skipping and (k, v) != ('iter', 'end')
-    tr = NetTrainer(pairs + [('dev', 'cpu')])
-    tr.init_net()
-    s = lambda a: jax.ShapeDtypeStruct(  # noqa: E731
-        a.shape, a.dtype, sharding=one_chip)
-    params = jax.tree.map(s, jax.eval_shape(tr.net.init_params,
-                                            jax.random.PRNGKey(0)))
-    seq = 8192
+    return pairs
+
+
+def _described(tr, one_chip):
+    """The trainer's parameters and optimizer state as shapes on the
+    described chip, and a maker of further such shapes."""
+    from cxxnet_tpu.updater import init_opt_state
     arg = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
         shape, dt, sharding=one_chip)
-    compiled = tr._train_step_fn._jit.lower(
-        params, {'m1': params, 'm2': params}, params,
-        arg((1, 1, 1, seq + 1), jnp.int32), arg((1, 2 * seq), jnp.float32),
-        (), arg((1,), jnp.float32), arg((2,), jnp.uint32), 0, 0,
-        do_update=True).compile()
+    on_chip = lambda tree: jax.tree.map(  # noqa: E731
+        lambda a: arg(a.shape, a.dtype), tree)
+    params = on_chip(jax.eval_shape(tr.net.init_params,
+                                    jax.random.PRNGKey(0)))
+    opt = on_chip(jax.eval_shape(
+        lambda p: init_opt_state(tr.net_cfg.updater_type, p), params))
+    return params, opt, arg
+
+
+@pytest.fixture(scope='module')
+def glm_step(one_chip):
+    """The example conf's training step, compiled for one v5e chip from
+    shapes alone (about a minute: once for the tests that read it)."""
+    from cxxnet_tpu.nnet.trainer import NetTrainer
+    from cxxnet_tpu.ops import attention
+    tr = NetTrainer(_conf_without_iterators('LM', 'GLM-4.7-Flash.ep8.conf')
+                    + [('dev', 'cpu')])
+    tr.init_net()
+    params, opt, arg = _described(tr, one_chip)
+    seq = 8192
+    with pytest.MonkeyPatch.context() as patch:
+        # ops/attention asks jax.default_backend(), which is the CPU here:
+        # the test steers it, the program has no option for it
+        patch.setattr(attention, '_use_flash',
+                      lambda q, k, v, spmd: spmd == 1)
+        compiled = tr._train_step_fn._jit.lower(
+            params, opt, params,
+            arg((1, 1, 1, seq + 1), jnp.int32),
+            arg((1, 2 * seq), jnp.float32), (), arg((1,), jnp.float32),
+            arg((2,), jnp.uint32), 0, 0, do_update=True).compile()
+    return compiled, params
+
+
+def test_the_whole_step_fits_the_chip(glm_step):
+    """706.5 M parameters at 16 bytes each plus the step's temporaries stay
+    under the chip's 16.9e9 bytes with room for the forward program."""
+    import numpy as np
+    compiled, params = glm_step
     m = compiled.memory_analysis()
     state = sum(int(np.prod(a.shape)) for a in jax.tree.leaves(params)) * 16
     assert state == 706_518_848 * 16
@@ -201,3 +228,63 @@ def test_the_whole_step_fits_the_chip(one_chip, monkeypatch):
             - m.alias_size_in_bytes + m.temp_size_in_bytes)
     assert m.alias_size_in_bytes >= state          # the state is donated
     assert live < 14.6e9, live / 2 ** 30           # 16.9e9 on the chip
+
+
+@pytest.mark.parametrize('event', ['flash_attention', 'flash_mha_bwd_dq',
+                                   'flash_mha_bwd_dkv'])
+def test_the_kernels_the_benchmark_reads_keep_their_names(glm_step, event):
+    """``kernels.flash_*_roofline_pct`` join a trace's device events to
+    JAX's three flash kernels by the start of the custom call's name
+    (``benchmark/scope_times.kernel_ms``, PERF.md 3): in the step the
+    chip runs, every Mosaic call under a latent-attention layer is named
+    so, and each of the three names is there."""
+    from cxxnet_tpu.utils import profiler
+    hlo = glm_step[0].as_text()
+    scopes = profiler.hlo_op_names(hlo)
+    kernels = {}
+    for line in hlo.splitlines():
+        m = profiler._HLO_INSTRUCTION.match(line)
+        if m and 'tpu_custom_call' in line:
+            kernels[m.group(1)] = profiler.scope_of(scopes[m.group(1)])[0]
+    names = ('flash_attention', 'flash_mha_bwd_dq', 'flash_mha_bwd_dkv')
+    in_mla = [n for n, scope in kernels.items() if '_mla' in scope]
+    assert in_mla and all(n.startswith(names) for n in in_mla), in_mla
+    mine = [n for n in in_mla if n.startswith(event)]
+    # 6 latent-attention layers: the forward kernel in the forward pass and
+    # in the backward's recomputation, dq and dkv once a layer
+    assert len(mine) == (12 if event == 'flash_attention' else 6), mine
+
+
+# the benchmark's two CNN confs (train step and evaluation forward) and the
+# two other ImageNet examples (train step): at batch 8 no gate of the program
+# would pick a kernel on the chip either (the fc8 eval matmul asks m >= 128)
+@pytest.mark.parametrize('conf,program', [
+    ('ImageNet.conf', 'train'), ('ImageNet.conf', 'eval'),
+    ('GoogLeNet.conf', 'train'), ('GoogLeNet.conf', 'eval'),
+    ('Inception-BN.conf', 'train'), ('VGG16.conf', 'train')])
+def test_the_cnn_programs_hold_no_custom_call_on_the_v5e(one_chip, conf,
+                                                         program):
+    """PR 28's finding held for whole programs: one custom call in a CNN
+    step cost 24-34% through the layouts it forced on its neighbours.  The
+    step and the evaluation forward compile for the chip to XLA's own
+    instructions, no Mosaic kernel among them."""
+    from cxxnet_tpu.nnet.trainer import NetTrainer
+    batch = 8
+    tr = NetTrainer(_conf_without_iterators('ImageNet', conf)
+                    + [('batch_size', str(batch)), ('dev', 'cpu')])
+    tr.init_net()
+    params, opt, arg = _described(tr, one_chip)
+    c, y, x = tr.net_cfg.input_shape
+    data = arg((batch, c, y, x), tr.compute_dtype)
+    if program == 'train':
+        compiled = tr._train_step_fn._jit.lower(
+            params, opt, params, data, arg((batch, 1), jnp.float32), (),
+            arg((batch,), jnp.float32), arg((2,), jnp.uint32), 0, 0,
+            do_update=True).compile()
+    else:
+        compiled = tr._forward_fn._jit.lower(
+            params, data, (), 0, nodes=tuple(tr._eval_node_ids)).compile()
+    hlo = compiled.as_text()
+    # XLA's own custom calls (a gather's packed indices) are not Mosaic's
+    assert 'tpu_custom_call' not in hlo
+    assert 'convolution(' in hlo

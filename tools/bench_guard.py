@@ -379,19 +379,12 @@ def expand_cnn_fused(p: dict, name: str) -> Tuple[List[str], List[dict]]:
     in-bench twin assertion (a speedup over diverging math is not a
     speedup), the micro_batch sweep must be bitwise at every split with
     ledger peak bytes monotone non-increasing in the split, and the
-    headline must be the best leg's speedup.  Per-leg throughputs are
+    headline must be the inference leg's speedup.  Per-leg throughputs are
     expanded into synthetic payloads for cross-round regression
     flags."""
     errs: List[str] = []
     synth: List[dict] = []
     plat = p.get('platform')
-    train = p.get('train')
-    if not isinstance(train, dict):
-        errs.append(f'{name}: cnn_fused receipt has no train leg')
-        train = {}
-    elif train.get('twin_ok') is not True:
-        errs.append(f'{name}: train leg params were not twin-asserted '
-                    '— fused training could have diverged unnoticed')
     infer = p.get('inference')
     if not isinstance(infer, dict):
         errs.append(f'{name}: cnn_fused receipt has no inference leg')
@@ -428,21 +421,17 @@ def expand_cnn_fused(p: dict, name: str) -> Tuple[List[str], List[dict]]:
             errs.append(f'{name}: micro_batch peak_bytes {peaks} grow '
                         'with the split — splitting must bound peak '
                         'HBM, not inflate it')
-    speedups = [leg.get('speedup') for leg in (train, infer)
-                if isinstance(leg.get('speedup'), (int, float))]
+    speedup = infer.get('speedup')
     value = p.get('value')
-    if speedups and isinstance(value, (int, float)) \
-            and abs(value - max(speedups)) > 1e-6:
-        errs.append(f'{name}: headline {value} is not the best-leg '
-                    f'speedup ({max(speedups)})')
-    for leg, key, unit in (
-            (train, 'fused_steps_per_sec', 'steps/sec'),
-            (train, 'unfused_steps_per_sec', 'steps/sec'),
-            (infer, 'folded_rows_per_sec', 'rows/sec'),
-            (infer, 'plain_rows_per_sec', 'rows/sec')):
-        if key in leg:
+    if isinstance(speedup, (int, float)) \
+            and isinstance(value, (int, float)) \
+            and abs(value - speedup) > 1e-6:
+        errs.append(f'{name}: headline {value} is not the inference '
+                    f"leg's speedup ({speedup})")
+    for key in ('folded_rows_per_sec', 'plain_rows_per_sec'):
+        if key in infer:
             synth.append({'metric': f'cnn_fused_{key}',
-                          'value': leg.get(key), 'unit': unit,
+                          'value': infer.get(key), 'unit': 'rows/sec',
                           'platform': plat})
     return errs, synth
 
