@@ -74,10 +74,7 @@ def _batches(n: int, batch: int):
 
 
 def _state_bytes(tr) -> int:
-    import jax
-    tree = {'params': tr.params, 'opt_state': tr.opt_state,
-            'grad_acc': tr.grad_acc}
-    return sum(np.asarray(x).nbytes for x in jax.tree.leaves(tree))
+    return sum(tr.resident_state_bytes().values())
 
 
 def _params_host(tr):

@@ -415,12 +415,25 @@ def _abstract_like(like):
     return jax.tree.map(to_abstract, like)
 
 
+def saved_keys(path: str) -> set:
+    """Top-level keys of the tree a committed step dir holds, in either
+    format, read from its metadata and not from its arrays."""
+    if '://' not in path and \
+            os.path.exists(os.path.join(path, _MANIFEST_NAME)):
+        with open(os.path.join(path, _MANIFEST_NAME)) as f:
+            tops = (re.match(r"\['([^']*)'\]", k) for k in json.load(f))
+        return {m.group(1) for m in tops if m}
+    return set(_shared_ck().metadata(path).item_metadata.tree)
+
+
 def restore_sharded(ckpt_dir: str, like, step: Optional[int] = None,
                     retry: Optional[faults.RetryPolicy] = None):
     """Restore the checkpoint at ``step`` (default: latest) with every
     leaf placed per ``like``'s shapes/dtypes/shardings — ``like`` is a
     pytree of sharding-annotated ``jax.ShapeDtypeStruct`` (e.g.
-    ``models.transformer.abstract_params``) or of live sharded arrays.
+    ``models.transformer.abstract_params``) or of live sharded arrays, or
+    a function from the step's :func:`saved_keys` to such a tree, for a
+    reader that takes a subtree only where the writer kept one.
     The storage read retries under ``retry`` (default
     ``faults.DEFAULT_IO_RETRY``).  Returns (params, step)."""
     if step is None:
@@ -434,6 +447,9 @@ def restore_sharded(ckpt_dir: str, like, step: Optional[int] = None,
     if '://' not in path and not os.path.isdir(path):
         raise FileNotFoundError(f'no checkpoint dir {path}')
     retry = faults.DEFAULT_IO_RETRY if retry is None else retry
+    if callable(like):
+        like = like(retry.call(lambda: saved_keys(path),
+                               op_name=f'saved_keys:step_{step}'))
     if '://' not in path and \
             os.path.exists(os.path.join(path, _MANIFEST_NAME)):
         # async-written native format (runtime/async_ckpt.py): restored

@@ -307,7 +307,59 @@ class NetTrainer:
             return
         opt = init_opt_state(self.net_cfg.updater_type, self.params)
         self.opt_state = {k: put(v) for k, v in opt.items()}
-        self.grad_acc = put(jax.tree.map(jnp.zeros_like, self.params))
+        self.grad_acc = (self._zero_accumulator()
+                         if self.update_period > 1 else None)
+        self._record_state()
+
+    def _zero_accumulator(self):
+        return jax.tree.map(
+            lambda p: jax.device_put(jnp.zeros_like(p), p.sharding),
+            self.params)
+
+    def _record_state(self) -> None:
+        from ..obs import record_event
+        record_event('train.state', 'train', **self.resident_state_bytes())
+
+    def _require_empty(self, acc, doing: str) -> None:
+        """Dropping an accumulator that still holds gradients would lose
+        them without a word: raise instead."""
+        if any(bool(jnp.any(g != 0)) for g in jax.tree.leaves(acc)):
+            raise RuntimeError(
+                f'{doing}: the gradient accumulator holds unapplied '
+                f'gradients, which update_period = 1 has nowhere to keep; '
+                f'change update_period on an accumulation boundary')
+
+    def _sync_accumulator(self, period: int) -> None:
+        """Make ``grad_acc`` what a step of ``update_period = period``
+        carries (``update_period`` may be set at any time).  Only a period
+        above 1 has anything to carry between steps: at period 1
+        ``grad_acc`` is ``None``, an empty pytree, so the step programs
+        keep their positional signature and carry, read and zero-fill
+        nothing of a parameter's size but the parameter and its optimizer
+        state.  Allocated at the first step after the period rose above 1,
+        dropped at the first after it fell to 1; the jitted step retraces
+        on the new pytree."""
+        want = period > 1
+        if want == (self.grad_acc is not None):
+            return
+        if want:
+            self.grad_acc = self._zero_accumulator()
+        else:
+            self._require_empty(
+                self.grad_acc, f'update_period fell to 1 at step '
+                f'{self.sample_counter}')
+            self.grad_acc = None
+        self._record_state()
+
+    def resident_state_bytes(self) -> Dict[str, int]:
+        """Logical bytes of what the trainer keeps on the device between
+        steps (a replicated leaf counts once): the ``train.state`` hub
+        event's three fields."""
+        size = lambda tree: sum(  # noqa: E731
+            int(x.nbytes) for x in jax.tree.leaves(tree))
+        return {'param_bytes': size(self.params),
+                'opt_state_bytes': size(self.opt_state),
+                'accumulator_bytes': size(self.grad_acc)}
 
     def _norm_args(self, batch):
         """Device constants for a deferred-normalization batch: ``()`` when
@@ -411,6 +463,19 @@ class NetTrainer:
                     grads = jax.tree.map(
                         lambda g: jnp.where(ok, g, jnp.zeros_like(g)),
                         grads)
+            if grad_acc is None:
+                # update_period = 1 (_sync_accumulator): every step
+                # applies, so the update reads the gradients themselves
+                # and no float32 copy of the parameters is carried, added
+                # into and zero-filled
+                if not do_update:
+                    raise ValueError(
+                        'a step that does not apply needs an accumulator')
+                with jax.named_scope('update'):
+                    params, opt_state = apply_updates(
+                        updater_type, hypers, params, grads, opt_state,
+                        epoch)
+                return params, opt_state, None, loss, evals, stats
             with jax.named_scope('grad_acc'):
                 grad_acc = jax.tree.map(jnp.add, grad_acc, grads)
             if do_update:
@@ -466,12 +531,15 @@ class NetTrainer:
         Composes with the production constraints the per-step path
         carries (the ExecutionPlan contract, doc/trainer.md):
 
-        * ``update_period = P`` — the gradient accumulator rides the scan
-          carry; step ``t`` adds its grads and the optimizer applies (and
-          the epoch counter advances) only when ``(sc0 + t + 1) % P == 0``
-          — the EXACT per-step cadence, so windows need not align with
-          accumulation boundaries (a partial accumulation carries across
-          dispatches through the trainer's live ``grad_acc``).
+        * ``update_period = P > 1`` — the gradient accumulator rides the
+          scan carry; step ``t`` adds its grads and the optimizer applies
+          (and the epoch counter advances) only when
+          ``(sc0 + t + 1) % P == 0`` — the EXACT per-step cadence, so
+          windows need not align with accumulation boundaries (a partial
+          accumulation carries across dispatches through the trainer's
+          live ``grad_acc``).  At ``update_period = 1`` there is no
+          accumulator: ``grad_acc`` is ``None`` in and out, and every step
+          applies its own gradients.
         * ``train_eval=True`` — each step's eval-node outputs ride the
           scan's stacked ys, so ``eval_train=1`` train metrics cost ONE
           host readback per dispatch instead of one per step
@@ -524,32 +592,34 @@ class NetTrainer:
                         grads = jax.tree.map(
                             lambda g: jnp.where(ok, g, jnp.zeros_like(g)),
                             grads)
-                # accumulate-then-apply, exactly as the per-step path: the
-                # 0+g add is kept even at P=1 so the float ops match
-                # bitwise (the per-step train_step always adds into the
-                # zeroed accumulator before applying)
-                with jax.named_scope('grad_acc'):
-                    grad_acc = jax.tree.map(jnp.add, grad_acc, grads)
-                if period == 1:
+                ys = (loss, tuple(evals) if train_eval else ())
+                if grad_acc is None:
+                    # update_period = 1, as the per-step program: the
+                    # update reads the gradients, nothing rides the carry
+                    # beside the parameters and the optimizer state
+                    if period != 1:
+                        raise ValueError(
+                            f'a window compiled for update_period = '
+                            f'{period} needs an accumulator')
                     with jax.named_scope('update'):
                         params, opt_state = apply_updates(
-                            updater_type, hypers, params, grad_acc,
-                            opt_state, epoch)
-                        grad_acc = jax.tree.map(jnp.zeros_like, grad_acc)
-                    epoch = epoch + 1
-                else:
-                    def _apply(args):
-                        p, o, g, e = args
-                        p, o = apply_updates(updater_type, hypers, p, g, o,
-                                             e)
-                        return p, o, jax.tree.map(jnp.zeros_like, g), e + 1
+                            updater_type, hypers, params, grads, opt_state,
+                            epoch)
+                    return (params, opt_state, None, epoch + 1), ys
+                # accumulate-then-apply, exactly as the per-step path
+                with jax.named_scope('grad_acc'):
+                    grad_acc = jax.tree.map(jnp.add, grad_acc, grads)
 
-                    with jax.named_scope('update'):
-                        params, opt_state, grad_acc, epoch = jax.lax.cond(
-                            (sc0 + t + 1) % period == 0, _apply,
-                            lambda args: args,
-                            (params, opt_state, grad_acc, epoch))
-                ys = (loss, tuple(evals) if train_eval else ())
+                def _apply(args):
+                    p, o, g, e = args
+                    p, o = apply_updates(updater_type, hypers, p, g, o, e)
+                    return p, o, jax.tree.map(jnp.zeros_like, g), e + 1
+
+                with jax.named_scope('update'):
+                    params, opt_state, grad_acc, epoch = jax.lax.cond(
+                        (sc0 + t + 1) % period == 0, _apply,
+                        lambda args: args,
+                        (params, opt_state, grad_acc, epoch))
                 return (params, opt_state, grad_acc, epoch), ys
 
             (params, opt_state, grad_acc, _), (losses, evals) = jax.lax.scan(
@@ -708,18 +778,19 @@ class NetTrainer:
         sc0 = self.sample_counter
         old_pending = self._pending_train_eval
         self._pending_train_eval = None
+        # the accumulation cadence BAKED INTO the compiled body, not the
+        # live config — a multi_fn compiled before an update_period tweak
+        # applies the optimizer on its compile-time cadence, and the
+        # accumulator and the host epoch counter follow the same one
+        period = getattr(multi_fn, 'update_period',
+                         max(1, self.update_period))
+        self._sync_accumulator(period)
         with span('train.launch', 'train', k=n_steps, update=sc0):
             (self.params, self.opt_state, self.grad_acc, losses, evals) = \
                 multi_fn(self.params, self.opt_state, self.grad_acc,
                          data_stack, label_stack, self._rng,
                          self.epoch_counter, sc0, mask_stack, self.round,
                          norm)
-        # the accumulation cadence BAKED INTO the compiled body, not the
-        # live config — a multi_fn compiled before an update_period tweak
-        # applies the optimizer on its compile-time cadence, and the host
-        # epoch counter must follow the same one
-        period = getattr(multi_fn, 'update_period',
-                         max(1, self.update_period))
         if period == 1:
             self.epoch_counter += n_steps
         else:
@@ -921,6 +992,7 @@ class NetTrainer:
                 'it can predict/evaluate but not train')
         (data, label, extra, mask, host_label, bs, num_batch_padd,
          norm) = staged
+        self._sync_accumulator(self.update_period)
         do_update = (self.sample_counter + 1) % self.update_period == 0
         rng = jax.random.fold_in(self._rng, 1 + self.sample_counter * 131 +
                                  self.round)
@@ -1123,6 +1195,7 @@ class NetTrainer:
         pipelines that pre-stage batches to hide host->device latency.
         ``norm``: required (as from :meth:`_norm_args`) when ``data`` is
         RAW pixels from a ``device_normalize=1`` chain."""
+        self._sync_accumulator(self.update_period)
         do_update = (self.sample_counter + 1) % self.update_period == 0
         rng = jax.random.fold_in(self._rng, 1 + self.sample_counter * 131 +
                                  self.round)
@@ -1332,26 +1405,35 @@ class NetTrainer:
         return out[:n]
 
     # --- checkpointing ----------------------------------------------------
-    def save_training_state(self, ckpt_dir: str, step: int,
-                            block: bool = True, retry=None) -> str:
-        """Beyond-reference EXACT resume state: params + optimizer state
-        (momentum/Adam moments) + gradient accumulator + counters, via the
-        sharded orbax path (nnet/sharded_ckpt.py).  The reference model
-        file deliberately drops optimizer state (``nnet_impl:82-87`` saves
-        layer blobs only — parity preserved in :meth:`save_model`); this
-        sidecar makes ``continue=1`` bit-exact mid-momentum.  Works for
-        mesh-sharded state (shards save/restore in place)."""
-        from . import sharded_ckpt
+    def _training_state(self) -> dict:
+        """The exact-resume tree: params, optimizer state, counters and,
+        where ``update_period > 1`` keeps one, the gradient accumulator
+        (a period-1 trainer has none, and its sidecar holds none)."""
         tree = {'params': self.params, 'opt_state': self.opt_state,
-                'grad_acc': self.grad_acc,
                 'counters': {
                     # numpy (not jnp): int64 survives regardless of the
                     # jax x64 flag
                     'epoch': np.asarray(self.epoch_counter, np.int64),
                     'sample': np.asarray(self.sample_counter, np.int64),
                     'round': np.asarray(self.round, np.int64)}}
-        return sharded_ckpt.save_sharded(ckpt_dir, step, tree, block=block,
-                                         retry=retry)
+        if self.grad_acc is not None:
+            tree['grad_acc'] = self.grad_acc
+        return tree
+
+    def save_training_state(self, ckpt_dir: str, step: int,
+                            block: bool = True, retry=None) -> str:
+        """Beyond-reference EXACT resume state: params + optimizer state
+        (momentum/Adam moments) + counters + the gradient accumulator of
+        an ``update_period > 1`` trainer, via the sharded orbax path
+        (nnet/sharded_ckpt.py).  The reference model
+        file deliberately drops optimizer state (``nnet_impl:82-87`` saves
+        layer blobs only — parity preserved in :meth:`save_model`); this
+        sidecar makes ``continue=1`` bit-exact mid-momentum.  Works for
+        mesh-sharded state (shards save/restore in place)."""
+        from . import sharded_ckpt
+        return sharded_ckpt.save_sharded(ckpt_dir, step,
+                                         self._training_state(),
+                                         block=block, retry=retry)
 
     def snapshot_training_state(self):
         """Donation-safe snapshot of the exact-resume tree (same structure
@@ -1364,13 +1446,7 @@ class NetTrainer:
         NaN-streak rule) must be resolved BEFORE taking the snapshot —
         once taken, the writer will commit it."""
         from ..runtime.async_ckpt import snapshot_tree
-        return snapshot_tree(
-            {'params': self.params, 'opt_state': self.opt_state,
-             'grad_acc': self.grad_acc,
-             'counters': {
-                 'epoch': np.asarray(self.epoch_counter, np.int64),
-                 'sample': np.asarray(self.sample_counter, np.int64),
-                 'round': np.asarray(self.round, np.int64)}})
+        return snapshot_tree(self._training_state())
 
     def load_training_state(self, ckpt_dir: str,
                             step: Optional[int] = None,
@@ -1391,23 +1467,45 @@ class NetTrainer:
         ``fallback=True`` restores resiliently: the newest step that
         passes integrity verification wins, corrupt ones are quarantined
         (``sharded_ckpt.restore_resilient``) — the supervisor's
-        restore-last-good path."""
+        restore-last-good path.
+
+        The accumulator follows this trainer's ``update_period``, not the
+        writer's: a sidecar without one (written at period 1) restores
+        into a period-P trainer as zeros, which is what it was; a sidecar
+        with one (period P, or any written before PR 32) restores into a
+        period-1 trainer only if it is all zeros, and raises if gradients
+        would be lost."""
         from . import sharded_ckpt
-        like = {'params': self.params, 'opt_state': self.opt_state,
-                'grad_acc': self.grad_acc,
-                'counters': {'epoch': np.asarray(0, np.int64),
-                             'sample': np.asarray(0, np.int64),
-                             'round': np.asarray(0, np.int64)}}
+
+        def like(saved_keys):
+            tree = self._training_state()
+            tree.pop('grad_acc', None)
+            if 'grad_acc' in saved_keys:
+                tree['grad_acc'] = jax.tree.map(
+                    lambda p: jax.ShapeDtypeStruct(p.shape, p.dtype,
+                                                   sharding=p.sharding),
+                    self.params)
+            return tree
+
         if fallback:
             tree, got = sharded_ckpt.restore_resilient(ckpt_dir, like,
                                                        retry=retry)
         else:
             tree, got = sharded_ckpt.restore_sharded(ckpt_dir, like, step,
                                                      retry=retry)
+        acc = tree.get('grad_acc')
+        if self.update_period > 1:
+            acc = self._zero_accumulator() if acc is None else acc
+        elif acc is not None:
+            self._require_empty(
+                acc, f'restoring step {got} of {ckpt_dir} into an '
+                f'update_period = 1 trainer')
+            acc = None
         if restore_params:
             self.params = tree['params']
         self.opt_state = tree['opt_state']
-        self.grad_acc = tree['grad_acc']
+        self.grad_acc = acc
+        self._record_state()
         c = tree['counters']
         self.epoch_counter = int(c['epoch'])
         self.sample_counter = int(c['sample'])

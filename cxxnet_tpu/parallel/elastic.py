@@ -854,9 +854,10 @@ class ElasticStepper:
         self.apply_fn = apply_fn if apply_fn is not None \
             else trainer.compile_apply_grad()
         self.updates = 0
-        # gradient wire format, fixed at construction from grad_acc
-        # (same structure/shardings as params)
-        leaves, self._treedef = jax.tree.flatten(trainer.grad_acc)
+        # gradient wire format, fixed at construction from params (the
+        # gradients' structure and shardings; an update_period = 1
+        # trainer keeps no accumulator to read them from)
+        leaves, self._treedef = jax.tree.flatten(trainer.params)
         self._leaf_shapes = [l.shape for l in leaves]
         self._leaf_sizes = [int(np.prod(s)) for s in self._leaf_shapes]
         self._leaf_shardings = [l.sharding for l in leaves]

@@ -11,7 +11,8 @@ applied to checkpoint I/O):
 1. **Snapshot** — at the save boundary the param/opt trees are copied
    *on device* (:func:`snapshot_tree`): a cheap, non-blocking dispatch
    that creates fresh buffers, so the trainer's next donated step
-   (``train_step`` donates params/opt_state/grad_acc) cannot invalidate
+   (``train_step`` donates params, opt_state and, where ``update_period
+   > 1`` keeps one, grad_acc) cannot invalidate
    what the writer is about to read.  The device→host transfer happens in
    the background, off the step loop.
 2. **Background write** — :class:`AsyncCheckpointer` hands the snapshot to

@@ -64,7 +64,8 @@ def trace_owner() -> Optional[str]:
 
 # --- reading the program's own names back -----------------------------------
 # Net.forward runs every conf layer in a ``jax.named_scope`` and train_step
-# its ``nan_gate`` / ``grad_acc`` / ``update``; every Pallas kernel has a
+# its ``nan_gate`` / ``update`` (and ``grad_acc``, which only an
+# ``update_period > 1`` program has); every Pallas kernel has a
 # ``name=`` (ops/pallas_kernels.KERNEL_NAMES).  The compiler keeps both: the
 # scope in the instruction's ``op_name`` (``jit(train_step)/jvp(l03_lrn)/..``
 # forward, ``transpose(jvp(l03_lrn))`` backward), the kernel's name as the

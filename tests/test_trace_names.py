@@ -83,15 +83,25 @@ def step_hlo():
     return _lowered_step(_trainer()).compile().as_text()
 
 
+@pytest.fixture(scope='module')
+def period2_step_hlo():
+    return _lowered_step(
+        _trainer('update_period = 2\n')).compile().as_text()
+
+
 @pytest.mark.parametrize('scope', SCOPES)
 def test_layer_is_forward_and_backward_in_the_step_program(step_hlo, scope):
     assert f'/jvp({scope})/' in step_hlo
     assert f'/transpose(jvp({scope}))/' in step_hlo
 
 
-def test_step_parts_are_scoped(step_hlo):
+def test_step_parts_are_scoped(step_hlo, period2_step_hlo):
     assert re.search(r'op_name="jit\(train_step\)/update/', step_hlo)
-    assert 'op_name="jit(train_step)/grad_acc/' in step_hlo
+    # the accumulator is a part of update_period > 1 programs only
+    assert 'grad_acc/' not in step_hlo
+    assert re.search(r'op_name="jit\(train_step\)/update/',
+                     period2_step_hlo)
+    assert 'op_name="jit(train_step)/grad_acc/' in period2_step_hlo
     # the input's transpose and the gate melt into their neighbours'
     # fusions once compiled; the lowered program still says whose they are
     nan = _lowered_step(_trainer('nan_action = skip\n')).as_text(
@@ -100,8 +110,9 @@ def test_step_parts_are_scoped(step_hlo):
     assert '"jit(train_step)/nan_gate/' in nan
 
 
-def test_scanned_step_carries_the_same_scopes():
-    tr = _trainer()
+@pytest.mark.parametrize('period', [1, 2])
+def test_scanned_step_carries_the_same_scopes(period):
+    tr = _trainer(f'update_period = {period}\n')
     fn = tr.compile_multi_step(2)
     staged = [tr.stage_batch(_batch()) for _ in range(2)]
     stack = lambda i: tr._device_stack([s[i] for s in staged])  # noqa: E731
@@ -109,8 +120,9 @@ def test_scanned_step_carries_the_same_scopes():
         tr.params, tr.opt_state, tr.grad_acc, stack(0), stack(1), tr._rng,
         tr.epoch_counter, 0, stack(3), tr.round).as_text(debug_info=True)
     for scope in ('"jvp(l00_conv_c1)/', '"transpose(jvp(l05_fullc_fc))/',
-                  '"update/', '"grad_acc/'):
+                  '"update/'):
         assert scope in text, scope
+    assert ('"grad_acc/' in text) == (period > 1)
 
 
 def _strip_names(mlir: str) -> str:
