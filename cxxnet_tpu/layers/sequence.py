@@ -395,9 +395,9 @@ class MoELayer(SequenceLayer):
         idx, weights = moe_ops.sigmoid_topk_route(
             x, params['router'], params['router_bias'],
             self.experts_per_token, self.routed_scaling_factor)
-        y, sizes = moe_ops.held_experts_ffn(
+        y, sizes, full = moe_ops.held_experts_ffn(
             x, idx, weights, params['wgate'], params['wup'], params['wdown'],
-            self.expert_first)
+            self.expert_first, self.experts_published)
         if 'sgate' in params:
             y = y + swiglu(x, params['sgate'], params['sup'], params['sdown'])
         out = (h.astype(jnp.float32) + y.reshape(h.shape)).astype(h.dtype)
@@ -407,7 +407,8 @@ class MoELayer(SequenceLayer):
             'moe.local_assignment_share':
                 local / float(b * s * self.experts_per_token),
             'moe.load_max_over_mean':
-                jnp.max(sizes) * self.experts_held / jnp.maximum(local, 1.0)}
+                jnp.max(sizes) * self.experts_held / jnp.maximum(local, 1.0),
+            'moe.full_buffer_share': full}
         return [out], stats
 
     def forward(self, params, inputs, ctx):

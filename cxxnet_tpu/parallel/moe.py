@@ -23,6 +23,8 @@ einsum scatters them back to token order scaled by the gate probability.
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 from jax import lax
@@ -115,6 +117,9 @@ def moe_ffn_reference(x, gate_w, w1, w2, capacity_factor: float = 2.0):
 # (those of experts held elsewhere go last) and the products run grouped over
 # it (``lax.ragged_dot``, which skips the rows past the groups: on the v5e
 # its time follows the sum of the group sizes, not the rows; PERF.md PR 29).
+# Everything around the products costs by the row, so the layer works on the
+# first rows of the buffer alone where they hold every held assignment
+# (``bounded_rows``; PERF.md PR 34).
 
 def sigmoid_topk_route(x, router_w, router_bias, k: int, scaling: float):
     """``noaux_tc`` routing with sigmoid scores: ``x``: (T, D),
@@ -170,15 +175,99 @@ def combine_sorted(ys, order, weights, valid):
         contrib)
 
 
-def held_experts_ffn(x, idx, weights, w_gate, w_up, w_down, first: int):
-    """The held experts' part of a top-k MoE layer's result for tokens
-    ``x`` (T, D): (T, D) float32, and the held experts' loads.  The rows
-    past the groups are masked on the way in and on the way out, so that
-    what the grouped products leave there reaches neither the result nor,
-    in the backward pass, the tokens' gradient."""
-    held, k = w_gate.shape[0], idx.shape[1]
-    order, sizes = sort_by_held_expert(idx, first, held)
-    valid = jnp.arange(order.shape[0]) < jnp.sum(sizes)
+#: the row tile of the grouped products the v5e's compiler makes of
+#: ``lax.ragged_dot`` (``ragged_dot_tiling="512,512,512"`` on the Mosaic
+#: calls of the compiled step, held by tests/test_v5e_compile.py)
+ROW_TILE = 512
+
+
+def bounded_rows(assignments: int, held: int, published: int) -> int:
+    """Rows of the sorted buffer that the layer works on when the held
+    assignments fit them: twice the share a balanced router would send here
+    (``assignments * held / published``), up to a multiple of ``ROW_TILE``,
+    at most all ``assignments``.  Derived from the layer's shape, no
+    setting: 8,192 of 32,768 for 8 of 64 experts held over 8,192 tokens x 4,
+    and all of them where the chip holds every expert.  (Further sizes
+    between the two cost a set-up that grows with every compiled branch:
+    PERF.md 6, PR 34.)"""
+    rows = -(-2 * assignments * held // published)
+    return min(-(-rows // ROW_TILE) * ROW_TILE, assignments)
+
+
+def _ffn_over_rows(rows: int, x, order, weights, sizes, w_gate, w_up,
+                   w_down):
+    """``held_experts_ffn``'s result from the first ``rows`` rows of the
+    sorted order: every held assignment, if they number at most ``rows``.
+    The rows past the groups are masked on the way in and on the way out,
+    so that what the grouped products leave there reaches neither the
+    result nor, in the backward pass, the tokens' gradient."""
+    k = weights.shape[1]
+    order = order[:rows]
+    valid = jnp.arange(rows) < jnp.sum(sizes)
     xs = jnp.where(valid[:, None], x[order // k], jnp.zeros((), x.dtype))
     ys = grouped_swiglu(xs, w_gate, w_up, w_down, sizes)
-    return combine_sorted(ys, order, weights, valid), sizes
+    return combine_sorted(ys, order, weights, valid)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _ffn_over_fitting_rows(rows: int, x, order, weights, sizes, *w):
+    """``_ffn_over_rows`` over ``rows`` rows where the held assignments fit
+    them and over all of them where they do not, and the same choice again
+    in the backward pass.  Its own derivative rule because autodiff through
+    ``lax.cond`` hands every branch's residuals from the forward conditional
+    to the backward one, the untaken branch's zero-filled (+2.08 GB of the
+    step's temporaries at the published widths): here each pass's
+    conditional keeps what it computes to itself."""
+    return lax.cond(jnp.sum(sizes) <= rows,
+                    functools.partial(_ffn_over_rows, rows),
+                    functools.partial(_ffn_over_rows, order.shape[0]),
+                    x, order, weights, sizes, *w)
+
+
+def _fitting_fwd(rows, *operands):
+    return _ffn_over_fitting_rows(rows, *operands), operands
+
+
+# Jitted: every expert layer of a net calls it with the same shapes, so the
+# step's trace holds the two pullbacks once and not once a layer (tracing and
+# lowering are counted in every run's set-up).  The forward rule is not: the
+# compiler then files every layer's forward branches under one layer's scope.
+@functools.partial(jax.jit, static_argnums=0)
+def _fitting_bwd(rows, operands, dy):
+    x, order, weights, sizes, *w = operands
+
+    def pull(n):
+        def back(dy, x, weights, *w):
+            return jax.vjp(lambda x, weights, *w: _ffn_over_rows(
+                n, x, order, weights, sizes, *w), x, weights, *w)[1](dy)
+        return back
+
+    dx, dweights, *dw = lax.cond(jnp.sum(sizes) <= rows, pull(rows),
+                                 pull(order.shape[0]), dy, x, weights, *w)
+    return (dx, None, dweights, None, *dw)
+
+
+_ffn_over_fitting_rows.defvjp(_fitting_fwd, _fitting_bwd)
+
+
+def held_experts_ffn(x, idx, weights, w_gate, w_up, w_down, first: int,
+                     published: int):
+    """The held experts' part of a top-k MoE layer's result for tokens
+    ``x`` (T, D), of ``published`` experts in all: (T, D) float32, the held
+    experts' loads, and whether the whole buffer was worked on (0 or 1).
+
+    The sorted order puts every held assignment first, so the gather, the
+    masks, the products' buffers, the combine and their transposes run over
+    the first ``bounded_rows`` rows whenever the held assignments fit them:
+    the same rows in the same order as over all ``T * k``.  When they do
+    not fit, the layer works on the whole buffer: nothing is dropped at any
+    imbalance, it only costs what the worst case costs."""
+    held = w_gate.shape[0]
+    order, sizes = sort_by_held_expert(idx, first, held)
+    total = order.shape[0]
+    rows = bounded_rows(total, held, published)
+    operands = (x, order, weights, sizes, w_gate, w_up, w_down)
+    if rows == total:
+        return _ffn_over_rows(total, *operands), sizes, jnp.float32(0.0)
+    full = (jnp.sum(sizes) > rows).astype(jnp.float32)
+    return _ffn_over_fitting_rows(rows, *operands), sizes, full

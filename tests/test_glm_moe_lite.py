@@ -21,6 +21,7 @@ from cxxnet_tpu.io.data import DataBatch                       # noqa: E402
 from cxxnet_tpu.layers import ForwardContext, NodeSpec         # noqa: E402
 from cxxnet_tpu.layers.sequence import MoELayer                # noqa: E402
 from cxxnet_tpu.nnet.trainer import NetTrainer                 # noqa: E402
+from cxxnet_tpu.parallel import moe as moe_ops                 # noqa: E402
 from cxxnet_tpu.utils.config import parse_config_file          # noqa: E402
 
 TINY = os.path.join(ROOT, 'example', 'LM', 'tiny-glm.conf')
@@ -248,11 +249,14 @@ CFG8 = dict(nhidden=48, experts_published=8, experts_held=8, expert_first=0,
             experts_per_token=2, routed_scaling_factor=1.8)
 
 
-def test_the_shares_add_up_to_the_whole_layer():
+@pytest.mark.parametrize('seq', [32, 256])
+def test_the_shares_add_up_to_the_whole_layer(seq):
     """Outputs of the four shares (2 held of 8 each), the shared expert and
-    the residual counted once, add up to the uncut reference's layer."""
+    the residual counted once, add up to the uncut reference's layer: at 64
+    tokens, where a share's buffer has one size, and at 512, where each
+    share works on half of its 1,024 sorted rows."""
     _, p = _whole_moe_params(jax.random.PRNGKey(1))
-    h = np.asarray(jax.random.normal(jax.random.PRNGKey(2), (2, 1, 32, 64)))
+    h = np.asarray(jax.random.normal(jax.random.PRNGKey(2), (2, 1, seq, 64)))
     ctx = ForwardContext(is_train=False)
     no_shared = {k: v for k, v in p.items() if not k.startswith('s')}
     total = np.zeros_like(h)
@@ -262,6 +266,7 @@ def test_the_shares_add_up_to_the_whole_layer():
             _share(no_shared, first, 2), [jnp.asarray(h)], ctx)
         total += np.asarray(out[0]) - h            # the routed part alone
         shares.append(float(stats['moe.local_assignment_share']))
+        assert float(stats['moe.full_buffer_share']) == 0.0
     assert abs(sum(shares) - 1.0) < 1e-6           # every assignment, once
     shared_only = {**p, 'wgate': p['wgate'][:1] * 0, 'wup': p['wup'][:1] * 0,
                    'wdown': p['wdown'][:1] * 0}
@@ -271,14 +276,18 @@ def test_the_shares_add_up_to_the_whole_layer():
     np.testing.assert_allclose((h + total + shared)[:, 0], want, atol=2e-5)
 
 
+@pytest.mark.parametrize('seq', [32, 256])
 @pytest.mark.parametrize('favoured', [0, 1, 5])
-def test_nothing_is_dropped_when_every_token_picks_one_expert(favoured):
+def test_nothing_is_dropped_when_every_token_picks_one_expert(favoured, seq):
     """A correction bias that sends every token to one expert (and its
-    second choice wherever): the layer has no capacity to overflow."""
+    second choice wherever): the layer has no capacity to overflow.  At 512
+    tokens the favoured held expert's 512 first choices and the other's
+    second choices pass the 512 rows of the bounded buffer: the layer works
+    on all 1,024 and says so."""
     _, p = _whole_moe_params(jax.random.PRNGKey(3))
     p['router_bias'] = np.zeros(8, np.float32)
     p['router_bias'][favoured] = 10.0
-    h = np.asarray(jax.random.normal(jax.random.PRNGKey(4), (2, 1, 32, 64)))
+    h = np.asarray(jax.random.normal(jax.random.PRNGKey(4), (2, 1, seq, 64)))
     out, stats = _moe_layer(0, 2).forward_with_stats(
         _share(p, 0, 2), [jnp.asarray(h)], ForwardContext(is_train=False))
     want = _ref_moe({**CFG8, 'experts_held': 2}, h[:, 0], _share(p, 0, 2))
@@ -288,6 +297,158 @@ def test_nothing_is_dropped_when_every_token_picks_one_expert(favoured):
         # least the 64 first choices, of 128 assignments
         assert float(stats['moe.local_assignment_share']) >= 0.5
         assert float(stats['moe.load_max_over_mean']) > 1.0
+    assert float(stats['moe.full_buffer_share']) == float(
+        favoured < 2 and seq == 256)
+
+
+# --- the bounded buffer against the whole one --------------------------------
+
+MOE_LEAVES = ('norm', 'router', 'wgate', 'wup', 'wdown', 'sgate', 'sup',
+              'sdown')
+FFN_OUTPUTS = ('result', 'tokens', 'weights', 'wgate', 'wup', 'wdown')
+HELD = (300, 512, 513, 1500)
+
+
+def _assert_equal_to_float32(got, want):
+    """Equal to float32's last digits: the CPU blocks a product by its rows,
+    so two buffer sizes add the same terms in another order."""
+    assert np.abs(want).max() > 0
+    np.testing.assert_allclose(got, want, rtol=1e-6,
+                               atol=1e-6 * np.abs(want).max())
+
+
+def test_the_bound_comes_from_the_layers_shape():
+    """Twice the balanced share up to the products' row tile, never past the
+    buffer: the published cell's layer, the tiny twin's (one size, one path),
+    the 512-token twin of the tests below, a chip that holds every expert, a
+    share that is no multiple of the tile."""
+    assert moe_ops.bounded_rows(8192 * 4, 8, 64) == 8192
+    assert moe_ops.bounded_rows(64 * 2, 2, 8) == 64 * 2
+    assert moe_ops.bounded_rows(512 * 2, 2, 8) == 512
+    assert moe_ops.bounded_rows(8192 * 4, 64, 64) == 8192 * 4
+    assert moe_ops.bounded_rows(1000 * 4, 8, 64) == 1024     # 1,000 rounded up
+    assert 1024 % moe_ops.ROW_TILE == 0
+
+
+@pytest.fixture(scope='module')
+def hand_routed():
+    """``held_experts_ffn`` on hand-made routings of 1,024 tokens x 2 over
+    16 experts, 2 held: a bound of 512 of 2,048 rows, with 300, 512, 513 and
+    1,500 assignments held, against the one path over all 2,048 rows (what
+    the layer was before it had a bound): result, loads, and the gradients
+    of the tokens, the routing weights and the three matrices."""
+    t, k, d, f = 1024, 2, 64, 48
+    assert moe_ops.bounded_rows(t * k, 2, 16) == 512
+    keys = jax.random.split(jax.random.PRNGKey(11), 6)
+    x = jax.random.normal(keys[0], (t, d))
+    weights = jax.random.uniform(keys[1], (t, k), minval=0.2, maxval=1.0)
+    ws = [0.2 * jax.random.normal(key, shape) for key, shape in zip(
+        keys[2:5], [(2, d, f), (2, d, f), (2, f, d)])]
+    g = jax.random.normal(keys[5], (t, d))
+
+    def routing(held):
+        # the first ``held`` assignments (row-major) go to the two held
+        # experts in turn, unevenly, the others to experts held elsewhere
+        flat = 2 + np.arange(t * k) % 14
+        flat[:held] = (np.arange(held) % 3 == 0)
+        return jnp.asarray(flat.reshape(t, k), jnp.int32)
+
+    def run(fn):
+        def loss(x, weights, *ws):
+            y, sizes, full = fn(x, weights, *ws)
+            return jnp.sum(y * g), (y, sizes, full)
+        return jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4),
+                                          has_aux=True))
+
+    found = {}
+    for held in HELD:
+        idx = routing(held)
+        got = run(lambda x, w, *ws: moe_ops.held_experts_ffn(
+            x, idx, w, *ws, 0, 16))(x, weights, *ws)
+
+        def one_path(x, w, *ws):
+            order, sizes = moe_ops.sort_by_held_expert(idx, 0, 2)
+            return moe_ops._ffn_over_rows(t * k, x, order, w, sizes,
+                                          *ws), sizes, 0.0
+        found[held] = jax.device_get((got, run(one_path)(x, weights, *ws)))
+    return found
+
+
+@pytest.mark.parametrize('what', FFN_OUTPUTS)
+@pytest.mark.parametrize('held', HELD)
+def test_either_buffer_gives_the_whole_ones_result_and_gradients(
+        hand_routed, held, what):
+    """Up to the bound's 512 held assignments the layer works on 512 rows,
+    from 513 on all 2,048: either way what the one path over all of them
+    gives, and the counter says which."""
+    ((_, (y, sizes, full)), grads), ((_, (want_y, want_sizes, _)),
+                                     want_grads) = hand_routed[held]
+    assert sizes.sum() == held and (sizes == want_sizes).all()
+    assert float(full) == float(held > 512)
+    at = FFN_OUTPUTS.index(what) - 1
+    _assert_equal_to_float32(*((y, want_y) if what == 'result'
+                               else (grads[at], want_grads[at])))
+
+
+@pytest.fixture(scope='module')
+def both_buffers():
+    """Two held of eight experts over 512 tokens x 2, so that the bound
+    (512 rows) is under the buffer (1,024): output, counters and gradients
+    of the layer as it is and with the bound taken away (the one path of
+    the layer before it had a bound), on a routing that fits the bound and
+    on one that passes it (every token's first choice a held expert)."""
+    _, p = _whole_moe_params(jax.random.PRNGKey(7))
+    p = _share(p, 0, 2)
+    h = jax.random.normal(jax.random.PRNGKey(8), (2, 1, 256, 64))
+    g = jax.random.normal(jax.random.PRNGKey(9), h.shape)
+    ctx = ForwardContext(is_train=True)
+    layer = _moe_layer(0, 2)
+
+    def run(p, h):
+        out, stats = layer.forward_with_stats(p, [h], ctx)
+        return jnp.sum(out[0] * g), (out[0], stats)
+
+    def step(p):                   # a trace of its own each time it is asked
+        return jax.device_get(jax.jit(jax.value_and_grad(
+            run, argnums=(0, 1), has_aux=True))(p, h))
+
+    def both(p):
+        p = {k: jnp.asarray(v) for k, v in p.items()}
+        with pytest.MonkeyPatch.context() as patch:
+            patch.setattr(moe_ops, 'bounded_rows', lambda rows, *_: rows)
+            whole = step(p)
+        return step(p), whole
+
+    crowded = dict(p, router_bias=np.where(np.arange(8) == 1, 10.0,
+                                           0.0).astype(np.float32))
+    return {'fits': both(p), 'passes': both(crowded)}
+
+
+@pytest.mark.parametrize('routing', ['fits', 'passes'])
+def test_the_bounded_buffer_gives_the_full_ones_output(both_buffers, routing):
+    """The same rows in the same order: the output is the whole buffer's
+    and the routing counters are equal; only ``moe.full_buffer_share`` says
+    which buffer was worked on."""
+    ((_, (out, stats)), _), ((_, (want, full)), _) = both_buffers[routing]
+    _assert_equal_to_float32(out, want)
+    for name in ('moe.local_assignment_share', 'moe.load_max_over_mean'):
+        assert float(stats[name]) == float(full[name])
+    held = float(stats['moe.local_assignment_share']) * 1024
+    assert (held > 512) == (routing == 'passes')
+    assert float(stats['moe.full_buffer_share']) == float(routing == 'passes')
+    assert float(full['moe.full_buffer_share']) == 0.0   # one path: no bound
+
+
+@pytest.mark.parametrize('routing', ['fits', 'passes'])
+@pytest.mark.parametrize('leaf', MOE_LEAVES + ('tokens',))
+def test_the_bounded_buffer_gives_the_full_ones_gradient(both_buffers, leaf,
+                                                         routing):
+    """Every leaf's gradient and the tokens', through the layer's own
+    derivative rule (a conditional a pass), against autodiff through the
+    one full path."""
+    (_, (dp, dh)), (_, (want_p, want_h)) = both_buffers[routing]
+    _assert_equal_to_float32(*((dh, want_h) if leaf == 'tokens'
+                               else (dp[leaf], want_p[leaf])))
 
 
 def test_mtp_head_shares_embedding_and_head_with_the_main_model(tiny):
