@@ -47,14 +47,15 @@ def rms_norm(x, gamma, eps: float):
 
 
 def rope(x, theta: float):
-    """Rotary positions over the last axis of ``x`` (..., seq, heads, dim),
-    all ``dim`` of them, half-split layout: the pair of ``x[..., i]`` is
-    ``x[..., i + dim/2]``.  Position = index along ``seq``."""
-    seq, dim = x.shape[-3], x.shape[-1]
+    """Rotary positions over the last axis of ``x`` (..., seq, dim), all
+    ``dim`` of them, half-split layout: the pair of ``x[..., i]`` is
+    ``x[..., i + dim/2]``.  Position = index along ``seq``, the axis before
+    the last (the head-major arrays' own: nothing is moved to rotate it)."""
+    seq, dim = x.shape[-2:]
     half = dim // 2
     inv = theta ** (-jnp.arange(half, dtype=jnp.float32) * 2.0 / dim)
     ang = jnp.arange(seq, dtype=jnp.float32)[:, None] * inv[None, :]
-    cos, sin = jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
     x32 = x.astype(jnp.float32)
     a, b = x32[..., :half], x32[..., half:]
     return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
@@ -196,7 +197,13 @@ class LatentAttentionLayer(SequenceLayer):
     ``[c_kv | k_r] = x W_kva``; ``[k_nope | v] = RMSNorm(c_kv) W_kvb``;
     ``q_rope`` and ``k_r`` (one head, shared by all) rotated; scores
     ``(q_nope . k_nope + q_rope . k_rope) / sqrt(nope + rope)``, causal
-    softmax, ``out = concat(P v) W_o``.  No cache, no absorption."""
+    softmax, ``out = concat(P v) W_o``.  No cache, no absorption.
+
+    Between the projections and the kernels every activation is head-major,
+    ``(batch, heads, seq, dim)``, written so by the product that makes it
+    (``wq_b``, ``wkv_b`` viewed ``(rank, heads, dim)``; ``wo`` contracts
+    ``(heads, v_dim)`` of the kernel's output): no transposed copy in
+    either pass.  The leaves stay stored as published."""
 
     type_name = 'mla'
     type_id = kMLA
@@ -254,32 +261,40 @@ class LatentAttentionLayer(SequenceLayer):
 
     def forward(self, params, inputs, ctx):
         h = inputs[0][:, 0]                                  # (b, s, d)
-        b, s, _ = h.shape
+        b, s, d = h.shape
         dt, nh = h.dtype, self.nhead
         dot = lambda a, w: jnp.dot(                          # noqa: E731
             a, w.astype(dt), preferred_element_type=jnp.float32).astype(dt)
+
+        def heads(a, w):
+            """``a`` through ``w``'s columns, a head at a time, written
+            head-major by the product: ``(b, s, r) -> (b, nh, s, dim)``."""
+            w = w.astype(dt).reshape(w.shape[0], nh, -1)
+            return jnp.einsum('bsr,rhd->bhsd', a, w,
+                              preferred_element_type=jnp.float32).astype(dt)
+
         x = rms_norm(h, params['norm'], self.eps)
         c_q = rms_norm(dot(x, params['wq_a']), params['q_norm'], self.eps)
-        q = dot(c_q, params['wq_b']).reshape(b, s, nh,
-                                             self.nope + self.rope_dim)
-        ckv = dot(x, params['wkv_a'])
-        c_kv, k_r = ckv[..., :self.kv_lora_rank], ckv[..., self.kv_lora_rank:]
-        kv = dot(rms_norm(c_kv, params['kv_norm'], self.eps),
-                 params['wkv_b']).reshape(b, s, nh, self.nope + self.v_dim)
-        q_rope = rope(q[..., self.nope:], self.rope_theta)
-        k_rope = rope(k_r[:, :, None, :], self.rope_theta)
-        q = jnp.concatenate([q[..., :self.nope], q_rope], axis=-1)
+        q = heads(c_q, params['wq_b'])                       # [nope | rope]
+        q = jnp.concatenate(
+            [q[..., :self.nope], rope(q[..., self.nope:], self.rope_theta)],
+            axis=-1)
+        ckv = dot(x, params['wkv_a'])                        # [c_kv | k_r]
+        c_kv = rms_norm(ckv[..., :self.kv_lora_rank], params['kv_norm'],
+                        self.eps)
+        kv = heads(c_kv, params['wkv_b'])                    # [k_nope | v]
+        # one head of rotated keys, shared: broadcast inside k's concat
+        k_rope = rope(ckv[..., self.kv_lora_rank:], self.rope_theta)[:, None]
         k = jnp.concatenate(
             [kv[..., :self.nope],
-             jnp.broadcast_to(k_rope, (b, s, nh, self.rope_dim))], axis=-1)
+             jnp.broadcast_to(k_rope, (b, nh, s, self.rope_dim))], axis=-1)
         v = kv[..., self.nope:]
-        o = causal_attention(
-            q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-            v.transpose(0, 2, 1, 3),
-            1.0 / math.sqrt(self.nope + self.rope_dim), ctx.spmd_devices)
-        o = o.transpose(0, 2, 1, 3).reshape(b, s, nh * self.v_dim)
-        out = jnp.dot(o, params['wo'].astype(dt),
-                      preferred_element_type=jnp.float32)
+        o = causal_attention(q, k, v,
+                             1.0 / math.sqrt(self.nope + self.rope_dim),
+                             ctx.spmd_devices)               # (b, nh, s, v)
+        out = jnp.einsum('bhsv,hvd->bsd', o,
+                         params['wo'].astype(dt).reshape(nh, self.v_dim, d),
+                         preferred_element_type=jnp.float32)
         return [(h.astype(jnp.float32) + out).astype(dt)[:, None]]
 
 

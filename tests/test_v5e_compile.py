@@ -223,7 +223,11 @@ def test_the_whole_step_fits_the_chip(glm_step):
     step's temporaries, the gradients among them, stay under the chip's
     16.9e9 bytes with room for the forward program.  Read 11.14e9 at PR 34
     (an expert layer's full buffer is planned inside a conditional of its
-    own), 11.78e9 at PR 32; with the accumulator 14.30e9."""
+    own), 11.78e9 at PR 32; with the accumulator 14.30e9.  11.02e9 at PR 35;
+    with ``k_nope`` and ``v`` from two products it read 11.65e9: the
+    scheduler then ran the main head's loss after the MTP module's expert
+    layer had been recomputed, and both heads' float32 ``dW`` (0.51e9)
+    stood beside that layer's whole-buffer backward branch (PERF.md 6)."""
     import numpy as np
     compiled, params = glm_step
     m = compiled.memory_analysis()
@@ -323,6 +327,73 @@ def test_no_kernel_of_the_step_lost_its_name(glm_step):
             found[next((k for k in known if m.group(1).startswith(k)),
                        m.group(1))] += 1
     assert found == dict(zip(known, (12, 6, 6, 120, 30))), found
+
+
+# --- latent attention, head-major from product to kernel to product (PR 35) --
+
+def _outside_fusions(hlo):
+    """The instruction lines of every computation that runs as written: the
+    entry, loop bodies and branches, not what a fusion calls."""
+    comps = _computations(hlo)
+    fused = set(re.findall(r' fusion\(.*?calls=%([\w.\-]+)', hlo))
+    return [line for name, lines in comps.items() if name not in fused
+            for line in lines]
+
+
+def test_no_pass_moves_an_array_between_projections_and_kernels(glm_step):
+    """``q``, ``k``, ``v`` and ``o`` are written ``(batch, heads, seq,
+    dim)`` by the product or the concat that makes them: the step holds no
+    ``copy`` or ``transpose`` instruction of its own (outside a fusion)
+    whose result is a ``(1, 8192, 20, *)`` or ``(1, 20, 8192, *)`` bf16
+    array, forward, recomputation or backward, and a ``slice`` only of the
+    last axis of a head-major product's output (``v`` and ``k_nope`` out of
+    ``[k_nope | v]``, which the kernels and the concat read as arrays of
+    their own).  (The compiler's prefetches, ``copy-start`` /
+    ``copy-done``, are another opcode.)"""
+    mover = re.compile(r'= bf16\[1,(?:8192,20|20,8192),\d+\]\S* '
+                       r'(?:copy|slice|transpose)\(')
+    last_axis = re.compile(r' slice\(%fusion[.\d]*\), slice=\{\[0:1\], '
+                           r'\[0:20\], \[0:8192\], \[\d+:\d+\]\}')
+    moved = [line.strip()[:160]
+             for line in _outside_fusions(glm_step[0].as_text())
+             if mover.search(line) and not last_axis.search(line)]
+    assert not moved, moved
+
+
+@pytest.mark.parametrize('event', ['flash_attention', 'flash_mha_bwd_dq',
+                                   'flash_mha_bwd_dkv'])
+def test_a_kernel_reads_what_a_product_or_the_concat_wrote(glm_step, event):
+    """Each flash kernel's ``q`` and ``k`` (its first two operands) is the
+    output of the concat that joins the rotated part, and its ``v`` that
+    of a product's fusion, whole or a slice of its last axis (``[k_nope |
+    v]`` is one product), looked at through the compiler's prefetches and
+    bitcasts: no copy or transpose fusion stands between."""
+    from cxxnet_tpu.utils import profiler
+    lines = {}
+    for line in glm_step[0].as_text().splitlines():
+        m = profiler._HLO_INSTRUCTION.match(line)
+        if m:
+            lines[m.group(1)] = line
+    through = re.compile(
+        r' (?:copy-done|copy-start|bitcast|slice)\(%([\w.\-]+)[,)]')
+
+    def maker(name):
+        while m := through.search(lines[name]):
+            name = m.group(1)
+        return lines[name]
+
+    calls = [line for name, line in lines.items()
+             if name.startswith(event) and 'tpu_custom_call' in line]
+    assert len(calls) == (12 if event == 'flash_attention' else 6)
+    for call in calls:
+        operands = re.search(r' custom-call\(([^)]*)\)', call).group(1)
+        q, k, v = (maker(name) for name in
+                   re.findall(r'%([\w.\-]+)', operands)[:3])
+        for made in (q, k):
+            assert ' fusion(' in made and re.search(
+                r'op_name="[^"]*/concatenate"', made), made
+        assert ' fusion(' in v and 'kind=kOutput' in v and re.search(
+            r'op_name="[^"]*bsr,rhd->bhsd/dot_general"', v), v
 
 
 # the benchmark's two CNN confs (train step and evaluation forward) and the
