@@ -65,6 +65,7 @@ kMoE = 44
 kMTPJoin = 45
 kLMHeadLoss = 46
 kSeqSlice = 48
+kGQA = 49
 kPairTestGap = 1024
 
 _NAME2TYPE = {
@@ -80,7 +81,7 @@ _NAME2TYPE = {
     'ch_concat': kChConcat, 'prelu': kPRelu, 'batch_norm': kBatchNorm,
     'embedding': kEmbedding, 'rmsnorm': kRMSNorm, 'mla': kMLA,
     'swiglu': kSwiGLU, 'moe': kMoE, 'mtp_join': kMTPJoin,
-    'lm_head_loss': kLMHeadLoss, 'seq_slice': kSeqSlice,
+    'lm_head_loss': kLMHeadLoss, 'seq_slice': kSeqSlice, 'gqa': kGQA,
 }
 _TYPE2NAME = {v: k for k, v in _NAME2TYPE.items()}
 _TYPE2NAME[kMaxPooling] = 'max_pooling'  # keep canonical names on collision

@@ -1,6 +1,7 @@
-"""Sequence layers: what a decoder-only language model with latent
-attention, routed experts and a multi-token-prediction head is made of, as
-conf layer types on the same ``Net`` graph as the CNN zoo (doc/sequence.md).
+"""Sequence layers: what a decoder-only language model with latent or
+grouped, windowed and gated attention, routed experts and a
+multi-token-prediction head is made of, as conf layer types on the same
+``Net`` graph as the CNN zoo (doc/sequence.md).
 
 The node contract.  A *sequence node* is ``NodeSpec(c=d, y=1, x=seq)``,
 stored ``(batch, 1, seq, d)`` like any NHWC node.  Token ids travel as a
@@ -11,8 +12,8 @@ columns of the label matrix, one per token and head (``label_vec[0,2*seq)
 
 One layer type a sublayer, so that the scope a conf layer gets in a trace
 (``lNN_mla``, ``lNN_moe``) splits the step by sublayer.  The residual layers
-(``mla``, ``swiglu``, ``moe``) hold their pre-norm and their residual add
-inside, take and return the residual stream, and are recomputed in the
+(``mla``, ``gqa``, ``swiglu``, ``moe``) hold their pre-norm and their residual
+add inside, take and return the residual stream, and are recomputed in the
 backward pass (``recompute``): what is saved between them is the residual
 stream alone.
 
@@ -31,8 +32,8 @@ import jax.numpy as jnp
 
 from ..ops.attention import causal_attention
 from ..parallel import moe as moe_ops
-from .base import (Layer, NodeSpec, Params, kEmbedding, kLMHeadLoss, kMLA,
-                   kMoE, kMTPJoin, kRMSNorm, kSeqSlice, kSwiGLU,
+from .base import (Layer, NodeSpec, Params, kEmbedding, kGQA, kLMHeadLoss,
+                   kMLA, kMoE, kMTPJoin, kRMSNorm, kSeqSlice, kSwiGLU,
                    register_layer)
 from .loss import LossLayerBase
 
@@ -60,6 +61,51 @@ def rope(x, theta: float):
     a, b = x32[..., :half], x32[..., half:]
     return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
                            axis=-1).astype(x.dtype)
+
+
+def yarn_frequencies(dims: int, theta: float, factor: float,
+                     original_positions: int, beta_fast: float,
+                     beta_slow: float):
+    """The ``dims / 2`` rotary frequencies of ``rope_type: yarn`` (Hugging
+    Face's ``_compute_yarn_parameters``): the plain ``theta ** (-2i /
+    dims)`` where a pair turns more than ``beta_fast`` times over the
+    original context, the same over ``factor`` where it turns fewer than
+    ``beta_slow`` times, and a linear ramp between the two pairs (rounded
+    outward) in which those counts fall.  ``factor <= 1`` is plain rotary.
+    Worked out once, in float64, from the conf's numbers."""
+    import numpy as np
+    plain = theta ** (-np.arange(0, dims, 2, dtype=np.float64) / dims)
+    if factor <= 1.0:
+        return plain
+
+    def pair_turning(turns: float) -> float:
+        return dims * math.log(original_positions / (turns * 2 * math.pi)) \
+            / (2 * math.log(theta))
+
+    low = max(math.floor(pair_turning(beta_fast)), 0)
+    high = min(math.ceil(pair_turning(beta_slow)), dims - 1)
+    ramp = np.clip((np.arange(dims // 2, dtype=np.float64) - low)
+                   / ((high if high != low else high + 0.001) - low), 0, 1)
+    return plain / factor * ramp + plain * (1 - ramp)
+
+
+def rotary(x, inv_freq, attention_factor: float = 1.0):
+    """Rotary positions over the first ``2 * len(inv_freq)`` components of
+    the last axis of ``x`` (..., seq, dim), the rest passed through
+    (``partial_rotary_factor``); half-split pairs inside the rotated part,
+    position = index along ``seq``; ``cos`` and ``sin`` times
+    ``attention_factor`` (YaRN's)."""
+    seq, half = x.shape[-2], len(inv_freq)
+    ang = jnp.arange(seq, dtype=jnp.float32)[:, None] \
+        * jnp.asarray(inv_freq, jnp.float32)[None, :]
+    cos = jnp.cos(ang) * attention_factor
+    sin = jnp.sin(ang) * attention_factor
+    x32 = x.astype(jnp.float32)
+    a, b = x32[..., :half], x32[..., half:2 * half]
+    parts = [a * cos - b * sin, b * cos + a * sin]
+    if 2 * half < x.shape[-1]:
+        parts.append(x32[..., 2 * half:])
+    return jnp.concatenate(parts, axis=-1).astype(x.dtype)
 
 
 def swiglu(x, w_gate, w_up, w_down):
@@ -299,6 +345,106 @@ class LatentAttentionLayer(SequenceLayer):
 
 
 @register_layer
+class GroupedAttentionLayer(SequenceLayer):
+    """Grouped-query attention with a causal window, rotary positions by the
+    layer's kind and a gate a head, with its pre-norm and its residual:
+    ``h + (g * Attn(RMSNorm(h))) W_o``, no biases.
+
+    ``x = RMSNorm(h)``; ``q = x W_q`` as ``nhead`` heads of ``head_dim``,
+    ``k = x W_k`` and ``v = x W_v`` as ``nkvhead`` heads: query head ``i``
+    reads key/value head ``i // (nhead / nkvhead)``.  The first
+    ``rotary_dims`` components of every ``q`` and ``k`` head are rotated
+    (``rope_theta``; with ``rope_factor > 1`` the frequencies and the
+    ``rope_attention_factor`` of YaRN, :func:`yarn_frequencies`).  Scores
+    ``q . k / sqrt(head_dim)``; position ``i`` sees ``j <= i`` and, with
+    ``window > 0``, only ``i - j < window``.  ``g = sigmoid(x W_g)``, one
+    number a head and position, scales the head's output before ``W_o``.
+
+    ``nhead``, ``window`` and the rotary keys differ by layer; ``nkvhead``,
+    ``head_dim`` and ``eps`` are global pairs of the conf.  Head-major from
+    the products to the kernels and back, as ``mla``: no transposed copy."""
+
+    type_name = 'gqa'
+    type_id = kGQA
+    param_fields = ('norm', 'wq', 'wk', 'wv', 'wgate', 'wo')
+    recompute = True
+
+    def __init__(self, name: str = ''):
+        super().__init__(name=name)
+        self.nhead = self.nkvhead = self.head_dim = 0
+        self.window = 0
+        self.rope_theta = 10000.0
+        self.rotary_dims = 0                 # 0: all of head_dim
+        self.rope_factor = 1.0               # YaRN's; 1 is plain rotary
+        self.rope_original_positions = 0
+        self.rope_beta_fast, self.rope_beta_slow = 32.0, 1.0
+        self.rope_attention_factor = 1.0
+
+    def set_param(self, name, val):
+        super().set_param(name, val)
+        if name in ('nhead', 'nkvhead', 'head_dim', 'window', 'rotary_dims',
+                    'rope_original_positions'):
+            setattr(self, name, int(val))
+        if name in ('rope_theta', 'rope_factor', 'rope_beta_fast',
+                    'rope_beta_slow', 'rope_attention_factor'):
+            setattr(self, name, float(val))
+
+    def infer_shapes(self, in_specs):
+        rot = self.rotary_dims or self.head_dim
+        if min(self.nhead, self.nkvhead, self.head_dim) <= 0 \
+                or self.nhead % self.nkvhead or self.window < 0 \
+                or rot % 2 or rot > self.head_dim \
+                or (self.rope_factor > 1
+                    and self.rope_original_positions <= 0):
+            raise ValueError(
+                'gqa: set nhead (a multiple of nkvhead), nkvhead, head_dim, '
+                'window >= 0, rotary_dims (even, at most head_dim) and, with '
+                'rope_factor > 1, rope_original_positions')
+        return [self._seq_spec(in_specs[0], 'gqa')]
+
+    def init_params(self, rng, in_specs, dtype=jnp.float32) -> Params:
+        d, hd = in_specs[0].c, self.head_dim
+        return {'norm': jnp.ones((d,), dtype),
+                'wq': self._w(rng, 0, (d, self.nhead * hd), dtype),
+                'wk': self._w(rng, 1, (d, self.nkvhead * hd), dtype),
+                'wv': self._w(rng, 2, (d, self.nkvhead * hd), dtype),
+                'wgate': self._w(rng, 3, (d, self.nhead), dtype),
+                'wo': self._w(rng, 4, (self.nhead * hd, d), dtype)}
+
+    def forward(self, params, inputs, ctx):
+        h = inputs[0][:, 0]                                  # (b, s, d)
+        d, dt, hd = h.shape[2], h.dtype, self.head_dim
+
+        def heads(a, w, n):
+            """``a`` through ``w``'s columns, a head at a time, written
+            head-major by the product: ``(b, s, d) -> (b, n, s, hd)``."""
+            return jnp.einsum('bsr,rhd->bhsd', a,
+                              w.astype(dt).reshape(d, n, hd),
+                              preferred_element_type=jnp.float32).astype(dt)
+
+        inv_freq = yarn_frequencies(
+            self.rotary_dims or hd, self.rope_theta, self.rope_factor,
+            self.rope_original_positions, self.rope_beta_fast,
+            self.rope_beta_slow)
+        x = rms_norm(h, params['norm'], self.eps)
+        q = rotary(heads(x, params['wq'], self.nhead), inv_freq,
+                   self.rope_attention_factor)
+        k = rotary(heads(x, params['wk'], self.nkvhead), inv_freq,
+                   self.rope_attention_factor)
+        v = heads(x, params['wv'], self.nkvhead)
+        o = causal_attention(q, k, v, 1.0 / math.sqrt(hd), ctx.spmd_devices,
+                             window=self.window)             # (b, nh, s, hd)
+        gate = jax.nn.sigmoid(jnp.dot(x, params['wgate'].astype(dt),
+                                      preferred_element_type=jnp.float32))
+        o = (o.astype(jnp.float32)
+             * jnp.moveaxis(gate, 2, 1)[..., None]).astype(dt)
+        out = jnp.einsum('bhsv,hvd->bsd', o,
+                         params['wo'].astype(dt).reshape(self.nhead, hd, d),
+                         preferred_element_type=jnp.float32)
+        return [(h.astype(jnp.float32) + out).astype(dt)[:, None]]
+
+
+@register_layer
 class SwiGLULayer(SequenceLayer):
     """The dense gated FFN with its pre-norm and residual:
     ``h + W_down(silu(W_gate x) * (W_up x))``, ``x = RMSNorm(h)``."""
@@ -332,8 +478,10 @@ class MoELayer(SequenceLayer):
     """Routed experts of which this chip holds ``experts_held`` from
     ``expert_first`` on, a shared expert, pre-norm and residual inside.
 
-    ``s = sigmoid(x W_r)`` over all ``experts_published`` experts, float32;
-    chosen = top-``experts_per_token`` of ``s + b``; ``w_e =
+    ``s = sigmoid(x W_r)`` (``router_score = sigmoid``, the default) or
+    ``softmax(x W_r)`` (``router_score = softmax``) over all
+    ``experts_published`` experts, float32; chosen =
+    top-``experts_per_token`` of ``s + b``; ``w_e =
     routed_scaling_factor * s_e / sum_chosen s`` (the sum over all chosen,
     held here or not); ``y = sum_{e chosen and held} w_e SwiGLU_e(x) +
     SwiGLU_shared(x)``.  What the experts held elsewhere would add is left
@@ -359,6 +507,7 @@ class MoELayer(SequenceLayer):
         self.experts_per_token = 1
         self.routed_scaling_factor = 1.0
         self.shared_experts = 1
+        self.router_score = 'sigmoid'
 
     def set_param(self, name, val):
         super().set_param(name, val)
@@ -374,6 +523,11 @@ class MoELayer(SequenceLayer):
             self.routed_scaling_factor = float(val)
         if name == 'shared_experts':
             self.shared_experts = int(val)
+        if name == 'router_score':
+            if val not in moe_ops.ROUTER_SCORES:
+                raise ValueError(f'moe: router_score = {val!r}, not one of '
+                                 f'{sorted(moe_ops.ROUTER_SCORES)}')
+            self.router_score = val
 
     def infer_shapes(self, in_specs):
         pub, held = self.experts_published, self.experts_held
@@ -407,9 +561,10 @@ class MoELayer(SequenceLayer):
         h = inputs[0]
         b, _, s, d = h.shape
         x = rms_norm(h, params['norm'], self.eps).reshape(b * s, d)
-        idx, weights = moe_ops.sigmoid_topk_route(
+        idx, weights = moe_ops.topk_route(
             x, params['router'], params['router_bias'],
-            self.experts_per_token, self.routed_scaling_factor)
+            self.experts_per_token, self.routed_scaling_factor,
+            self.router_score)
         y, sizes, full = moe_ops.held_experts_ffn(
             x, idx, weights, params['wgate'], params['wup'], params['wdown'],
             self.expert_first, self.experts_published)

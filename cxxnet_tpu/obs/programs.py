@@ -51,6 +51,7 @@ from __future__ import annotations
 
 import collections
 import os
+import re
 import threading
 import time
 from typing import Callable, Dict, List, Optional, Tuple
@@ -71,6 +72,14 @@ __all__ = ['ProgramLedger', 'LedgerProgram', 'ProgramEntry', 'get_ledger',
 PEAK_BF16_TFLOPS: Tuple[Tuple[str, float], ...] = (
     ('v6', 918.0), ('v5p', 459.0), ('v5', 197.0), ('v4', 275.0),
 )
+
+
+
+def an_instruction_a_line(hlo_text: str) -> str:
+    """``hlo_text`` with the one attribute the compiler prints over three
+    lines (``kernel_metadata={``, the kernel's JSON, ``}}``) on one."""
+    return re.sub(r'(kernel_metadata=\{)\n([^\n]*)\n(\}\})', r'\1\2\3',
+                  hlo_text)
 
 
 class UnknownDeviceKindError(LookupError):
@@ -286,8 +295,12 @@ class _WrappedJit:
         partitioned over a mesh reads as the one that runs), without
         executing it and without counting as a compilation.  What
         ``utils/profiler.device_time_by_scope`` joins a trace's events
-        to."""
-        return self._probe_compile(args, kwargs).as_text()
+        to.  An instruction a line: a kernel's attributes that the compiler
+        prints over several lines (the JSON that JAX's splash-attention
+        kernels attach) are joined, so that a reader by the line finds the
+        instruction's ``op_name``."""
+        return an_instruction_a_line(
+            self._probe_compile(args, kwargs).as_text())
 
     def ensure_compiled(self, *args, **kwargs) -> Optional['ProgramEntry']:
         """Register (and analyze) this signature WITHOUT executing —

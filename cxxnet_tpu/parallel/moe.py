@@ -119,18 +119,27 @@ def moe_ffn_reference(x, gate_w, w1, w2, capacity_factor: float = 2.0):
 # its time follows the sum of the group sizes, not the rows; PERF.md PR 29).
 # Everything around the products costs by the row, so the layer works on the
 # first rows of the buffer alone where they hold every held assignment
-# (``bounded_rows``; PERF.md PR 34).
+# (``bounded_rows``; PERF.md PR 34), and through as many blocks of that size
+# as hold one where they do not (PR 36: at 81,920 assignments of 3,072 wide
+# rows the whole buffer's temporaries, about 5 GB, fit no chip's plan).
 
-def sigmoid_topk_route(x, router_w, router_bias, k: int, scaling: float):
-    """``noaux_tc`` routing with sigmoid scores: ``x``: (T, D),
-    ``router_w``: (D, E), ``router_bias``: (E,), the correction bias that
-    enters the choice and not the weights.  Returns ``idx`` (T, k) int32
-    and ``weights`` (T, k) float32 = ``scaling * s_e / sum_chosen s``.
+#: the router's score functions (``router_score`` of a ``moe`` layer)
+ROUTER_SCORES = {'sigmoid': jax.nn.sigmoid,
+                 'softmax': functools.partial(jax.nn.softmax, axis=-1)}
+
+
+def topk_route(x, router_w, router_bias, k: int, scaling: float,
+               score: str = 'sigmoid'):
+    """Top-``k`` routing: ``x``: (T, D), ``router_w``: (D, E), scores ``s``
+    = ``score`` (``sigmoid``: ``noaux_tc``'s; ``softmax``: over all E) of
+    the logits, ``router_bias``: (E,), the correction bias that enters the
+    choice and not the weights.  Returns ``idx`` (T, k) int32 and
+    ``weights`` (T, k) float32 = ``scaling * s_e / sum_chosen s``.
     Scores in float32 at the highest precision: a choice between two
     nearly equal scores should not hang on a bf16 pass."""
-    s = jax.nn.sigmoid(jnp.dot(x.astype(jnp.float32),
-                               router_w.astype(jnp.float32),
-                               precision=lax.Precision.HIGHEST))
+    s = ROUTER_SCORES[score](jnp.dot(x.astype(jnp.float32),
+                                     router_w.astype(jnp.float32),
+                                     precision=lax.Precision.HIGHEST))
     _, idx = lax.top_k(s + router_bias.astype(jnp.float32), k)
     chosen = jnp.take_along_axis(s, idx, axis=1)
     weights = scaling * chosen / jnp.sum(chosen, axis=-1, keepdims=True)
@@ -181,46 +190,87 @@ def combine_sorted(ys, order, weights, valid):
 ROW_TILE = 512
 
 
-def bounded_rows(assignments: int, held: int, published: int) -> int:
+def bounded_rows(assignments: int, held: int, published: int,
+                 tokens: int = 0) -> int:
     """Rows of the sorted buffer that the layer works on when the held
     assignments fit them: twice the share a balanced router would send here
-    (``assignments * held / published``), up to a multiple of ``ROW_TILE``,
-    at most all ``assignments``.  Derived from the layer's shape, no
-    setting: 8,192 of 32,768 for 8 of 64 experts held over 8,192 tokens x 4,
-    and all of them where the chip holds every expert.  (Further sizes
-    between the two cost a set-up that grows with every compiled branch:
-    PERF.md 6, PR 34.)"""
-    rows = -(-2 * assignments * held // published)
+    (``assignments * held / published``) and no fewer than one a token, up
+    to a multiple of ``ROW_TILE``, at most all ``assignments``.  Derived
+    from the layer's shape, no setting: 8,192 of 32,768 for 8 of 64 experts
+    held over 8,192 tokens x 4, 8,192 of 81,920 for 8 of 256 over 8,192 x 10
+    (twice the balanced share is 5,120 there), and all of them where the
+    chip holds every expert.  The row a token is there because a buffer
+    shorter than the layer's other passes over its tokens saves little (the
+    routed part of a layer at 3,072 x 1,024 takes 8.0 ms at 2,560 held
+    assignments and 9.1 at 5,000, forward and backward) and because what
+    lies past the bound costs a block's whole time (14.6 ms at 5,200):
+    PERF.md 6, PR 36.  (Further sizes between the two cost a set-up that
+    grows with every compiled branch: PERF.md 6, PR 34.)"""
+    rows = max(-(-2 * assignments * held // published), tokens)
     return min(-(-rows // ROW_TILE) * ROW_TILE, assignments)
 
 
 def _ffn_over_rows(rows: int, x, order, weights, sizes, w_gate, w_up,
-                   w_down):
-    """``held_experts_ffn``'s result from the first ``rows`` rows of the
-    sorted order: every held assignment, if they number at most ``rows``.
-    The rows past the groups are masked on the way in and on the way out,
-    so that what the grouped products leave there reaches neither the
-    result nor, in the backward pass, the tokens' gradient."""
+                   w_down, first_row=0):
+    """``held_experts_ffn``'s result from the ``rows`` rows of the sorted
+    order from ``first_row`` on: all of it if they hold every held
+    assignment, else that block's share (``_ffn_in_blocks`` sums the
+    blocks).  Each held expert's group is clipped to the block, and the rows
+    past the groups are masked on the way in and on the way out, so that
+    what the grouped products leave there reaches neither the result nor, in
+    the backward pass, the tokens' gradient."""
     k = weights.shape[1]
-    order = order[:rows]
-    valid = jnp.arange(rows) < jnp.sum(sizes)
+    order = lax.dynamic_slice(order, (first_row,), (rows,))
+    valid = first_row + jnp.arange(rows) < jnp.sum(sizes)
+    ends = jnp.cumsum(sizes)
+    inside = lambda at: jnp.clip(at, first_row, first_row + rows)  # noqa: E731
+    sizes = inside(ends) - inside(ends - sizes)
     xs = jnp.where(valid[:, None], x[order // k], jnp.zeros((), x.dtype))
     ys = grouped_swiglu(xs, w_gate, w_up, w_down, sizes)
     return combine_sorted(ys, order, weights, valid)
 
 
+def _blocks(rows: int, order, sizes):
+    """The sorted order padded to whole blocks of ``rows`` rows, and the
+    number of blocks that hold a held assignment."""
+    return (jnp.pad(order, (0, -order.shape[0] % rows)),
+            lax.div(jnp.sum(sizes) + (rows - 1), rows))
+
+
+def _ffn_in_blocks(rows: int, x, order, weights, sizes, *w):
+    """``held_experts_ffn``'s result at any imbalance in the memory of one
+    block: the sorted rows ``rows`` at a time, as many blocks as hold a held
+    assignment (all ``T * k`` rows at the worst), summed.  Nothing is
+    dropped, and no array grows with ``T * k`` but the sorted order."""
+    order, blocks = _blocks(rows, order, sizes)
+    return lax.fori_loop(
+        0, blocks,
+        lambda i, y: y + _ffn_over_rows(rows, x, order, weights, sizes, *w,
+                                        first_row=i * rows),
+        jnp.zeros(x.shape, jnp.float32))
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
 def _ffn_over_fitting_rows(rows: int, x, order, weights, sizes, *w):
-    """``_ffn_over_rows`` over ``rows`` rows where the held assignments fit
-    them and over all of them where they do not, and the same choice again
-    in the backward pass.  Its own derivative rule because autodiff through
-    ``lax.cond`` hands every branch's residuals from the forward conditional
-    to the backward one, the untaken branch's zero-filled (+2.08 GB of the
-    step's temporaries at the published widths): here each pass's
-    conditional keeps what it computes to itself."""
+    """``_ffn_over_rows`` over the first ``rows`` rows where the held
+    assignments fit them and ``_ffn_in_blocks`` where they do not, and the
+    same choice again in the backward pass.  Its own derivative rule because
+    autodiff through ``lax.cond`` hands every branch's residuals from the
+    forward conditional to the backward one, the untaken branch's
+    zero-filled (+2.08 GB of the step's temporaries at GLM's widths), and
+    because a loop whose length the routing decides has no transpose: here
+    each pass's conditional keeps what it computes to itself, and the
+    backward pass's adds the blocks' pullbacks in a loop of the same
+    length.  (Two other forms were measured on the chip, PERF.md 6, PR 36.
+    The loop alone, the first block in line and no further block while they
+    fit, is one path and no conditional: 0.65% slower in the GLM cell, whose
+    every layer then carries its gradients through a loop that does not
+    turn.  Blocks of a quarter of the bound cost more at every load: a
+    block's time is mostly its fixed part, the experts' matrices read and
+    their gradients written and summed once a block.)"""
     return lax.cond(jnp.sum(sizes) <= rows,
                     functools.partial(_ffn_over_rows, rows),
-                    functools.partial(_ffn_over_rows, order.shape[0]),
+                    functools.partial(_ffn_in_blocks, rows),
                     x, order, weights, sizes, *w)
 
 
@@ -236,14 +286,21 @@ def _fitting_fwd(rows, *operands):
 def _fitting_bwd(rows, operands, dy):
     x, order, weights, sizes, *w = operands
 
-    def pull(n):
-        def back(dy, x, weights, *w):
-            return jax.vjp(lambda x, weights, *w: _ffn_over_rows(
-                n, x, order, weights, sizes, *w), x, weights, *w)[1](dy)
-        return back
+    def pull(order, first_row):
+        return jax.vjp(lambda x, weights, *w: _ffn_over_rows(
+            rows, x, order, weights, sizes, *w, first_row=first_row),
+            x, weights, *w)[1](dy)
 
-    dx, dweights, *dw = lax.cond(jnp.sum(sizes) <= rows, pull(rows),
-                                 pull(order.shape[0]), dy, x, weights, *w)
+    def in_blocks():
+        padded, blocks = _blocks(rows, order, sizes)
+        return lax.fori_loop(
+            0, blocks,
+            lambda i, sums: jax.tree.map(jnp.add, sums,
+                                         pull(padded, i * rows)),
+            jax.tree.map(jnp.zeros_like, (x, weights, *w)))
+
+    dx, dweights, *dw = lax.cond(jnp.sum(sizes) <= rows,
+                                 lambda: pull(order, 0), in_blocks)
     return (dx, None, dweights, None, *dw)
 
 
@@ -254,18 +311,21 @@ def held_experts_ffn(x, idx, weights, w_gate, w_up, w_down, first: int,
                      published: int):
     """The held experts' part of a top-k MoE layer's result for tokens
     ``x`` (T, D), of ``published`` experts in all: (T, D) float32, the held
-    experts' loads, and whether the whole buffer was worked on (0 or 1).
+    experts' loads, and whether the layer went past its bounded buffer (0 or
+    1).
 
     The sorted order puts every held assignment first, so the gather, the
     masks, the products' buffers, the combine and their transposes run over
     the first ``bounded_rows`` rows whenever the held assignments fit them:
     the same rows in the same order as over all ``T * k``.  When they do
-    not fit, the layer works on the whole buffer: nothing is dropped at any
-    imbalance, it only costs what the worst case costs."""
+    not fit, the layer works through the sorted rows a block of
+    ``bounded_rows`` at a time (``_ffn_in_blocks``): nothing is dropped at
+    any imbalance, it costs a block's time for every block that holds an
+    assignment and one block's memory."""
     held = w_gate.shape[0]
     order, sizes = sort_by_held_expert(idx, first, held)
     total = order.shape[0]
-    rows = bounded_rows(total, held, published)
+    rows = bounded_rows(total, held, published, x.shape[0])
     operands = (x, order, weights, sizes, w_gate, w_up, w_down)
     if rows == total:
         return _ffn_over_rows(total, *operands), sizes, jnp.float32(0.0)

@@ -282,8 +282,8 @@ def test_nothing_is_dropped_when_every_token_picks_one_expert(favoured, seq):
     """A correction bias that sends every token to one expert (and its
     second choice wherever): the layer has no capacity to overflow.  At 512
     tokens the favoured held expert's 512 first choices and the other's
-    second choices pass the 512 rows of the bounded buffer: the layer works
-    on all 1,024 and says so."""
+    second choices pass the 512 rows of the bounded buffer: the layer goes on
+    through the second block of 512 and says so."""
     _, p = _whole_moe_params(jax.random.PRNGKey(3))
     p['router_bias'] = np.zeros(8, np.float32)
     p['router_bias'][favoured] = 10.0
@@ -306,7 +306,16 @@ def test_nothing_is_dropped_when_every_token_picks_one_expert(favoured, seq):
 MOE_LEAVES = ('norm', 'router', 'wgate', 'wup', 'wdown', 'sgate', 'sup',
               'sdown')
 FFN_OUTPUTS = ('result', 'tokens', 'weights', 'wgate', 'wup', 'wdown')
-HELD = (300, 512, 513, 1500)
+#: (tokens, experts a token, experts held, published), and the held
+#: assignments tried: under, at and just over the bound's 512 rows, far over
+#: it, and every assignment held (every token choosing held experts only).
+#: The first is the shape of GLM's routing (top-2 of 16 here), the second
+#: Laguna's (top-10 of 128 with 4 held: ten choices a token, a bound of
+#: 1/5 of the buffer)
+SHAPES = {'top2of16': ((512, 2, 2, 16), (300, 512, 513, 800, 1024)),
+          'top10of128': ((256, 10, 4, 128), (300, 512, 513, 1500, 2560))}
+ROUTINGS = [(shape, held) for shape, (_, helds) in SHAPES.items()
+            for held in helds]
 
 
 def _assert_equal_to_float32(got, want):
@@ -318,71 +327,83 @@ def _assert_equal_to_float32(got, want):
 
 
 def test_the_bound_comes_from_the_layers_shape():
-    """Twice the balanced share up to the products' row tile, never past the
-    buffer: the published cell's layer, the tiny twin's (one size, one path),
-    the 512-token twin of the tests below, a chip that holds every expert, a
-    share that is no multiple of the tile."""
+    """Twice the balanced share and no fewer than a row a token, up to the
+    products' row tile, never past the buffer: the published cells' layers
+    (GLM's twice-the-share is its token count; Laguna's is 5,120, under
+    it), the tiny twin's (one size, one path), the 512-token twin of the
+    tests below, a chip that holds every expert, a share that is no multiple
+    of the tile."""
+    assert moe_ops.bounded_rows(8192 * 4, 8, 64, 8192) == 8192
     assert moe_ops.bounded_rows(8192 * 4, 8, 64) == 8192
-    assert moe_ops.bounded_rows(64 * 2, 2, 8) == 64 * 2
-    assert moe_ops.bounded_rows(512 * 2, 2, 8) == 512
-    assert moe_ops.bounded_rows(8192 * 4, 64, 64) == 8192 * 4
-    assert moe_ops.bounded_rows(1000 * 4, 8, 64) == 1024     # 1,000 rounded up
+    assert moe_ops.bounded_rows(8192 * 10, 8, 256, 8192) == 8192
+    assert moe_ops.bounded_rows(8192 * 10, 8, 256) == 5120
+    assert moe_ops.bounded_rows(64 * 2, 2, 8, 64) == 64 * 2
+    assert moe_ops.bounded_rows(512 * 2, 2, 8, 512) == 512
+    assert moe_ops.bounded_rows(8192 * 4, 64, 64, 8192) == 8192 * 4
+    assert moe_ops.bounded_rows(1000 * 4, 8, 64, 1000) == 1024   # rounded up
     assert 1024 % moe_ops.ROW_TILE == 0
 
 
 @pytest.fixture(scope='module')
 def hand_routed():
-    """``held_experts_ffn`` on hand-made routings of 1,024 tokens x 2 over
-    16 experts, 2 held: a bound of 512 of 2,048 rows, with 300, 512, 513 and
-    1,500 assignments held, against the one path over all 2,048 rows (what
-    the layer was before it had a bound): result, loads, and the gradients
-    of the tokens, the routing weights and the three matrices."""
-    t, k, d, f = 1024, 2, 64, 48
-    assert moe_ops.bounded_rows(t * k, 2, 16) == 512
-    keys = jax.random.split(jax.random.PRNGKey(11), 6)
-    x = jax.random.normal(keys[0], (t, d))
-    weights = jax.random.uniform(keys[1], (t, k), minval=0.2, maxval=1.0)
-    ws = [0.2 * jax.random.normal(key, shape) for key, shape in zip(
-        keys[2:5], [(2, d, f), (2, d, f), (2, f, d)])]
-    g = jax.random.normal(keys[5], (t, d))
-
-    def routing(held):
-        # the first ``held`` assignments (row-major) go to the two held
-        # experts in turn, unevenly, the others to experts held elsewhere
-        flat = 2 + np.arange(t * k) % 14
-        flat[:held] = (np.arange(held) % 3 == 0)
-        return jnp.asarray(flat.reshape(t, k), jnp.int32)
-
-    def run(fn):
-        def loss(x, weights, *ws):
-            y, sizes, full = fn(x, weights, *ws)
-            return jnp.sum(y * g), (y, sizes, full)
-        return jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4),
-                                          has_aux=True))
-
+    """``held_experts_ffn`` on hand-made routings of each of ``SHAPES``: a
+    bound of 512 rows of the 1,024 and 2,560 sorted ones, with as many
+    assignments held as ``SHAPES`` lists, against the one path over all the
+    rows (what the layer was before it had a bound): result, loads, and the
+    gradients of the tokens, the routing weights and the three matrices.
+    Past the bound the layer works through blocks of 512 rows: two at 513
+    and 800, three at 1,500, all two or five when every assignment is
+    held."""
+    d, f = 64, 48
     found = {}
-    for held in HELD:
-        idx = routing(held)
-        got = run(lambda x, w, *ws: moe_ops.held_experts_ffn(
-            x, idx, w, *ws, 0, 16))(x, weights, *ws)
+    for shape, ((t, k, held_experts, published), helds) in SHAPES.items():
+        assert moe_ops.bounded_rows(t * k, held_experts, published, t) == 512
+        keys = jax.random.split(jax.random.PRNGKey(11), 6)
+        x = jax.random.normal(keys[0], (t, d))
+        weights = jax.random.uniform(keys[1], (t, k), minval=0.2, maxval=1.0)
+        ws = [0.2 * jax.random.normal(key, (held_experts,) + dims)
+              for key, dims in zip(keys[2:5], [(d, f), (d, f), (f, d)])]
+        g = jax.random.normal(keys[5], (t, d))
 
-        def one_path(x, w, *ws):
-            order, sizes = moe_ops.sort_by_held_expert(idx, 0, 2)
-            return moe_ops._ffn_over_rows(t * k, x, order, w, sizes,
-                                          *ws), sizes, 0.0
-        found[held] = jax.device_get((got, run(one_path)(x, weights, *ws)))
+        def routing(held):
+            # the first ``held`` assignments (row-major) go to the held
+            # experts in turn, unevenly, the others to experts held elsewhere
+            flat = held_experts + np.arange(t * k) % (published - held_experts)
+            flat[:held] = (np.arange(held) % 3) % held_experts
+            return jnp.asarray(flat.reshape(t, k), jnp.int32)
+
+        def run(fn):
+            def loss(x, weights, *ws):
+                y, sizes, full = fn(x, weights, *ws)
+                return jnp.sum(y * g), (y, sizes, full)
+            return jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4),
+                                              has_aux=True))
+
+        for held in helds:
+            idx = routing(held)
+            got = run(lambda x, w, *ws: moe_ops.held_experts_ffn(
+                x, idx, w, *ws, 0, published))(x, weights, *ws)
+
+            def one_path(x, w, *ws):
+                order, sizes = moe_ops.sort_by_held_expert(idx, 0,
+                                                           held_experts)
+                return moe_ops._ffn_over_rows(t * k, x, order, w, sizes,
+                                              *ws), sizes, 0.0
+            found[shape, held] = jax.device_get(
+                (got, run(one_path)(x, weights, *ws)))
     return found
 
 
 @pytest.mark.parametrize('what', FFN_OUTPUTS)
-@pytest.mark.parametrize('held', HELD)
+@pytest.mark.parametrize('shape,held', ROUTINGS)
 def test_either_buffer_gives_the_whole_ones_result_and_gradients(
-        hand_routed, held, what):
+        hand_routed, shape, held, what):
     """Up to the bound's 512 held assignments the layer works on 512 rows,
-    from 513 on all 2,048: either way what the one path over all of them
-    gives, and the counter says which."""
+    from 513 on through blocks of 512 until none holds an assignment:
+    either way what the one path over all of them gives, and the counter
+    says which."""
     ((_, (y, sizes, full)), grads), ((_, (want_y, want_sizes, _)),
-                                     want_grads) = hand_routed[held]
+                                     want_grads) = hand_routed[shape, held]
     assert sizes.sum() == held and (sizes == want_sizes).all()
     assert float(full) == float(held > 512)
     at = FFN_OUTPUTS.index(what) - 1
@@ -426,9 +447,10 @@ def both_buffers():
 
 @pytest.mark.parametrize('routing', ['fits', 'passes'])
 def test_the_bounded_buffer_gives_the_full_ones_output(both_buffers, routing):
-    """The same rows in the same order: the output is the whole buffer's
-    and the routing counters are equal; only ``moe.full_buffer_share`` says
-    which buffer was worked on."""
+    """The same rows in the same order, a block at a time: the output is
+    what one pass over the whole buffer gives and the routing counters are
+    equal; only ``moe.full_buffer_share`` says whether the layer went past
+    its first block."""
     ((_, (out, stats)), _), ((_, (want, full)), _) = both_buffers[routing]
     _assert_equal_to_float32(out, want)
     for name in ('moe.local_assignment_share', 'moe.load_max_over_mean'):

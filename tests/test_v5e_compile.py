@@ -278,38 +278,63 @@ def _computations(hlo):
     return found
 
 
+def _expert_layer_branches(hlo, pas, layers):
+    """Of each expert layer's conditional of one pass, as instruction lines:
+    (the body of the blocks' loop, the bounded branch, all the lines the
+    branch that holds the loop reaches)."""
+    comps = _computations(hlo)
+    scope = {'fwd': r'/jvp\(l\d+_moe_\w+\)/',
+             'bwd': r'/transpose\(jvp\(l\d+_moe_\w+\)\)/'}[pas]
+    conds = [l for l in hlo.splitlines() if ' conditional(' in l
+             and re.search(r'op_name="jit\(train_step\)' + scope, l)]
+    assert len(conds) == layers, conds
+    for line in conds:
+        names = re.search(r'branch_computations=\{([^}]*)\}', line).group(1)
+        blocks, bounded = re.findall(r'%([\w.\-]+)', names)  # false, true
+        loops = [m for l in comps[blocks]
+                 for m in re.findall(r' while\(.*body=%([\w.\-]+)', l)]
+        assert len(loops) == 1, loops          # the blocks, and nothing else
+        yield comps[loops[0]], comps[bounded], comps[blocks] + comps[loops[0]]
+
+
+def _grouped_products(lines):
+    return [l for l in lines if 'tpu_custom_call' in l
+            and l.lstrip().startswith('%ragged-dot-none')]
+
+
+def _a_bounded_branch_and_the_blocks(hlo, pas, products, layers, rows,
+                                     tile):
+    """Both branches hold the same ``products`` grouped products over
+    ``rows`` rows, the second inside its loop; every one on the row tile."""
+    for body, bounded, reached in _expert_layer_branches(hlo, pas, layers):
+        for lines in (body, bounded):
+            calls = _grouped_products(lines)
+            assert len(calls) == products, len(calls)
+            assert all(f'ragged_dot_tiling="{tile},' in l for l in calls)
+            by_rows = [l for l in calls if re.search(rf'= f32\[{rows},', l)]
+            assert len(by_rows) == products - (3 if pas == 'bwd' else 0)
+        assert not _grouped_products(
+            [l for l in reached if l not in body])     # none outside the loop
+
+
 @pytest.mark.parametrize('pas,products', [('fwd', 3), ('bwd', 9)])
 def test_an_expert_layer_compiles_a_bounded_and_a_whole_branch(glm_step, pas,
                                                                products):
     """Each of the five expert layers holds one conditional a pass (the
     recomputation's forward one has no reader and is gone): the same
-    grouped products in both branches, over ``bounded_rows`` rows in the one
-    the benchmark's cell runs and over all 32,768 in the one that drops
-    nothing at any imbalance.  A step runs 12 of the 24 a layer, so a count
-    of executed products is 60 as before.  Every one on the row tile
+    grouped products in both branches, over the first ``bounded_rows`` rows
+    in the one the benchmark's cell runs, and in the one that drops nothing
+    at any imbalance inside a loop over blocks of as many rows (PR 36:
+    before, over all 32,768 at once; now no float array of 32,768 rows is
+    anywhere in the step).  A step runs 12 of the 24 a layer, so a count of
+    executed products is 60 as before.  Every one on the row tile
     ``bounded_rows`` rounds to."""
     from cxxnet_tpu.parallel import moe
     hlo = glm_step[0].as_text()
-    comps = _computations(hlo)
-    sizes = (32768, moe.bounded_rows(32768, 8, 64))    # false, true branch
-    assert sizes[1] == 8192
-    scope = {'fwd': r'/jvp\(l\d+_moe_\w+\)/',
-             'bwd': r'/transpose\(jvp\(l\d+_moe_\w+\)\)/'}[pas]
-    conds = [l for l in hlo.splitlines() if ' conditional(' in l
-             and re.search(r'op_name="jit\(train_step\)' + scope, l)]
-    assert len(conds) == 5, conds
-    for line in conds:
-        names = re.search(r'branch_computations=\{([^}]*)\}', line).group(1)
-        names = re.findall(r'%([\w.\-]+)', names)
-        assert len(names) == len(sizes)
-        for name, rows in zip(names, sizes):
-            calls = [l for l in comps[name] if 'tpu_custom_call' in l
-                     and l.lstrip().startswith('%ragged-dot-none')]
-            assert len(calls) == products, (name, len(calls))
-            assert all(f'ragged_dot_tiling="{moe.ROW_TILE},' in l
-                       for l in calls)
-            by_rows = [l for l in calls if re.search(rf'= f32\[{rows},', l)]
-            assert len(by_rows) == products - (3 if pas == 'bwd' else 0)
+    assert moe.bounded_rows(32768, 8, 64, 8192) == 8192
+    _a_bounded_branch_and_the_blocks(hlo, pas, products, 5, 8192,
+                                     moe.ROW_TILE)
+    assert not re.findall(r'(?:f32|bf16)\[32768,', hlo)
 
 
 def test_no_kernel_of_the_step_lost_its_name(glm_step):
@@ -429,3 +454,133 @@ def test_the_cnn_programs_hold_no_custom_call_on_the_v5e(one_chip, conf,
     # XLA's own custom calls (a gather's packed indices) are not Mosaic's
     assert 'tpu_custom_call' not in hlo
     assert 'convolution(' in hlo
+
+
+# --- Laguna-S-2.1's step: grouped, windowed attention and the blocks (PR 36) --
+
+@pytest.fixture(scope='module')
+def laguna_step(one_chip):
+    """``example/LM/Laguna-S-2.1.ep32.conf``'s training step, compiled for
+    one v5e chip from shapes alone (about a minute)."""
+    from cxxnet_tpu.nnet.trainer import NetTrainer
+    from cxxnet_tpu.ops import attention
+    tr = NetTrainer(_conf_without_iterators('LM', 'Laguna-S-2.1.ep32.conf')
+                    + [('dev', 'cpu')])
+    tr.init_net()
+    params, opt, arg = _described(tr, one_chip)
+    seq = 8192
+    with pytest.MonkeyPatch.context() as patch:
+        # ops/attention asks jax.default_backend(), which is the CPU here:
+        # the test steers it, the program has no option for it
+        patch.setattr(attention, '_use_splash',
+                      lambda q, k, v, spmd: spmd == 1)
+        compiled = tr._train_step_fn._jit.lower(
+            params, opt, None,
+            arg((1, 1, 1, seq + 1), jnp.int32),
+            arg((1, seq), jnp.float32), (), arg((1,), jnp.float32),
+            arg((2,), jnp.uint32), 0, 0, do_update=True).compile()
+    return compiled, params
+
+
+def test_the_laguna_step_fits_the_chip(laguna_step):
+    """811.0 M parameters at 12 bytes each resident and the step's
+    temporaries (the float32 gradients, one layer's activations, the
+    kernels' buffers) under the chip's 15.75 GiB with room for the forward
+    program: the compiler's plan, printed.  Read 11.98e9 bytes (11.15 GiB)
+    at PR 36; with the expert layer's overflow branch over all 81,920 rows
+    the plan was some 5e9 more and fitted no chip."""
+    import numpy as np
+    compiled, params = laguna_step
+    m = compiled.memory_analysis()
+    state = sum(int(np.prod(a.shape)) for a in jax.tree.leaves(params)) * 12
+    assert state == 811_018_240 * 12
+    live = (m.argument_size_in_bytes + m.output_size_in_bytes
+            - m.alias_size_in_bytes + m.temp_size_in_bytes)
+    print(f'the Laguna step\'s plan: {live} bytes, {live / 2 ** 30:.3f} GiB '
+          f'(state {state / 2 ** 30:.3f}, temporaries '
+          f'{m.temp_size_in_bytes / 2 ** 30:.3f})')
+    assert m.alias_size_in_bytes >= state          # the state is donated
+    assert live < 13.0e9 < 15.75 * 2 ** 30, live / 2 ** 30
+
+
+def _kernels_by_scope(hlo):
+    """Mosaic call -> (its conf layer's scope, its line), from the text as
+    ``step_program_text`` hands it out (an instruction a line)."""
+    from cxxnet_tpu.obs.programs import an_instruction_a_line
+    from cxxnet_tpu.utils import profiler
+    hlo = an_instruction_a_line(hlo)
+    scopes = profiler.hlo_op_names(hlo)
+    found = {}
+    for line in hlo.splitlines():
+        m = profiler._HLO_INSTRUCTION.match(line)
+        if m and 'tpu_custom_call' in line:
+            found[m.group(1)] = (profiler.scope_of(scopes[m.group(1)])[0],
+                                 line)
+    return found
+
+
+@pytest.mark.parametrize('event,calls', [('splash_mqa_fwd', 10),
+                                         ('splash_mqa_dq', 5),
+                                         ('splash_mqa_dkv', 5)])
+def test_the_gqa_kernels_keep_their_names(laguna_step, event, calls):
+    """``kernels.gqa_*_roofline_pct`` join a trace's device events to the
+    block-sparse kernels by the start of the custom call's name
+    (``benchmark/attention_costs.KERNELS``): every Mosaic call under a
+    ``gqa`` layer's scope is named so, five layers' forward kernel twice
+    (the recomputation) and dq and dkv once."""
+    kernels = _kernels_by_scope(laguna_step[0].as_text())
+    names = ('splash_mqa_fwd', 'splash_mqa_dq', 'splash_mqa_dkv')
+    in_gqa = [n for n, (scope, _) in kernels.items() if '_gqa' in scope]
+    assert in_gqa and all(n.startswith(names) for n in in_gqa), in_gqa
+    assert len([n for n in in_gqa if n.startswith(event)]) == calls
+    others = {n for n in kernels if n not in in_gqa}
+    assert all(n.startswith('ragged-dot') for n in others), others
+
+
+def test_a_window_layers_kernels_get_a_block_sparse_grid(laguna_step):
+    """The mask is laid over the grid of blocks when the step is traced: a
+    kernel's first operand is the table of key blocks to fetch, ``s8[1,
+    query blocks, key blocks a query block]``.  A window layer's has as many
+    key blocks a query block as reach over window + block - 1 keys (2 of 16
+    at 512: the other 14 are neither fetched nor computed), a full layer's
+    all of them; in the forward, the dq and, transposed, the dkv kernel."""
+    from cxxnet_tpu.ops import attention
+    kernels = _kernels_by_scope(laguna_step[0].as_text())
+    seen = {}
+    for name, (scope, line) in kernels.items():
+        if '_gqa' not in scope:
+            continue
+        table = re.search(r'operand_layout_constraints=\{s8\[1,(\d+),(\d+)\]',
+                          line)
+        assert table, line[:300]
+        windowed = scope in ('l04_gqa_attn1', 'l06_gqa_attn2',
+                             'l08_gqa_attn3')
+        kind = name.split('_')[2].split('.')[0]            # fwd, dq, dkv
+        seen.setdefault((windowed, kind), set()).add(
+            tuple(int(g) for g in table.groups()))
+    bq, bkv, _ = attention.SPLASH_BLOCKS_WINDOW
+    reach = -(-(512 + bq - 1) // bkv)                  # key blocks a q block
+    assert seen[True, 'fwd'] == seen[True, 'dq'] == {(8192 // bq, reach)}
+    assert reach * bkv <= 2 * 512 < 8192
+    # dkv walks the query blocks of a key block: as few
+    (dkv,) = seen[True, 'dkv']
+    assert dkv[0] * dkv[1] <= (8192 // bkv) * -(-(512 + bkv - 1) // bq)
+    fq, fkv, _ = attention.SPLASH_BLOCKS_FULL
+    assert seen[False, 'fwd'] == seen[False, 'dq'] == {(8192 // fq,
+                                                        8192 // fkv)}
+
+
+@pytest.mark.parametrize('pas,products', [('fwd', 3), ('bwd', 9)])
+def test_no_assignment_row_array_stands_in_an_expert_layers_branch(
+        laguna_step, pas, products):
+    """81,920 assignments of 3,072-wide rows: the bounded branch works on
+    ``bounded_rows`` = 8,192 rows and the branch that drops nothing on
+    blocks of as many in a loop, and nowhere in the step is there a float
+    array with a row an assignment (the sorted order and its masks are
+    integers)."""
+    from cxxnet_tpu.parallel import moe
+    hlo = laguna_step[0].as_text()
+    assert moe.bounded_rows(8192 * 10, 8, 256, 8192) == 8192
+    _a_bounded_branch_and_the_blocks(hlo, pas, products, 4, 8192,
+                                     moe.ROW_TILE)
+    assert not re.findall(r'(?:f32|bf16)\[81920,', hlo)
