@@ -28,6 +28,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental.xla_metadata import set_xla_metadata
 
 
 def switch_gate(x, gate_w, capacity: int):
@@ -116,7 +117,10 @@ def moe_ffn_reference(x, gate_w, w1, w2, capacity_factor: float = 2.0):
 # assignments are sorted by held expert into one (tokens * k)-row buffer
 # (those of experts held elsewhere go last) and the products run grouped over
 # it (``lax.ragged_dot``, which skips the rows past the groups: on the v5e
-# its time follows the sum of the group sizes, not the rows; PERF.md PR 29).
+# its time follows the row tiles the groups touch, not the rows; PERF.md PR
+# 29, 37).  The products' tile is chosen from each product's shape and handed
+# to the compiler (``grouped_tiling``, ``GROUP_ROW_TILE``: 256 rows for groups
+# of a few hundred); the buffer is rounded to another constant, ``ROW_TILE``.
 # Everything around the products costs by the row, so the layer works on the
 # first rows of the buffer alone where they hold every held assignment
 # (``bounded_rows``; PERF.md PR 34), and through as many blocks of that size
@@ -158,18 +162,76 @@ def sort_by_held_expert(idx, first: int, held: int):
     return order, sizes
 
 
+#: the row tile the v5e's compiler gives a grouped product that names none
+#: (``ragged_dot_tiling="512,512,512"``), and what ``bounded_rows`` rounds the
+#: bounded buffer to, so that every array of the step keeps its shape whatever
+#: tile the products run on
+ROW_TILE = 512
+
+#: rows of the tile a grouped product runs on where its groups are short, and
+#: the tile of the wider of its two widths (the narrower is taken whole, up to
+#: ``WHOLE_WIDTH``): ``grouped_tiling``, measured on the v5e (PERF.md 6, PR 37)
+GROUP_ROW_TILE = 256
+WIDTH_TILE = 512
+WHOLE_WIDTH = 1536
+
+
+def grouped_tiling(rows: int, groups: int, k: int, n: int):
+    """The tiling (rows, contraction ``k``, columns ``n``) of one grouped
+    product of ``rows`` rows over ``groups`` groups, from its shape alone, or
+    ``None``: the compiler's own (on the v5e 512, 512, 512 at these sizes).
+
+    The kernel visits one (row tile, group) pair at a time and pays a whole
+    tile for each, so groups of a few hundred rows that start anywhere cost
+    ``rows / tile + groups - 1`` visits: at 4,750 held rows over 8 groups, 16.3
+    tiles of 512 (8,350 rows of work) or 25.6 of 256 (6,550).  A visit re-reads
+    its group's matrix, which a tile of 256 rows about balances against the
+    MXU and one of 128 does not; and the fewer tiles a visit walks across the
+    widths, the fewer grid steps: the narrower width whole and the wider in
+    512s is the largest the weight-gradient product's float32 tile leaves room
+    for in VMEM (512 x 2,048 is refused).  A product's two transposes in the
+    backward pass ride its tiling.  Measured on the v5e, the twelve products
+    of a layer and step against the compiler's tiling on the same routing:
+    -21 to -23% at 2,048 x 1,536 (groups of 90 to 1,780 rows), -22 to -27% at
+    3,072 x 1,024 (200 to 900), -2% and -6% at 1,024 rows in every group
+    (PERF.md 6, PR 37).
+
+    ``None`` where the buffer gives a group more than two of the compiler's
+    row tiles (``rows == total`` on a chip that holds every expert: groups of
+    thousands of rows span many tiles and little of a visit is wasted; no
+    cell runs it, PERF.md 7 has what 4,096 rows a group read), where ``rows``
+    is no multiple of the tile (the compiler refuses such a tiling and picks
+    a smaller tile itself) and at widths these tiles do not divide."""
+    narrow, wide = min(k, n), max(k, n)
+    if (rows % GROUP_ROW_TILE or rows > 2 * ROW_TILE * groups
+            or narrow % 128 or narrow > WHOLE_WIDTH or wide % WIDTH_TILE):
+        return None
+    return ((GROUP_ROW_TILE, WIDTH_TILE, n) if n <= k
+            else (GROUP_ROW_TILE, k, WIDTH_TILE))
+
+
+def _grouped_dot(xs, w, sizes):
+    """``lax.ragged_dot`` in float32 out, on ``grouped_tiling``'s tiling:
+    the frontend attribute ``ragged_dot_tiling`` rides the product and, in a
+    backward pass, its two transposes to the compiler (the TPU's alone reads
+    it)."""
+    tiling = grouped_tiling(xs.shape[0], sizes.shape[0], *w.shape[1:])
+    named = {} if tiling is None else {
+        'ragged_dot_tiling': ','.join(map(str, tiling))}
+    with set_xla_metadata(**named):
+        return lax.ragged_dot(xs, w, sizes,
+                              preferred_element_type=jnp.float32)
+
+
 def grouped_swiglu(xs, w_gate, w_up, w_down, sizes):
     """SwiGLU of each held expert over its rows of ``xs`` (sorted by
     expert, ``sizes`` rows each): ``w_gate, w_up``: (E, D, F), ``w_down``:
     (E, F, D).  Rows past the groups come back unspecified."""
     dt = xs.dtype
-    g = lax.ragged_dot(xs, w_gate.astype(dt), sizes,
-                       preferred_element_type=jnp.float32)
-    u = lax.ragged_dot(xs, w_up.astype(dt), sizes,
-                       preferred_element_type=jnp.float32)
+    g = _grouped_dot(xs, w_gate.astype(dt), sizes)
+    u = _grouped_dot(xs, w_up.astype(dt), sizes)
     h = (jax.nn.silu(g) * u).astype(dt)
-    return lax.ragged_dot(h, w_down.astype(dt), sizes,
-                          preferred_element_type=jnp.float32).astype(dt)
+    return _grouped_dot(h, w_down.astype(dt), sizes).astype(dt)
 
 
 def combine_sorted(ys, order, weights, valid):
@@ -184,18 +246,14 @@ def combine_sorted(ys, order, weights, valid):
         contrib)
 
 
-#: the row tile of the grouped products the v5e's compiler makes of
-#: ``lax.ragged_dot`` (``ragged_dot_tiling="512,512,512"`` on the Mosaic
-#: calls of the compiled step, held by tests/test_v5e_compile.py)
-ROW_TILE = 512
-
-
 def bounded_rows(assignments: int, held: int, published: int,
                  tokens: int = 0) -> int:
     """Rows of the sorted buffer that the layer works on when the held
     assignments fit them: twice the share a balanced router would send here
     (``assignments * held / published``) and no fewer than one a token, up
-    to a multiple of ``ROW_TILE``, at most all ``assignments``.  Derived
+    to a multiple of ``ROW_TILE`` (the rounding alone: the tile the products
+    run on is ``grouped_tiling``'s, which divides it), at most all
+    ``assignments``.  Derived
     from the layer's shape, no setting: 8,192 of 32,768 for 8 of 64 experts
     held over 8,192 tokens x 4, 8,192 of 81,920 for 8 of 256 over 8,192 x 10
     (twice the balanced share is 5,120 there), and all of them where the

@@ -318,6 +318,16 @@ ROUTINGS = [(shape, held) for shape, (_, helds) in SHAPES.items()
             for held in helds]
 
 
+def _hand_routing(shape, held):
+    """(tokens, experts a token) indices of ``SHAPES[shape]``: the first
+    ``held`` assignments (row-major) go to the held experts in turn,
+    unevenly, the others to experts held elsewhere."""
+    (t, k, held_experts, published), _ = SHAPES[shape]
+    flat = held_experts + np.arange(t * k) % (published - held_experts)
+    flat[:held] = (np.arange(held) % 3) % held_experts
+    return jnp.asarray(flat.reshape(t, k), jnp.int32)
+
+
 def _assert_equal_to_float32(got, want):
     """Equal to float32's last digits: the CPU blocks a product by its rows,
     so two buffer sizes add the same terms in another order."""
@@ -327,8 +337,8 @@ def _assert_equal_to_float32(got, want):
 
 
 def test_the_bound_comes_from_the_layers_shape():
-    """Twice the balanced share and no fewer than a row a token, up to the
-    products' row tile, never past the buffer: the published cells' layers
+    """Twice the balanced share and no fewer than a row a token, up to a
+    multiple of ``ROW_TILE``, never past the buffer: the published cells' layers
     (GLM's twice-the-share is its token count; Laguna's is 5,120, under
     it), the tiny twin's (one size, one path), the 512-token twin of the
     tests below, a chip that holds every expert, a share that is no multiple
@@ -341,7 +351,7 @@ def test_the_bound_comes_from_the_layers_shape():
     assert moe_ops.bounded_rows(512 * 2, 2, 8, 512) == 512
     assert moe_ops.bounded_rows(8192 * 4, 64, 64, 8192) == 8192 * 4
     assert moe_ops.bounded_rows(1000 * 4, 8, 64, 1000) == 1024   # rounded up
-    assert 1024 % moe_ops.ROW_TILE == 0
+    assert 1024 % moe_ops.ROW_TILE == 0            # what the bound rounds to
 
 
 @pytest.fixture(scope='module')
@@ -365,13 +375,6 @@ def hand_routed():
               for key, dims in zip(keys[2:5], [(d, f), (d, f), (f, d)])]
         g = jax.random.normal(keys[5], (t, d))
 
-        def routing(held):
-            # the first ``held`` assignments (row-major) go to the held
-            # experts in turn, unevenly, the others to experts held elsewhere
-            flat = held_experts + np.arange(t * k) % (published - held_experts)
-            flat[:held] = (np.arange(held) % 3) % held_experts
-            return jnp.asarray(flat.reshape(t, k), jnp.int32)
-
         def run(fn):
             def loss(x, weights, *ws):
                 y, sizes, full = fn(x, weights, *ws)
@@ -380,7 +383,7 @@ def hand_routed():
                                               has_aux=True))
 
         for held in helds:
-            idx = routing(held)
+            idx = _hand_routing(shape, held)
             got = run(lambda x, w, *ws: moe_ops.held_experts_ffn(
                 x, idx, w, *ws, 0, published))(x, weights, *ws)
 
@@ -471,6 +474,218 @@ def test_the_bounded_buffer_gives_the_full_ones_gradient(both_buffers, leaf,
     (_, (dp, dh)), (_, (want_p, want_h)) = both_buffers[routing]
     _assert_equal_to_float32(*((dh, want_h) if leaf == 'tokens'
                                else (dp[leaf], want_p[leaf])))
+
+
+# --- the grouped products' tiling (PR 37) -------------------------------------
+
+@pytest.mark.parametrize('rows,groups,k,n,want', [
+    # the bounded buffer of the two LM cells, 8,192 rows over 8 held experts:
+    # gate and up, then down (its transposes ride each product's tiling)
+    pytest.param(8192, 8, 2048, 1536, (256, 512, 1536), id='glm-gate-up'),
+    pytest.param(8192, 8, 1536, 2048, (256, 1536, 512), id='glm-down'),
+    pytest.param(8192, 8, 3072, 1024, (256, 512, 1024), id='laguna-gate-up'),
+    pytest.param(8192, 8, 1024, 3072, (256, 1024, 512), id='laguna-down'),
+    pytest.param(8192, 8, 1024, 1024, (256, 512, 1024), id='square'),
+    pytest.param(2048, 2, 2048, 1536, (256, 512, 1536), id='two-tiles-a-group'),
+    # a chip that holds every expert: ``rows == total``, 4,096 rows a group
+    pytest.param(32768, 8, 2048, 1536, None, id='every-expert-held'),
+    pytest.param(4096, 1, 2048, 1536, None, id='one-long-group'),
+    # the tiny twins: fewer rows than a tile
+    pytest.param(128, 2, 64, 48, None, id='tiny-layer'),
+    pytest.param(8000, 8, 2048, 1536, None, id='rows-no-multiple-of-the-tile'),
+    pytest.param(8192, 8, 2000, 1500, None, id='widths-nothing-divides'),
+    pytest.param(8192, 8, 2304, 1536, None, id='wide-no-multiple-of-512'),
+    pytest.param(8192, 8, 4096, 2048, None, id='narrow-past-vmem'),
+])
+def test_the_products_tiling_comes_from_their_shape(rows, groups, k, n, want):
+    """256 rows, the narrower width whole and the wider in 512s where the
+    buffer gives a group at most two tiles of 512 rows; else, and wherever
+    the compiler would refuse the tiling (rows it does not divide) or it was
+    never measured (widths it does not divide), none: the compiler's own."""
+    assert moe_ops.grouped_tiling(rows, groups, k, n) == want
+    if want is not None:
+        assert rows % want[0] == 0 and rows <= 2 * moe_ops.ROW_TILE * groups
+
+
+def test_the_bound_and_the_tile_are_two_constants():
+    """``ROW_TILE`` is what ``bounded_rows`` rounds to (and the compiler's
+    own row tile), ``GROUP_ROW_TILE`` what the products run on: the bounded
+    buffer of both cells keeps its 8,192 rows, which both divide."""
+    assert (moe_ops.ROW_TILE, moe_ops.GROUP_ROW_TILE) == (512, 256)
+    for shape in ((8192 * 4, 8, 64, 8192), (8192 * 10, 8, 256, 8192)):
+        assert moe_ops.bounded_rows(*shape) % moe_ops.ROW_TILE == 0
+        assert moe_ops.bounded_rows(*shape) % moe_ops.GROUP_ROW_TILE == 0
+
+
+#: the tiling a test hands every product in place of the rule's (``None`` at
+#: every size a CPU test can afford), so that the context is in place
+HANDED = (256, 512, 1024)
+TILINGS = [pytest.param(None, id='the-rules-own'),
+           pytest.param(HANDED, id='a-tiling-handed')]
+
+
+@pytest.fixture
+def tiling(request, monkeypatch):
+    if request.param is not None:
+        monkeypatch.setattr(moe_ops, 'grouped_tiling',
+                            lambda *shape: request.param)
+    return request.param
+
+
+def _dense_expert(x, wg, wu, wd):
+    with jax.default_matmul_precision('highest'):
+        return (jax.nn.silu(x @ wg) * (x @ wu)) @ wd
+
+
+def _dense_swiglu(xs, wg, wu, wd, sizes):
+    """Every expert's SwiGLU over its rows by plain products, no grouped
+    one: the rows past the groups come back zero."""
+    ends = np.cumsum(sizes)
+    out = jnp.zeros(xs.shape, jnp.float32)
+    for e, (lo, hi) in enumerate(zip(ends - sizes, ends)):
+        out = out.at[lo:hi].set(_dense_expert(xs[lo:hi], wg[e], wu[e], wd[e]))
+    return out
+
+
+SWIGLU_OUTPUTS = ('result', 'rows', 'wgate', 'wup', 'wdown')
+
+
+@pytest.mark.parametrize('what', SWIGLU_OUTPUTS)
+@pytest.mark.parametrize('sizes', [(10, 20, 5, 7), (0, 64, 0, 0),
+                                   (16, 16, 16, 16)], ids=str)
+@pytest.mark.parametrize('tiling', TILINGS, indirect=True)
+def test_grouped_swiglu_equals_the_experts_one_by_one(tiling, sizes, what):
+    """Value and the four gradients of the three grouped products under
+    the tiling's context (which no CPU reads) against each expert's plain
+    products over its own rows: uneven groups, one group, full groups."""
+    d, f, rows = 16, 8, 64
+    keys = jax.random.split(jax.random.PRNGKey(21), 5)
+    xs = jax.random.normal(keys[0], (rows, d))
+    ws = [0.3 * jax.random.normal(key, (len(sizes),) + dims)
+          for key, dims in zip(keys[1:4], [(d, f), (d, f), (f, d)])]
+    g = jax.random.normal(keys[4], (rows, d))
+    held = sum(sizes)
+    sz = np.asarray(sizes, np.int32)
+
+    def run(fn):
+        def loss(xs, *ws):
+            y = fn(xs, *ws)[:held]
+            return jnp.sum(y * g[:held]), y
+        return jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3),
+                                          has_aux=True))(xs, *ws)
+
+    (_, y), grads = run(lambda xs, *ws: moe_ops.grouped_swiglu(
+        xs, *ws, jnp.asarray(sz)))
+    (_, want_y), want = run(lambda xs, *ws: _dense_swiglu(xs, *ws, sz))
+    at = SWIGLU_OUTPUTS.index(what) - 1
+    got, want = (y, want_y) if what == 'result' else (grads[at], want[at])
+    np.testing.assert_allclose(got, want, rtol=2e-5,
+                               atol=2e-5 * np.abs(want).max())
+
+
+def _dense_held_ffn(x, idx, weights, wg, wu, wd, first):
+    """``held_experts_ffn``'s result with no sort, buffer or grouped
+    product: every held expert over every token, times the weight of the
+    choices that picked it."""
+    y = jnp.zeros(x.shape, jnp.float32)
+    for e in range(wg.shape[0]):
+        picked = jnp.sum(jnp.where(idx == first + e, weights, 0.0), axis=1)
+        y = y + _dense_expert(x, wg[e], wu[e], wd[e]) * picked[:, None]
+    return y
+
+
+@pytest.fixture(scope='module')
+def against_dense():
+    """``held_experts_ffn`` on hand-made routings of ``SHAPES`` that stay in
+    the bounded branch (300 held assignments) and that take the blocks (800
+    and 1,500), with the rule's own tiling and with one handed to every
+    product, against ``_dense_held_ffn``: result and five gradients."""
+    d, f = 64, 48
+    found = {}
+    for shape, held in (('top2of16', 300), ('top2of16', 800),
+                        ('top10of128', 300), ('top10of128', 1500)):
+        (t, k, held_experts, published), _ = SHAPES[shape]
+        keys = jax.random.split(jax.random.PRNGKey(13), 6)
+        x = jax.random.normal(keys[0], (t, d))
+        weights = jax.random.uniform(keys[1], (t, k), minval=0.2, maxval=1.0)
+        ws = [0.2 * jax.random.normal(key, (held_experts,) + dims)
+              for key, dims in zip(keys[2:5], [(d, f), (d, f), (f, d)])]
+        g = jax.random.normal(keys[5], (t, d))
+        idx = _hand_routing(shape, held)
+
+        def run(fn):
+            def loss(x, weights, *ws):
+                y = fn(x, weights, *ws)
+                return jnp.sum(y * g), y
+            return jax.device_get(jax.jit(jax.value_and_grad(
+                loss, argnums=(0, 1, 2, 3, 4), has_aux=True))(x, weights,
+                                                              *ws))
+
+        def layer(x, w, *ws):
+            return moe_ops.held_experts_ffn(x, idx, w, *ws, 0, published)[0]
+
+        found[shape, held, None] = run(layer)
+        with pytest.MonkeyPatch.context() as patch:
+            patch.setattr(moe_ops, 'grouped_tiling', lambda *shape: HANDED)
+            # the backward rule is jitted: a trace of its own for the handed
+            # tiling, and the rule's own again afterwards
+            moe_ops._fitting_bwd.clear_cache()
+            found[shape, held, HANDED] = run(layer)
+        moe_ops._fitting_bwd.clear_cache()
+        found[shape, held, 'dense'] = run(lambda x, w, *ws: _dense_held_ffn(
+            x, idx, w, *ws, 0))
+    return found
+
+
+@pytest.mark.parametrize('what', FFN_OUTPUTS)
+@pytest.mark.parametrize('shape,held', [
+    ('top2of16', 300), ('top2of16', 800), ('top10of128', 300),
+    ('top10of128', 1500)])
+@pytest.mark.parametrize('handed', [None, HANDED],
+                         ids=['the-rules-own', 'a-tiling-handed'])
+def test_the_layer_equals_every_expert_over_every_token(against_dense, shape,
+                                                        held, handed, what):
+    """The bounded branch and the blocks, value and gradients, through the
+    layer's own derivative rule with the products under a tiling's context:
+    what plain per-expert products give."""
+    (_, y), grads = against_dense[shape, held, handed]
+    (_, want_y), want = against_dense[shape, held, 'dense']
+    at = FFN_OUTPUTS.index(what) - 1
+    got, want = (y, want_y) if what == 'result' else (grads[at], want[at])
+    np.testing.assert_allclose(got, want, rtol=2e-5,
+                               atol=2e-5 * np.abs(want).max())
+
+
+def test_a_tiling_rides_every_product_and_its_transposes(monkeypatch):
+    """The frontend attribute is on every product of the lowered layer,
+    forward and backward, in the bounded branch and in the blocks (off the
+    TPU a grouped product lowers to a masked ``dot_general``: 12 a branch),
+    and on none where the rule gives no tiling."""
+    (t, k, held_experts, published), _ = SHAPES['top2of16']
+    x = jnp.ones((t, 64))
+    ws = [jnp.ones((held_experts,) + dims)
+          for dims in [(64, 48), (64, 48), (48, 64)]]
+    idx = _hand_routing('top2of16', 300)
+
+    def lowered():
+        moe_ops._fitting_bwd.clear_cache()
+        return jax.jit(jax.value_and_grad(lambda x, w, *ws: jnp.sum(
+            moe_ops.held_experts_ffn(x, idx, w, *ws, 0, published)[0]),
+            argnums=(0, 1, 2, 3, 4))).lower(
+                x, jnp.ones((t, k)), *ws).as_text()
+
+    products = [l for l in lowered().splitlines() if 'dot_general' in l]
+    assert len(products) == 24 and not any(
+        'ragged_dot_tiling' in l for l in products)
+    monkeypatch.setattr(
+        moe_ops, 'grouped_tiling',
+        lambda rows, groups, k, n: (256, 512, n) if n <= k else (256, k, 512))
+    products = [l for l in lowered().splitlines() if 'dot_general' in l]
+    moe_ops._fitting_bwd.clear_cache()
+    carried = [l for l in products if 'ragged_dot_tiling = "256,' in l]
+    assert len(products) == len(carried) == 24
+    assert sum('"256,512,48"' in l for l in carried) == 16   # gate, up: d x f
+    assert sum('"256,48,512"' in l for l in carried) == 8    # down: f x d
 
 
 def test_mtp_head_shares_embedding_and_head_with_the_main_model(tiny):

@@ -144,9 +144,41 @@ def test_blocked_attention_compiles_at_the_published_heads(one_chip,
     assert '8192,8192' not in hlo
 
 
-def test_grouped_expert_products_compile_at_the_published_widths(one_chip):
-    """8 held experts of 2048 x 1536 over the worst case's 32,768 sorted
-    assignments: the three grouped products and their gradients."""
+def _grouped_products(lines):
+    return [l for l in lines if 'tpu_custom_call' in l
+            and l.lstrip().startswith('%ragged-dot-none')]
+
+
+def _tilings(calls):
+    """``ragged_dot_tiling`` of each grouped product's line, as written."""
+    return [re.search(r'ragged_dot_tiling="([\d,]+)"', l).group(1)
+            for l in calls]
+
+
+def _tilings_of_the_rule(rows, d, f):
+    """``grouped_tiling``'s tilings of gate and up (``d x f``) and of down
+    (``f x d``) over 8 held experts, as the compiled text writes them."""
+    from cxxnet_tpu.parallel import moe
+    up, down = (moe.grouped_tiling(rows, 8, d, f),
+                moe.grouped_tiling(rows, 8, f, d))
+    assert up == (256, 512, f) and down == (256, f, 512)
+    return [','.join(map(str, tiling)) for tiling in (up, down)]
+
+
+# the bounded buffer of both LM cells (8,192 rows, 8 held experts) at GLM's
+# and Laguna's widths, a narrower pair the same rule serves, and the worst
+# case's whole buffer at GLM's (4,096 rows a group: the compiler's own tiling)
+@pytest.mark.parametrize('rows,d,f', [(8192, 2048, 1536), (8192, 3072, 1024),
+                                      (8192, 1024, 512), (4096, 4096, 1408),
+                                      (32768, 2048, 1536)])
+def test_grouped_expert_products_compile_at_the_published_widths(one_chip,
+                                                                 rows, d, f):
+    """8 held experts' three grouped products and their gradients: each
+    forward product carries the tiling ``grouped_tiling`` gives its shape to
+    the compiler, and so do its two transposes (the gradient to the rows and
+    the gradient to the weights); where the rule gives none the compiler's
+    own 512 stands.  The loss leaves the down product's result unread, so 8
+    of the 9 are compiled."""
     from cxxnet_tpu.parallel import moe
 
     def loss(xs, wg, wu, wd, sizes):
@@ -156,10 +188,20 @@ def test_grouped_expert_products_compile_at_the_published_widths(one_chip):
     s = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
         shape, dt, sharding=one_chip)
     compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3))).lower(
-        s((32768, 2048), jnp.bfloat16), s((8, 2048, 1536), jnp.float32),
-        s((8, 2048, 1536), jnp.float32), s((8, 1536, 2048), jnp.float32),
+        s((rows, d), jnp.bfloat16), s((8, d, f), jnp.float32),
+        s((8, d, f), jnp.float32), s((8, f, d), jnp.float32),
         s((8,), jnp.int32)).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 2 << 30
+    if rows == 32768:
+        assert moe.grouped_tiling(rows, 8, d, f) is None
+        assert moe.grouped_tiling(rows, 8, f, d) is None
+        up = down = '512,512,512'
+    else:
+        up, down = _tilings_of_the_rule(rows, d, f)
+    found = sorted(_tilings(_grouped_products(
+        compiled.as_text().splitlines())))
+    # gate and up: the product and both transposes; down: the transposes
+    assert found == sorted([up] * 6 + [down] * 2)
 
 
 def _conf_without_iterators(*path):
@@ -297,20 +339,18 @@ def _expert_layer_branches(hlo, pas, layers):
         yield comps[loops[0]], comps[bounded], comps[blocks] + comps[loops[0]]
 
 
-def _grouped_products(lines):
-    return [l for l in lines if 'tpu_custom_call' in l
-            and l.lstrip().startswith('%ragged-dot-none')]
-
-
-def _a_bounded_branch_and_the_blocks(hlo, pas, products, layers, rows,
-                                     tile):
+def _a_bounded_branch_and_the_blocks(hlo, pas, products, layers, rows, d, f):
     """Both branches hold the same ``products`` grouped products over
-    ``rows`` rows, the second inside its loop; every one on the row tile."""
+    ``rows`` rows, the second inside its loop; every one on the tiling
+    ``grouped_tiling`` gives the forward product it is or transposes: gate
+    and up ``d x f``, down ``f x d``, two to one in either pass."""
+    up, down = _tilings_of_the_rule(rows, d, f)
+    want = sorted([up] * (2 * products // 3) + [down] * (products // 3))
     for body, bounded, reached in _expert_layer_branches(hlo, pas, layers):
         for lines in (body, bounded):
             calls = _grouped_products(lines)
             assert len(calls) == products, len(calls)
-            assert all(f'ragged_dot_tiling="{tile},' in l for l in calls)
+            assert sorted(_tilings(calls)) == want
             by_rows = [l for l in calls if re.search(rf'= f32\[{rows},', l)]
             assert len(by_rows) == products - (3 if pas == 'bwd' else 0)
         assert not _grouped_products(
@@ -327,13 +367,13 @@ def test_an_expert_layer_compiles_a_bounded_and_a_whole_branch(glm_step, pas,
     at any imbalance inside a loop over blocks of as many rows (PR 36:
     before, over all 32,768 at once; now no float array of 32,768 rows is
     anywhere in the step).  A step runs 12 of the 24 a layer, so a count of
-    executed products is 60 as before.  Every one on the row tile
-    ``bounded_rows`` rounds to."""
+    executed products is 60 as before.  Every one on the tiling the rule
+    gives its shape (PR 37), which is not the tile ``bounded_rows`` rounds
+    to."""
     from cxxnet_tpu.parallel import moe
     hlo = glm_step[0].as_text()
     assert moe.bounded_rows(32768, 8, 64, 8192) == 8192
-    _a_bounded_branch_and_the_blocks(hlo, pas, products, 5, 8192,
-                                     moe.ROW_TILE)
+    _a_bounded_branch_and_the_blocks(hlo, pas, products, 5, 8192, 2048, 1536)
     assert not re.findall(r'(?:f32|bf16)\[32768,', hlo)
 
 
@@ -581,6 +621,5 @@ def test_no_assignment_row_array_stands_in_an_expert_layers_branch(
     from cxxnet_tpu.parallel import moe
     hlo = laguna_step[0].as_text()
     assert moe.bounded_rows(8192 * 10, 8, 256, 8192) == 8192
-    _a_bounded_branch_and_the_blocks(hlo, pas, products, 4, 8192,
-                                     moe.ROW_TILE)
+    _a_bounded_branch_and_the_blocks(hlo, pas, products, 4, 8192, 3072, 1024)
     assert not re.findall(r'(?:f32|bf16)\[81920,', hlo)
