@@ -200,10 +200,8 @@ class WindowedStepper:
         self.demoted = False
 
     def _step_one(self, staged) -> None:
-        from ..obs import span
         self.before_dispatch(self.updates)
-        with span('train.dispatch', 'train', k=1, update=self.updates):
-            self.trainer.update_staged(staged)
+        self.trainer.update_staged(staged)
         self.updates += 1
 
     def feed(self, batch) -> int:
@@ -229,15 +227,13 @@ class WindowedStepper:
             if len(self.window) == self.k:
                 # no tracer hook inside a window: profile_dir demotes at
                 # resolve time (a trace window can't bracket steps inside
-                # one dispatch).  The span brackets the DISPATCH (host
-                # enqueue of one scanned window), never a step inside
-                # it — which is why it composes where profile_dir must
-                # demote (doc/observability.md)
-                from ..obs import span
-                with span('train.dispatch', 'train', k=self.k,
-                          update=self.updates):
-                    self.trainer.update_staged_window(self.scan_fn,
-                                                      self.window)
+                # one dispatch).  The trainer's train.dispatch span
+                # brackets the DISPATCH (host enqueue of one scanned
+                # window), never a step inside it — which is why it
+                # composes where profile_dir must demote
+                # (doc/observability.md)
+                self.trainer.update_staged_window(self.scan_fn,
+                                                  self.window)
                 self.updates += self.k
                 self.window = []
         return self.updates - u0

@@ -37,6 +37,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..layers import ForwardContext
 from ..obs import span
+from ..obs.step_record import StepSeries
 from ..parallel.mesh import (batch_sharding, build_mesh, param_shardings,
                              replicated_sharding)
 from ..updater import (apply_updates, create_updater_hyper, init_opt_state)
@@ -147,6 +148,9 @@ class NetTrainer:
         # what the newest steps counted beside their loss (device scalars,
         # nothing fetched in the step loop): read by step_stats()
         self._step_stats = collections.deque(maxlen=512)
+        # the newest dispatches' intervals and what each train.dispatch
+        # span carries (obs/step_record.py)
+        self._steps = StepSeries()
         self.compute_dtype = jnp.float32
         self.dev = ''              # the dev= value; '' = default device
         self.metric = MetricSet()
@@ -785,12 +789,13 @@ class NetTrainer:
         period = getattr(multi_fn, 'update_period',
                          max(1, self.update_period))
         self._sync_accumulator(period)
-        with span('train.launch', 'train', k=n_steps, update=sc0):
+        with span('train.launch', 'train', k=n_steps, update=sc0) as launch:
             (self.params, self.opt_state, self.grad_acc, losses, evals) = \
                 multi_fn(self.params, self.opt_state, self.grad_acc,
                          data_stack, label_stack, self._rng,
                          self.epoch_counter, sc0, mask_stack, self.round,
                          norm)
+        self._steps.launch_ns = launch.dur_ns
         if period == 1:
             self.epoch_counter += n_steps
         else:
@@ -886,22 +891,28 @@ class NetTrainer:
             raise ValueError(
                 'eval_train=1 with train metrics needs a multi_fn compiled '
                 'with train_eval=True, or the window\'s metrics are lost')
-        if getattr(multi_fn, 'train_eval', False):
-            infos = [_HostLabelInfo(s[4], self.net_cfg.label_name_map,
-                                    self.net_cfg.label_range)
-                     for s in staged_list]
-            ns = [s[5] - s[6] for s in staged_list]
-            train_eval = (infos, ns)
-        data_stack = self._device_stack([s[0] for s in staged_list])
-        label_stack = self._device_stack([s[1] for s in staged_list])
-        mask_stack = self._device_stack([s[3] for s in staged_list])
-        return self.update_n_on_device(
-            multi_fn, data_stack, label_stack, mask_stack=mask_stack,
-            norm=staged_list[0][7], train_eval=train_eval)
+        with span('train.dispatch', 'train', k=multi_fn.n_steps,
+                  update=self.sample_counter) as dispatch:
+            self._steps.begin(dispatch)
+            if getattr(multi_fn, 'train_eval', False):
+                infos = [_HostLabelInfo(s[4], self.net_cfg.label_name_map,
+                                        self.net_cfg.label_range)
+                         for s in staged_list]
+                ns = [s[5] - s[6] for s in staged_list]
+                train_eval = (infos, ns)
+            data_stack = self._device_stack([s[0] for s in staged_list])
+            label_stack = self._device_stack([s[1] for s in staged_list])
+            mask_stack = self._device_stack([s[3] for s in staged_list])
+            last = self.update_n_on_device(
+                multi_fn, data_stack, label_stack, mask_stack=mask_stack,
+                norm=staged_list[0][7], train_eval=train_eval)
+        self._steps.end(dispatch.dur_ns)
+        return last
 
     # --- training ---------------------------------------------------------
     def start_round(self, round_: int) -> None:
         self.round = round_
+        self._steps.reset()
         if self.test_on_server:
             bad = self.check_weight_consistency()
             if bad:
@@ -992,43 +1003,50 @@ class NetTrainer:
                 'it can predict/evaluate but not train')
         (data, label, extra, mask, host_label, bs, num_batch_padd,
          norm) = staged
-        self._sync_accumulator(self.update_period)
-        do_update = (self.sample_counter + 1) % self.update_period == 0
-        rng = jax.random.fold_in(self._rng, 1 + self.sample_counter * 131 +
-                                 self.round)
-        old_pending = self._pending_train_eval
-        self._pending_train_eval = None
-        if self._step_avals is None:
-            self._step_avals = (jax.tree.map(
-                lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
-                                               sharding=x.sharding),
-                (data, label, extra, mask, norm)), do_update)
-        with span('train.launch', 'train', k=1, update=self.sample_counter):
-            (self.params, self.opt_state, self.grad_acc, loss, evals,
-             stats) = self._train_step_fn(
-                 self.params, self.opt_state, self.grad_acc, data, label,
-                 extra, mask, rng, self.epoch_counter, self.round,
-                 do_update=do_update, norm=norm)
-        if stats:
-            self._step_stats.append(dict(stats, loss=loss))
-        self._observe_loss(loss)
-        for listener in self._loss_listeners:
-            listener(loss)
-        if host_label is not None:
-            # defer this step's metric readback one step: by the next
-            # update() (or evaluate()) the values are already on host, so
-            # no per-step device sync — the analogue of the reference's
-            # reuse of already-copied eval nodes (nnet_impl:174-180)
-            label_info = _HostLabelInfo(host_label,
-                                        self.net_cfg.label_name_map,
-                                        self.net_cfg.label_range)
-            self._pending_train_eval = (
-                loss, evals, label_info, bs - num_batch_padd)
-        if old_pending is not None:
-            self._drain_train_eval(old_pending)
-        if do_update:
-            self.epoch_counter += 1
-        self.sample_counter += 1
+        with span('train.dispatch', 'train', k=1,
+                  update=self.sample_counter) as dispatch:
+            self._steps.begin(dispatch)
+            self._sync_accumulator(self.update_period)
+            do_update = (self.sample_counter + 1) % self.update_period == 0
+            rng = jax.random.fold_in(
+                self._rng, 1 + self.sample_counter * 131 + self.round)
+            old_pending = self._pending_train_eval
+            self._pending_train_eval = None
+            if self._step_avals is None:
+                self._step_avals = (jax.tree.map(
+                    lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                                   sharding=x.sharding),
+                    (data, label, extra, mask, norm)), do_update)
+            with span('train.launch', 'train', k=1,
+                      update=self.sample_counter) as launch:
+                (self.params, self.opt_state, self.grad_acc, loss, evals,
+                 stats) = self._train_step_fn(
+                     self.params, self.opt_state, self.grad_acc, data, label,
+                     extra, mask, rng, self.epoch_counter, self.round,
+                     do_update=do_update, norm=norm)
+            self._steps.launch_ns = launch.dur_ns
+            if stats:
+                self._step_stats.append(dict(stats, loss=loss))
+            self._observe_loss(loss)
+            for listener in self._loss_listeners:
+                listener(loss)
+            if host_label is not None:
+                # defer this step's metric readback one step: by the next
+                # update() (or evaluate()) the values are already on host,
+                # so no per-step device sync — the analogue of the
+                # reference's reuse of already-copied eval nodes
+                # (nnet_impl:174-180)
+                label_info = _HostLabelInfo(host_label,
+                                            self.net_cfg.label_name_map,
+                                            self.net_cfg.label_range)
+                self._pending_train_eval = (
+                    loss, evals, label_info, bs - num_batch_padd)
+            if old_pending is not None:
+                self._drain_train_eval(old_pending)
+            if do_update:
+                self.epoch_counter += 1
+            self.sample_counter += 1
+        self._steps.end(dispatch.dur_ns)
 
     def step_program_text(self) -> str:
         """Compiled text of the per-step program as :meth:`update_staged`
@@ -1051,15 +1069,11 @@ class NetTrainer:
         that landed on held experts, the largest held expert's load over
         the mean) - as one ``{name: value, 'loss': value}`` a step, oldest
         first.  The step loop only keeps the device scalars; the fetch is
-        here, and each row goes to the hub as a ``train.step_stats``
-        event.  ``[]`` for a net whose layers count nothing."""
+        here.  ``[]`` for a net whose layers count nothing."""
         rows = [{k: float(v) for k, v in row.items()}
                 for row in jax.device_get(list(self._step_stats))]
         if clear:
             self._step_stats.clear()
-            from ..obs import record_event
-            for row in rows:
-                record_event('train.step_stats', 'train', **row)
         return rows
 
     def add_loss_listener(self, listener) -> None:
@@ -1276,6 +1290,7 @@ class NetTrainer:
         (and cleared) when ``eval_train`` is set; ``data_iter=None``
         returns just the train part."""
         ret = ''
+        self._steps.reset()
         self.flush_train_metrics()
         if self.eval_train and len(self.train_metric):
             ret += self.train_metric.print('train')
@@ -1431,6 +1446,7 @@ class NetTrainer:
         sidecar makes ``continue=1`` bit-exact mid-momentum.  Works for
         mesh-sharded state (shards save/restore in place)."""
         from . import sharded_ckpt
+        self._steps.reset()
         return sharded_ckpt.save_sharded(ckpt_dir, step,
                                          self._training_state(),
                                          block=block, retry=retry)
@@ -1446,6 +1462,7 @@ class NetTrainer:
         NaN-streak rule) must be resolved BEFORE taking the snapshot —
         once taken, the writer will commit it."""
         from ..runtime.async_ckpt import snapshot_tree
+        self._steps.reset()
         return snapshot_tree(self._training_state())
 
     def load_training_state(self, ckpt_dir: str,
@@ -1476,6 +1493,7 @@ class NetTrainer:
         period-1 trainer only if it is all zeros, and raises if gradients
         would be lost."""
         from . import sharded_ckpt
+        self._steps.reset()
 
         def like(saved_keys):
             tree = self._training_state()
@@ -1533,6 +1551,7 @@ class NetTrainer:
         fo.write(blob)
 
     def save_model(self, fo: BinaryIO) -> None:
+        self._steps.reset()
         self.write_model_bytes(
             fo, self.model_header(),
             checkpoint.params_to_blob(self.net, self.params))

@@ -46,8 +46,10 @@ batcher, decode engine, registries, freshness tracker and io chain.
 from __future__ import annotations
 
 import collections
+import gc
 import json
 import os
+import resource
 import threading
 import time
 from typing import Callable, Dict, List, Optional, Tuple
@@ -108,7 +110,7 @@ class _Span:
     trace live the annotation is a flag test."""
 
     __slots__ = ('_hub', 'name', 'subsystem', 'trace_id', 'attrs', '_t0',
-                 '_off', '_annotation')
+                 '_off', '_annotation', 'dur_ns')
 
     def __init__(self, hub: 'TelemetryHub', name: str, subsystem: str,
                  trace_id: Optional[str], attrs: dict):
@@ -120,6 +122,13 @@ class _Span:
         self._t0 = 0
         self._off = False
         self._annotation = None
+        self.dur_ns = 0                 # set at exit; 0 on a disabled hub
+
+    @property
+    def t_start_ns(self) -> int:
+        """When the span was entered (``time.monotonic_ns``); 0 on a
+        disabled hub, which records nothing."""
+        return self._t0
 
     def __enter__(self):
         h = self._hub
@@ -139,7 +148,7 @@ class _Span:
     def __exit__(self, et, ev, tb):
         if self._off:
             return False
-        dur = time.monotonic_ns() - self._t0
+        dur = self.dur_ns = time.monotonic_ns() - self._t0
         self._annotation.__exit__(et, ev, tb)
         h = self._hub
         stack = h._span_stack()
@@ -203,6 +212,11 @@ class TelemetryHub:
         # the GIL a rare lost increment costs a count, never a tear
         self._events_n = 0
         self._t0_ns = time.monotonic_ns()
+        # the collector's pauses while this hub was the process's
+        # (_on_gc): running totals a step record carries, bumped under
+        # the GIL by whichever thread the collection ran on
+        self.gc_ns = 0
+        self.gc_n = 0
         # flight-recorder dump state
         self._dump_dir: Optional[str] = None
         self._dump_keep = self.DEFAULT_KEEP
@@ -212,6 +226,7 @@ class TelemetryHub:
         # SLO engines (obs/slo.py) attached via attach_slo: what /slos
         # merges, /healthz degrades on, and a postmortem dump includes
         self._slo_engines: List[object] = []   # guarded-by: _lock
+        _watch_collector()
 
     # -- StatSet / status registries ---------------------------------------
     def register_stats(self, name: str, stats,
@@ -360,6 +375,19 @@ class TelemetryHub:
             'thread': self._tls.tname,
             'attrs': attrs})
         self._events_n += 1
+
+    def host_totals(self) -> dict:
+        """The running totals a step record carries (``train.dispatch``,
+        doc/observability.md), read on the calling thread: its CPU time
+        and the process's, the collector's pauses, the thread's
+        involuntary context switches and major page faults.  Totals, not
+        differences: a reader subtracts two records.  Some three clock
+        reads; nothing waits."""
+        ru = resource.getrusage(resource.RUSAGE_THREAD)
+        return {'thread_cpu_ns': time.thread_time_ns(),
+                'process_cpu_ns': time.process_time_ns(),
+                'gc_ns': self.gc_ns, 'gc_n': self.gc_n,
+                'nivcsw': ru.ru_nivcsw, 'majflt': ru.ru_majflt}
 
     def set_ring(self, n: int) -> None:
         """Resize the flight-recorder ring (affects the merged view
@@ -662,6 +690,74 @@ class TelemetryHub:
 
 _HUB: Optional[TelemetryHub] = None
 _HUB_LOCK = threading.Lock()
+
+
+# --- the collector ----------------------------------------------------------
+
+#: a collection at least this long is a ``host.gc`` event.  The young
+#: generation's come by the hundred a second at some tens of microseconds
+#: each: as events they would wrap the ring within a minute.
+GC_EVENT_NS = 1_000_000
+
+_gc_open: Optional[tuple] = None   # (hub, start_ns, annotation) of the
+#                                    collection that is running: the
+#                                    collector does not nest
+
+
+def _watch_collector() -> None:
+    """Put :func:`_on_gc` on ``gc.callbacks``, once a process (the first
+    hub's construction)."""
+    if _on_gc not in gc.callbacks:
+        gc.callbacks.append(_on_gc)
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    """``gc.callbacks`` entry: every pause goes into the process hub's
+    ``gc_ns`` / ``gc_n`` (looked up at call time, so ``install_hub``
+    redirects it; none yet or a disabled one: return at once), one of
+    :data:`GC_EVENT_NS` or more is a ``host.gc`` [generation, collected]
+    event besides.  A collection of an older generation is held open as a
+    ``cxxnet.host.gc`` annotation, which a profiler trace shows on the
+    device events' clock: a pause's length is not known at its start and
+    its generation is, and the young generation's pauses, tens of
+    microseconds by the hundred a second, would only fill a trace's host
+    plane.  Runs on the thread that tripped the collector, inside whatever
+    that thread was doing."""
+    global _gc_open
+    if phase == 'start':
+        hub = _HUB          # get_hub() would make one, inside an allocation
+        if hub is None or not hub.enabled:
+            _gc_open = None
+            return
+        note = None
+        if info.get('generation'):
+            from jax.profiler import TraceAnnotation
+            note = TraceAnnotation(_TRACE_PREFIX + 'host.gc')
+            note.__enter__()
+        _gc_open = (hub, time.monotonic_ns(), note)
+        return
+    opened, _gc_open = _gc_open, None
+    if opened is None:
+        return
+    hub, t0, note = opened
+    dur = time.monotonic_ns() - t0
+    if note is not None:
+        note.__exit__(None, None, None)
+    hub.gc_ns += dur
+    hub.gc_n += 1
+    if dur < GC_EVENT_NS:
+        return
+    # a thread's first record registers its ring under the hub's lock, and
+    # this thread may be the one that holds it (the collector runs inside
+    # any allocation): an event that would have to wait is left out, the
+    # totals have it
+    if getattr(hub._tls, 'gen', -1) != hub._gen:
+        if not hub._lock.acquire(blocking=False):
+            return
+        hub._lock.release()
+    hub._record('host.gc', 'host', None, t0, dur,
+                {'generation': info.get('generation'),
+                 'collected': info.get('collected')})
 
 
 def get_hub() -> TelemetryHub:

@@ -291,19 +291,22 @@ def test_trainer_spans_fire_once_a_call_with_their_attributes():
     tr.update_staged(staged)
     tr.update_staged(staged)
     launches = _events_since(t0, 'train.launch')
-    assert [e['attrs'] for e in launches] == [{'k': 1, 'update': 0},
-                                              {'k': 1, 'update': 1}]
+    # update_staged's own span, train.dispatch, holds them since PR 38: the
+    # hub stamps what it holds with its name
+    inside = {'parent': 'train.dispatch'}
+    assert [e['attrs'] for e in launches] == [
+        {'k': 1, 'update': 0, **inside}, {'k': 1, 'update': 1, **inside}]
     # eval_train = 1 with a metric: the second step drains the first's
     (fetch,) = _events_since(t0, 'train.eval_fetch')
     (score,) = _events_since(t0, 'train.eval_score')
-    assert fetch['attrs'] == score['attrs'] == {'rows': 8}
+    assert fetch['attrs'] == score['attrs'] == {'rows': 8, **inside}
     assert fetch['t_start_ns'] + fetch['dur_ns'] <= score['t_start_ns']
 
     t0 = _now()
     fn = tr.compile_multi_step(2, train_eval=True)
     tr.update_staged_window(fn, [tr.stage_batch(_batch()) for _ in range(2)])
     (ev,) = _events_since(t0, 'train.launch')
-    assert ev['attrs'] == {'k': 2, 'update': 2}
+    assert ev['attrs'] == {'k': 2, 'update': 2, **inside}
     tr.flush_train_metrics()
     (fetch,) = [e for e in _events_since(t0, 'train.eval_fetch')
                 if e['attrs'] == {'rows': 16}]

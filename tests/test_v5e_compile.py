@@ -559,6 +559,21 @@ def _kernels_by_scope(hlo):
     return found
 
 
+@pytest.mark.parametrize('step', ['glm_step', 'laguna_step'])
+def test_the_grouped_products_are_filed_under_their_moe_layer(step, request):
+    """XLA writes ``op_name="ragged-dot-none"`` over a grouped product's
+    path, so the call names no scope of its own; ``profiler.hlo_program``
+    files it where most of what it reads and of what reads it is filed (PR
+    38: the benchmark's rule since PR 33).  Every one of the compiled step's
+    lands under an expert layer, so ``profile_dir=`` prints the products
+    under ``l*_moe*`` and not under ``other``."""
+    kernels = _kernels_by_scope(request.getfixturevalue(step)[0].as_text())
+    products = {n: scope for n, (scope, _) in kernels.items()
+                if n.startswith('ragged-dot-none')}
+    assert len(products) >= 48, len(products)
+    assert all('_moe_' in scope for scope in products.values()), products
+
+
 @pytest.mark.parametrize('event,calls', [('splash_mqa_fwd', 10),
                                          ('splash_mqa_dq', 5),
                                          ('splash_mqa_dkv', 5)])
