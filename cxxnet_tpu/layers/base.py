@@ -66,6 +66,7 @@ kMTPJoin = 45
 kLMHeadLoss = 46
 kSeqSlice = 48
 kGQA = 49
+kKDA = 50
 kPairTestGap = 1024
 
 _NAME2TYPE = {
@@ -82,6 +83,7 @@ _NAME2TYPE = {
     'embedding': kEmbedding, 'rmsnorm': kRMSNorm, 'mla': kMLA,
     'swiglu': kSwiGLU, 'moe': kMoE, 'mtp_join': kMTPJoin,
     'lm_head_loss': kLMHeadLoss, 'seq_slice': kSeqSlice, 'gqa': kGQA,
+    'kda': kKDA,
 }
 _TYPE2NAME = {v: k for k, v in _NAME2TYPE.items()}
 _TYPE2NAME[kMaxPooling] = 'max_pooling'  # keep canonical names on collision

@@ -30,11 +30,12 @@ import math
 import jax
 import jax.numpy as jnp
 
+from ..ops import delta_rule
 from ..ops.attention import causal_attention
 from ..parallel import moe as moe_ops
-from .base import (Layer, NodeSpec, Params, kEmbedding, kGQA, kLMHeadLoss,
-                   kMLA, kMoE, kMTPJoin, kRMSNorm, kSeqSlice, kSwiGLU,
-                   register_layer)
+from .base import (Layer, NodeSpec, Params, kEmbedding, kGQA, kKDA,
+                   kLMHeadLoss, kMLA, kMoE, kMTPJoin, kRMSNorm, kSeqSlice,
+                   kSwiGLU, register_layer)
 from .loss import LossLayerBase
 
 
@@ -344,21 +345,55 @@ class LatentAttentionLayer(SequenceLayer):
         return [(h.astype(jnp.float32) + out).astype(dt)[:, None]]
 
 
+class HeadShare:
+    """A layer of heads that holds ``nhead`` of ``nhead_published`` from
+    ``head_first`` on (``nhead_published`` 0: all of them), as ``moe`` holds
+    its share of the experts: the chip's share of a layer whose heads are
+    split over chips.  It computes its heads' part of ``... W_o``; what the
+    other heads would add is left out, and that partial result goes on to
+    the next layer.  Nothing in a head's arithmetic depends on where it
+    sits, so the share is the layer with fewer heads, and the two keys are
+    metadata: checked, and the step is the same program without them
+    (doc/sequence.md)."""
+
+    nhead_published = head_first = 0
+
+    def _set_head_share(self, name, val) -> None:
+        if name in ('nhead_published', 'head_first'):
+            setattr(self, name, int(val))
+
+    def _head_share_error(self, group: int = 1) -> str:
+        """Why the share is no share of whole groups of ``group`` heads
+        (query heads a key/value head), or ``''``."""
+        published = self.nhead_published or self.nhead
+        if not (0 < self.nhead <= published
+                and 0 <= self.head_first <= published - self.nhead
+                and not (published % group or self.head_first % group)):
+            return (f'{self.nhead} heads from head {self.head_first} of '
+                    f'{published} in groups of {group}')
+        return ''
+
+
 @register_layer
-class GroupedAttentionLayer(SequenceLayer):
+class GroupedAttentionLayer(HeadShare, SequenceLayer):
     """Grouped-query attention with a causal window, rotary positions by the
-    layer's kind and a gate a head, with its pre-norm and its residual:
-    ``h + (g * Attn(RMSNorm(h))) W_o``, no biases.
+    layer's kind and a gate, with its pre-norm and its residual: ``h + (g *
+    Attn(RMSNorm(h))) W_o``, no biases.
 
     ``x = RMSNorm(h)``; ``q = x W_q`` as ``nhead`` heads of ``head_dim``,
     ``k = x W_k`` and ``v = x W_v`` as ``nkvhead`` heads: query head ``i``
     reads key/value head ``i // (nhead / nkvhead)``.  The first
     ``rotary_dims`` components of every ``q`` and ``k`` head are rotated
     (``rope_theta``; with ``rope_factor > 1`` the frequencies and the
-    ``rope_attention_factor`` of YaRN, :func:`yarn_frequencies`).  Scores
-    ``q . k / sqrt(head_dim)``; position ``i`` sees ``j <= i`` and, with
-    ``window > 0``, only ``i - j < window``.  ``g = sigmoid(x W_g)``, one
-    number a head and position, scales the head's output before ``W_o``.
+    ``rope_attention_factor`` of YaRN, :func:`yarn_frequencies`); with
+    ``use_rope = 0`` none is (NoPE).  Scores ``q . k / sqrt(head_dim)``;
+    position ``i`` sees ``j <= i`` and, with ``window > 0``, only ``i - j <
+    window``.  ``g = sigmoid(x W_g)`` scales the head's output before
+    ``W_o``: one number a head and position (``gate = head``, the default),
+    or one a channel of each head (``gate = elementwise``).
+
+    A layer may hold ``nhead`` of ``nhead_published`` query heads, from
+    ``head_first`` on, and the key/value heads they read (:class:`HeadShare`).
 
     ``nhead``, ``window`` and the rotary keys differ by layer; ``nkvhead``,
     ``head_dim`` and ``eps`` are global pairs of the conf.  Head-major from
@@ -379,15 +414,23 @@ class GroupedAttentionLayer(SequenceLayer):
         self.rope_original_positions = 0
         self.rope_beta_fast, self.rope_beta_slow = 32.0, 1.0
         self.rope_attention_factor = 1.0
+        self.use_rope = 1
+        self.gate = 'head'
 
     def set_param(self, name, val):
         super().set_param(name, val)
+        self._set_head_share(name, val)
         if name in ('nhead', 'nkvhead', 'head_dim', 'window', 'rotary_dims',
-                    'rope_original_positions'):
+                    'rope_original_positions', 'use_rope'):
             setattr(self, name, int(val))
         if name in ('rope_theta', 'rope_factor', 'rope_beta_fast',
                     'rope_beta_slow', 'rope_attention_factor'):
             setattr(self, name, float(val))
+        if name == 'gate':
+            if val not in ('head', 'elementwise'):
+                raise ValueError(f'gqa: gate = {val!r}, not head or '
+                                 f'elementwise')
+            self.gate = val
 
     def infer_shapes(self, in_specs):
         rot = self.rotary_dims or self.head_dim
@@ -400,15 +443,19 @@ class GroupedAttentionLayer(SequenceLayer):
                 'gqa: set nhead (a multiple of nkvhead), nkvhead, head_dim, '
                 'window >= 0, rotary_dims (even, at most head_dim) and, with '
                 'rope_factor > 1, rope_original_positions')
+        share = self._head_share_error(self.nhead // self.nkvhead)
+        if share:
+            raise ValueError(f'gqa: {share}: hold whole key/value heads')
         return [self._seq_spec(in_specs[0], 'gqa')]
 
     def init_params(self, rng, in_specs, dtype=jnp.float32) -> Params:
         d, hd = in_specs[0].c, self.head_dim
+        gates = self.nhead * (hd if self.gate == 'elementwise' else 1)
         return {'norm': jnp.ones((d,), dtype),
                 'wq': self._w(rng, 0, (d, self.nhead * hd), dtype),
                 'wk': self._w(rng, 1, (d, self.nkvhead * hd), dtype),
                 'wv': self._w(rng, 2, (d, self.nkvhead * hd), dtype),
-                'wgate': self._w(rng, 3, (d, self.nhead), dtype),
+                'wgate': self._w(rng, 3, (d, gates), dtype),
                 'wo': self._w(rng, 4, (self.nhead * hd, d), dtype)}
 
     def forward(self, params, inputs, ctx):
@@ -422,26 +469,193 @@ class GroupedAttentionLayer(SequenceLayer):
                               w.astype(dt).reshape(d, n, hd),
                               preferred_element_type=jnp.float32).astype(dt)
 
-        inv_freq = yarn_frequencies(
-            self.rotary_dims or hd, self.rope_theta, self.rope_factor,
-            self.rope_original_positions, self.rope_beta_fast,
-            self.rope_beta_slow)
+        def turned(a):
+            """Rotary positions, or none (``use_rope = 0``)."""
+            if not self.use_rope:
+                return a
+            inv_freq = yarn_frequencies(
+                self.rotary_dims or hd, self.rope_theta, self.rope_factor,
+                self.rope_original_positions, self.rope_beta_fast,
+                self.rope_beta_slow)
+            return rotary(a, inv_freq, self.rope_attention_factor)
+
+        elementwise = self.gate == 'elementwise'
         x = rms_norm(h, params['norm'], self.eps)
-        q = rotary(heads(x, params['wq'], self.nhead), inv_freq,
-                   self.rope_attention_factor)
-        k = rotary(heads(x, params['wk'], self.nkvhead), inv_freq,
-                   self.rope_attention_factor)
+        q = turned(heads(x, params['wq'], self.nhead))
+        k = turned(heads(x, params['wk'], self.nkvhead))
         v = heads(x, params['wv'], self.nkvhead)
         o = causal_attention(q, k, v, 1.0 / math.sqrt(hd), ctx.spmd_devices,
                              window=self.window)             # (b, nh, s, hd)
-        gate = jax.nn.sigmoid(jnp.dot(x, params['wgate'].astype(dt),
-                                      preferred_element_type=jnp.float32))
+        if elementwise:                                      # (b, nh, s, hd)
+            gate = jax.nn.sigmoid(jnp.einsum(
+                'bsr,rhd->bhsd', x,
+                params['wgate'].astype(dt).reshape(d, self.nhead, hd),
+                preferred_element_type=jnp.float32))
+        else:                                                # (b, s, nh)
+            gate = jax.nn.sigmoid(jnp.dot(x, params['wgate'].astype(dt),
+                                          preferred_element_type=jnp.float32))
         o = (o.astype(jnp.float32)
-             * jnp.moveaxis(gate, 2, 1)[..., None]).astype(dt)
+             * (gate if elementwise
+                else jnp.moveaxis(gate, 2, 1)[..., None])).astype(dt)
         out = jnp.einsum('bhsv,hvd->bsd', o,
                          params['wo'].astype(dt).reshape(self.nhead, hd, d),
                          preferred_element_type=jnp.float32)
         return [(h.astype(jnp.float32) + out).astype(dt)[:, None]]
+
+
+def l2_norm(x, eps: float = 1e-6):
+    """``x / sqrt(sum(x^2) + eps)`` over the last axis, float32."""
+    x = x.astype(jnp.float32)
+    return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + eps)
+
+
+def short_conv(x, w):
+    """Causal depthwise convolution along the axis before the last: ``x``
+    ``(..., seq, c)``, ``w`` ``(taps, c)``, ``y_t = sum_j w_j x_{t - taps + 1
+    + j}`` with zeros before the first position; float32, no bias."""
+    taps, s = w.shape[0], x.shape[-2]
+    x = jnp.pad(x.astype(jnp.float32),
+                [(0, 0)] * (x.ndim - 2) + [(taps - 1, 0), (0, 0)])
+    w = w.astype(jnp.float32)
+    return sum(x[..., j:j + s, :] * w[j] for j in range(taps))
+
+
+@register_layer
+class DeltaAttentionLayer(HeadShare, SequenceLayer):
+    """Kimi Delta Attention (Kimi Linear, arXiv 2510.26692, section 3): a
+    short convolution and a per-channel gated delta rule over ``nhead``
+    heads of ``head_dim``, with its pre-norm and its residual.
+
+    ``x = RMSNorm(h)``; for each head, ``q = L2Norm(SiLU(Conv(x W_q)))``,
+    ``k`` the same with ``W_k``, ``v = SiLU(Conv(x W_v))`` (``Conv``: causal,
+    depthwise, ``CONV_TAPS`` taps, no bias); one decay a channel of ``k``,
+    ``log alpha = -exp(A_log) * softplus(x W_a_down W_a_up + dt_bias)``
+    (``A_log`` one a head; ``W_a_down`` ``(d, head_dim)``, the low-rank
+    form), ``beta = BETA_MAX * sigmoid(x W_beta)`` one a head
+    (``BETA_MAX`` 2 lets ``I - beta k k^T`` take a negative eigenvalue);
+    ``S_t = (I - beta_t k_t k_t^T) Diag(alpha_t) S_{t-1} + beta_t k_t
+    v_t^T`` from ``S_0 = 0`` and ``o_t = S_t^T q_t / sqrt(head_dim)``
+    (``ops/delta_rule``: chunks of 64, float32 at the highest precision);
+    out ``h + sum_heads (sigmoid(x W_g_down W_g_up + b_g) * RMSNorm(o))
+    W_o``, the output norm's gain shared by the heads.
+
+    Products in the context's compute type with float32 accumulation; the
+    convolution, the norms, the decays, ``beta``, the gate and the
+    recurrence in float32.  Head-major from the products on, as ``gqa``.
+    Besides its output the layer counts the most negative summed
+    ``log alpha`` of any chunk of 64 tokens (``kda.chunk_log_decay_min``):
+    how near the chunked form runs to float32's ``exp`` range."""
+
+    type_name = 'kda'
+    type_id = kKDA
+    param_fields = ('norm', 'wq', 'wk', 'wv', 'conv_q', 'conv_k', 'conv_v',
+                    'wa_down', 'wa_up', 'dt_bias', 'a_log', 'wbeta',
+                    'wg_down', 'wg_up', 'g_bias', 'o_norm', 'wo')
+    recompute = True
+    has_stats = True
+    #: Kimi Linear's ``short_conv_kernel_size`` 4 and
+    #: ``kda_allow_neg_eigval`` (``beta`` in (0, 2))
+    CONV_TAPS = 4
+    BETA_MAX = 2.0
+
+    def __init__(self, name: str = ''):
+        super().__init__(name=name)
+        self.nhead = self.head_dim = 0
+
+    def set_param(self, name, val):
+        super().set_param(name, val)
+        self._set_head_share(name, val)
+        if name in ('nhead', 'head_dim'):
+            setattr(self, name, int(val))
+
+    def infer_shapes(self, in_specs):
+        if min(self.nhead, self.head_dim) <= 0 or self._head_share_error():
+            raise ValueError('kda: set nhead and head_dim; hold nhead of '
+                             'nhead_published heads from head_first')
+        return [self._seq_spec(in_specs[0], 'kda')]
+
+    def init_params(self, rng, in_specs, dtype=jnp.float32) -> Params:
+        d, hd, nh = in_specs[0].c, self.head_dim, self.nhead
+        width = nh * hd
+        ka, kt = jax.random.split(jax.random.fold_in(rng, 100))
+        # Mamba's convention: A from U(1, 16), the decay's step (softplus of
+        # dt_bias) log-uniform over [1e-3, 0.1]
+        dt = jnp.exp(jax.random.uniform(kt, (width,), jnp.float32,
+                                        math.log(1e-3), math.log(0.1)))
+        return {'norm': jnp.ones((d,), dtype),
+                'wq': self._w(rng, 0, (d, width), dtype),
+                'wk': self._w(rng, 1, (d, width), dtype),
+                'wv': self._w(rng, 2, (d, width), dtype),
+                'conv_q': self._w(rng, 3, (self.CONV_TAPS, width), dtype),
+                'conv_k': self._w(rng, 4, (self.CONV_TAPS, width), dtype),
+                'conv_v': self._w(rng, 5, (self.CONV_TAPS, width), dtype),
+                'wa_down': self._w(rng, 6, (d, hd), dtype),
+                'wa_up': self._w(rng, 7, (hd, width), dtype),
+                'dt_bias': (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype),
+                'a_log': jnp.log(jax.random.uniform(
+                    ka, (nh,), jnp.float32, 1.0, 16.0)).astype(dtype),
+                'wbeta': self._w(rng, 8, (d, nh), dtype),
+                'wg_down': self._w(rng, 9, (d, hd), dtype),
+                'wg_up': self._w(rng, 10, (hd, width), dtype),
+                'g_bias': jnp.zeros((width,), dtype),
+                'o_norm': jnp.ones((hd,), dtype),
+                'wo': self._w(rng, 11, (width, d), dtype)}
+
+    @staticmethod
+    def recurrence(q, k, v, log_decay, beta):
+        """The delta rule as the layer runs it, from its float32 inputs:
+        ``q``, ``k``, ``log_decay`` ``(b, nh, s, head_dim)``, ``v`` the same,
+        ``beta`` ``(b, nh, s)`` -> ``o`` ``(b, nh, s, head_dim)``.  The
+        benchmark's comparison calls it on the plain reference's inputs at
+        the cell's size."""
+        return delta_rule.chunk_gated_delta_rule(
+            q, k, v, log_decay, beta, 1.0 / math.sqrt(q.shape[-1]))
+
+    def forward_with_stats(self, params, inputs, ctx):
+        h = inputs[0][:, 0]                                  # (b, s, d)
+        d, dt, hd, nh = h.shape[2], h.dtype, self.head_dim, self.nhead
+        f32 = jnp.float32
+
+        def heads(a, w, bias=None):
+            """``a`` through ``w``'s columns, head-major and float32:
+            ``(b, s, r) -> (b, nh, s, hd)``."""
+            out = jnp.einsum('bsr,rhd->bhsd', a,
+                             w.astype(dt).reshape(w.shape[0], nh, hd),
+                             preferred_element_type=f32)
+            if bias is not None:
+                out = out + bias.astype(f32).reshape(nh, 1, hd)
+            return out
+
+        def low_rank(down, up, bias):
+            r = jnp.dot(x, params[down].astype(dt),
+                        preferred_element_type=f32).astype(dt)
+            return heads(r, params[up], params[bias])
+
+        def conv(w):
+            return short_conv(heads(x, params['w' + w]),
+                              params['conv_' + w].astype(f32).reshape(
+                                  -1, nh, 1, hd))
+
+        x = rms_norm(h, params['norm'], self.eps)
+        q = l2_norm(jax.nn.silu(conv('q')))
+        k = l2_norm(jax.nn.silu(conv('k')))
+        v = jax.nn.silu(conv('v'))
+        log_decay = -jnp.exp(params['a_log'].astype(f32)).reshape(nh, 1, 1) \
+            * jax.nn.softplus(low_rank('wa_down', 'wa_up', 'dt_bias'))
+        beta = self.BETA_MAX * jax.nn.sigmoid(jnp.moveaxis(jnp.dot(
+            x, params['wbeta'].astype(dt), preferred_element_type=f32), 2, 1))
+        o = self.recurrence(q, k, v, log_decay, beta)
+        gate = jax.nn.sigmoid(low_rank('wg_down', 'wg_up', 'g_bias'))
+        o = (rms_norm(o, params['o_norm'], self.eps) * gate).astype(dt)
+        out = jnp.einsum('bhsv,hvd->bsd', o,
+                         params['wo'].astype(dt).reshape(nh, hd, d),
+                         preferred_element_type=f32)
+        stats = {'kda.chunk_log_decay_min': jnp.min(
+            delta_rule.chunk_log_decay_sums(log_decay))}
+        return [(h.astype(f32) + out).astype(dt)[:, None]], stats
+
+    def forward(self, params, inputs, ctx):
+        return self.forward_with_stats(params, inputs, ctx)[0]
 
 
 @register_layer

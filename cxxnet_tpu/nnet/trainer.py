@@ -66,13 +66,14 @@ def _apply_input_norm(data, norm):
 
 def _over_layers(stats):
     """Per-layer step statistics (``<scope>/<name>``, ``Net.forward``)
-    folded over the layers: the worst layer of a ``*max_over_mean``, the
-    mean of anything else."""
+    folded over the layers: the worst layer of a ``*max_over_mean`` (its
+    largest) and of a ``*_min`` (its smallest), the mean of anything else."""
+    fold = {'max_over_mean': jnp.max, '_min': jnp.min}
     by_name = {}
     for key, value in stats.items():
         by_name.setdefault(key.split('/', 1)[1], []).append(value)
-    return {name: (jnp.max(jnp.stack(v)) if name.endswith('max_over_mean')
-                   else jnp.mean(jnp.stack(v)))
+    return {name: next((f for end, f in fold.items() if name.endswith(end)),
+                       jnp.mean)(jnp.stack(v))
             for name, v in by_name.items()}
 
 

@@ -10,7 +10,12 @@ renumbered.  At ``update_period = 2`` the accumulator stays, and the
 per-step programs (applying and not) and the scanned window hash to what
 the parent's tree gave: PR 32 changed nothing there.  All were taken under
 this JAX; another JAX lowers to other text, and then there is nothing to
-compare with."""
+compare with.
+
+The language-model step programs of ``example/LM/tiny-glm.conf`` and
+``tiny-laguna.conf`` at batch 2 hash to what the tree before the ``kda``
+layer gave (8ec148d): the ``gqa`` keys added with it (``use_rope``,
+``gate``, the head share) leave both as they were."""
 
 import hashlib
 import os
@@ -149,3 +154,28 @@ def test_period_2_programs_are_the_parents(program):
         got = _step_hash(tr, _staged(tr, 8, (1, 8, 8), 4),
                          do_update=program == 'step-applies')
     assert got == PERIOD_2[program]
+
+
+# 8ec148d's tree: the sequence confs' step programs at batch 2, one
+# token id a position and the next token's label (two heads for the GLM
+# twin's multi-token-prediction module)
+LM_CASES = {
+    'tiny-glm': (2, '18ae84738b5228948b47da0ccf8892f55a52074e8dee9af4f416d5f8b31c5438'),
+    'tiny-laguna': (1, '296d12655f89cc62831dcae83eea45c333e13a37bd4850aab3e82e582aecdae6'),
+}
+
+
+@pytest.mark.parametrize('case', sorted(LM_CASES))
+def test_lm_step_program_is_the_parents(case):
+    if jax.__version__ != TAKEN_WITH_JAX:
+        pytest.skip(f'hashes taken with jax {TAKEN_WITH_JAX}')
+    heads, want = LM_CASES[case]
+    tr = _trainer(parse_config_file(os.path.join(ROOT, 'example', 'LM',
+                                                 case + '.conf')), 2)
+    assert tr.net.takes_token_ids
+    rng = np.random.RandomState(0)
+    staged = tr.stage_batch(DataBatch(
+        rng.randint(0, 96, (2, 1, 1, 65)).astype(np.float32),
+        rng.randint(0, 96, (2, 64 * heads)).astype(np.float32)))
+    assert _step_hash(tr, staged) == want
+

@@ -18,7 +18,7 @@ from cxxnet_tpu.nnet.trainer import NetTrainer
 from cxxnet_tpu.obs import get_hub, span
 from cxxnet_tpu.ops import pallas_kernels as pk
 from cxxnet_tpu.utils import profiler
-from cxxnet_tpu.utils.config import parse_config_string
+from cxxnet_tpu.utils.config import parse_config_file, parse_config_string
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -146,6 +146,30 @@ def test_scopes_change_names_only(monkeypatch):
     assert _strip_names(with_names.as_text(debug_info=True)) \
         == _strip_names(bare.as_text(debug_info=True))
     assert with_names.as_text() == bare.as_text()
+
+
+def test_a_delta_attention_layer_is_scoped_like_every_conf_layer():
+    """``kda`` layers run under ``lNN_kda_<name>``, forward, recomputation
+    and backward, which is what ``net.kda_ms_per_step`` and
+    ``net.kda_roofline_pct`` split the trace by (``scope_times.scope_ms(run,
+    'kda')``); lowered, not compiled."""
+    pairs = parse_config_file(os.path.join(REPO, 'example', 'LM',
+                                           'tiny-solar.conf'))
+    # from the net on: the data section names an iterator, not the net
+    tr = NetTrainer(pairs[pairs.index(('netconfig', 'start')):])
+    tr.init_model()
+    scopes = [s for s in tr.net.layer_scopes if '_kda_' in s]
+    assert scopes == ['l04_kda_kda1', 'l06_kda_kda2', 'l08_kda_kda3']
+    rng = np.random.RandomState(0)
+    data, label, extra, mask = tr.stage_batch(DataBatch(
+        rng.randint(0, 96, (2, 1, 1, 97)).astype(np.float32),
+        rng.randint(0, 96, (2, 96)).astype(np.float32)))[:4]
+    text = tr._train_step_fn._jit.lower(
+        tr.params, tr.opt_state, tr.grad_acc, data, label, extra, mask,
+        jax.random.PRNGKey(0), 0, 0, do_update=True).as_text(debug_info=True)
+    for scope in scopes:
+        assert f'/jvp({scope})/' in text and \
+            f'/transpose(jvp({scope}))/' in text, scope
 
 
 # --- A.2: a name per Pallas kernel ------------------------------------------

@@ -559,7 +559,7 @@ def _kernels_by_scope(hlo):
     return found
 
 
-@pytest.mark.parametrize('step', ['glm_step', 'laguna_step'])
+@pytest.mark.parametrize('step', ['glm_step', 'laguna_step', 'solar_step'])
 def test_the_grouped_products_are_filed_under_their_moe_layer(step, request):
     """XLA writes ``op_name="ragged-dot-none"`` over a grouped product's
     path, so the call names no scope of its own; ``profiler.hlo_program``
@@ -638,3 +638,68 @@ def test_no_assignment_row_array_stands_in_an_expert_layers_branch(
     assert moe.bounded_rows(8192 * 10, 8, 256, 8192) == 8192
     _a_bounded_branch_and_the_blocks(hlo, pas, products, 4, 8192, 3072, 1024)
     assert not re.findall(r'(?:f32|bf16)\[81920,', hlo)
+
+
+# --- Solar-Open2-250B's step: the chunked delta rule beside NoPE GQA ---------
+
+@pytest.fixture(scope='module')
+def solar_step(one_chip):
+    """``example/LM/Solar-Open2-250B.ep40tp4.conf``'s training step, compiled
+    for one v5e chip from shapes alone (about a minute and a half)."""
+    from cxxnet_tpu.nnet.trainer import NetTrainer
+    from cxxnet_tpu.ops import attention
+    tr = NetTrainer(_conf_without_iterators('LM',
+                                            'Solar-Open2-250B.ep40tp4.conf')
+                    + [('dev', 'cpu')])
+    tr.init_net()
+    params, opt, arg = _described(tr, one_chip)
+    seq = 8192
+    with pytest.MonkeyPatch.context() as patch:
+        # ops/attention asks jax.default_backend(), which is the CPU here:
+        # the test steers it, the program has no option for it
+        patch.setattr(attention, '_use_splash',
+                      lambda q, k, v, spmd: spmd == 1)
+        compiled = tr._train_step_fn._jit.lower(
+            params, opt, None,
+            arg((1, 1, 1, seq + 1), jnp.int32),
+            arg((1, seq), jnp.float32), (), arg((1,), jnp.float32),
+            arg((2,), jnp.uint32), 0, 0, do_update=True).compile()
+    return compiled, params
+
+
+def test_the_solar_step_fits_the_chip(solar_step):
+    """905.8 M parameters at 12 bytes each resident (10.12 GiB) and the
+    step's temporaries under the chip's 15.75 GiB: the compiler's plan,
+    printed.  Read 15.74e9 bytes (14.66 GiB; the chip's peak read 14.64
+    GiB, PERF.md 5); the peak lies in the last expert layer's backward
+    through the blocks of its buffer, not in a delta layer."""
+    import numpy as np
+    compiled, params = solar_step
+    m = compiled.memory_analysis()
+    state = sum(int(np.prod(a.shape)) for a in jax.tree.leaves(params)) * 12
+    assert state == 905_766_576 * 12
+    live = (m.argument_size_in_bytes + m.output_size_in_bytes
+            - m.alias_size_in_bytes + m.temp_size_in_bytes)
+    print(f'the Solar step\'s plan: {live} bytes, {live / 2 ** 30:.3f} GiB '
+          f'(state {state / 2 ** 30:.3f}, temporaries '
+          f'{m.temp_size_in_bytes / 2 ** 30:.3f})')
+    assert m.alias_size_in_bytes >= state          # the state is donated
+    assert live < 15.9e9 < 15.75 * 2 ** 30, live / 2 ** 30
+
+
+@pytest.mark.parametrize('event,calls', [('splash_mqa_fwd', 2),
+                                         ('splash_mqa_dq', 1),
+                                         ('splash_mqa_dkv', 1)])
+def test_the_solar_kernels_keep_their_names(solar_step, event, calls):
+    """The one softmax layer's Mosaic calls are the block-sparse kernels
+    ``kernels.gqa_*_roofline_pct`` read (the forward twice: the
+    recomputation); every other Mosaic call of the step is a grouped
+    product: the delta layers' chunked rule, its triangular solves
+    included, is plain XLA."""
+    kernels = _kernels_by_scope(solar_step[0].as_text())
+    in_gqa = [n for n, (scope, _) in kernels.items() if '_gqa' in scope]
+    assert len([n for n in in_gqa if n.startswith(event)]) == calls
+    assert not [n for n, (scope, _) in kernels.items() if '_kda' in scope]
+    others = {n for n in kernels if n not in in_gqa}
+    assert others and all(n.startswith('ragged-dot') for n in others), others
+
