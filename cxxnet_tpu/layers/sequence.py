@@ -535,7 +535,8 @@ class DeltaAttentionLayer(HeadShare, SequenceLayer):
     (``BETA_MAX`` 2 lets ``I - beta k k^T`` take a negative eigenvalue);
     ``S_t = (I - beta_t k_t k_t^T) Diag(alpha_t) S_{t-1} + beta_t k_t
     v_t^T`` from ``S_0 = 0`` and ``o_t = S_t^T q_t / sqrt(head_dim)``
-    (``ops/delta_rule``: chunks of 64, float32 at the highest precision);
+    (``ops/delta_rule``: chunks of 64, float32 at the highest precision,
+    Pallas kernels on one TPU chip);
     out ``h + sum_heads (sigmoid(x W_g_down W_g_up + b_g) * RMSNorm(o))
     W_o``, the output norm's gain shared by the heads.
 
@@ -602,14 +603,16 @@ class DeltaAttentionLayer(HeadShare, SequenceLayer):
                 'wo': self._w(rng, 11, (width, d), dtype)}
 
     @staticmethod
-    def recurrence(q, k, v, log_decay, beta):
+    def recurrence(q, k, v, log_decay, beta, spmd_devices: int = 1):
         """The delta rule as the layer runs it, from its float32 inputs:
         ``q``, ``k``, ``log_decay`` ``(b, nh, s, head_dim)``, ``v`` the same,
-        ``beta`` ``(b, nh, s)`` -> ``o`` ``(b, nh, s, head_dim)``.  The
-        benchmark's comparison calls it on the plain reference's inputs at
-        the cell's size."""
-        return delta_rule.chunk_gated_delta_rule(
-            q, k, v, log_decay, beta, 1.0 / math.sqrt(q.shape[-1]))
+        ``beta`` ``(b, nh, s)`` -> ``o`` ``(b, nh, s, head_dim)``; the
+        Pallas kernels on one TPU chip, XLA elsewhere
+        (``delta_rule.gated_delta_rule``).  The benchmark's comparison calls
+        it on the plain reference's inputs at the cell's size."""
+        return delta_rule.gated_delta_rule(
+            q, k, v, log_decay, beta, 1.0 / math.sqrt(q.shape[-1]),
+            spmd_devices)
 
     def forward_with_stats(self, params, inputs, ctx):
         h = inputs[0][:, 0]                                  # (b, s, d)
@@ -644,7 +647,7 @@ class DeltaAttentionLayer(HeadShare, SequenceLayer):
             * jax.nn.softplus(low_rank('wa_down', 'wa_up', 'dt_bias'))
         beta = self.BETA_MAX * jax.nn.sigmoid(jnp.moveaxis(jnp.dot(
             x, params['wbeta'].astype(dt), preferred_element_type=f32), 2, 1))
-        o = self.recurrence(q, k, v, log_decay, beta)
+        o = self.recurrence(q, k, v, log_decay, beta, ctx.spmd_devices)
         gate = jax.nn.sigmoid(low_rank('wg_down', 'wg_up', 'g_bias'))
         o = (rms_norm(o, params['o_norm'], self.eps) * gate).astype(dt)
         out = jnp.einsum('bhsv,hvd->bsd', o,

@@ -25,10 +25,21 @@ lower-triangular solve a chunk, in float32).  Then
     M[r, i] = scale sum_c q_r[c] k_i[c] exp(G_r[c] - G_i[c])         (i <= r)
     S_C = Diag(exp(G_C)) S_0 + (K . exp(G_C - G))^T Delta.
 
-Every ``U``, ``W``, ``A`` and ``M`` is computed for all chunks at once by
-products; the scan over chunks carries ``S`` alone and does two products a
-step; the outputs are one batched product after it.  What a step of the
-scan keeps for the backward pass is one state a chunk, never one a token.
+Two forms compute this, one algorithm on two backends; ``gated_delta_rule``
+chooses from what it can observe, as ``ops/attention`` chooses a kernel:
+
+* on one TPU chip, with ``dk`` and ``dv`` multiples of 128: two Pallas
+  kernels under one ``custom_vjp`` (``ops/delta_rule_kernel``), a forward
+  that walks the chunks in order with the state in VMEM and a backward that
+  walks them in reverse with the state's gradient there;
+* everywhere else (the CPU, a mesh of several devices, other head sizes):
+  ``chunk_gated_delta_rule`` in XLA.  Every ``U``, ``W``, ``A`` and ``M``
+  is computed for all chunks at once by products and one batched
+  triangular solve; a scan over chunks carries ``S`` alone and does two
+  products a step; the outputs are one batched product after it; autodiff
+  makes the backward pass.
+
+Either keeps one state a chunk for the backward pass, never one a token.
 
 A decay summed over a chunk passes -88 readily (64 tokens of -1.4), and
 ``exp(-G_i)`` then overflows float32.  So ``exp(G_r - G_i)`` is never taken
@@ -45,6 +56,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from . import delta_rule_kernel
 
 #: tokens a chunk, and a sub-chunk of the pairs taken elementwise
 CHUNK = 64
@@ -143,6 +156,24 @@ def chunk_gated_delta_rule(q, k, v, log_decay, beta, scale: float,
     o = scale * (_mm('...ck,...kv->...cv', q * jnp.exp(gc), before)
                  + _mm('...ri,...iv->...rv', _pairs(q, k, gc, sub), delta))
     return o.reshape(b, h, n * chunk, dv)[:, :, :s]
+
+
+def _use_kernel(q, v, spmd_devices: int) -> bool:
+    # the backend, the mesh and the heads decide: no option does (the
+    # kernels' interpret mode is for tests, never worth a step)
+    return (spmd_devices == 1 and jax.default_backend() == 'tpu'
+            and q.shape[-1] % 128 == 0 and v.shape[-1] % 128 == 0)
+
+
+def gated_delta_rule(q, k, v, log_decay, beta, scale: float,
+                     spmd_devices: int = 1):
+    """``chunk_gated_delta_rule``'s contract at chunks of ``CHUNK``: on the
+    Pallas kernels where a step runs on one TPU chip and the heads are
+    multiples of 128 wide, in XLA elsewhere (module docstring)."""
+    if _use_kernel(q, v, spmd_devices):
+        return delta_rule_kernel.chunk_gated_delta_rule(
+            q, k, v, log_decay, beta, scale, CHUNK, SUB)
+    return chunk_gated_delta_rule(q, k, v, log_decay, beta, scale)
 
 
 def chunk_log_decay_sums(log_decay, chunk: int = CHUNK):

@@ -1,4 +1,4 @@
-"""The Pallas TPU kernel of the tree: the tiled matmul behind fullc.
+"""The Pallas TPU kernels' names, and the tiled matmul behind fullc.
 
 A hand-written kernel stays here only if some run that forces nothing can
 reach it on some platform (PR 31): the matmul's forward is picked by
@@ -15,6 +15,9 @@ shape class.  Design notes:
   neighbouring convolutions keep (PERF.md 6, PR 28).
 * **attention** is ``ops/attention.py``: JAX's own flash kernel on one
   TPU chip, XLA over blocks of queries elsewhere.
+* **the gated delta rule** of the ``kda`` layers is
+  ``ops/delta_rule_kernel.py``: a forward and a backward kernel over the
+  chunks on one TPU chip, XLA elsewhere (``ops/delta_rule.py``).
 * **fullc** gets a tiled-MXU matmul (``pallas_matmul``) where
   ``fullc_use_pallas`` says so, everywhere under ``CXXNET_PALLAS=1``;
   XLA's dot is the default.
@@ -95,7 +98,8 @@ def _interpret() -> bool:
 #: events by words such as ``convolution`` or ``all-reduce`` in their text
 #: must keep seeing a Mosaic custom call.  A new ``pallas_call`` adds its
 #: name here (tests/test_trace_names.py holds every call site to the table).
-KERNEL_NAMES = ('matmul', 'matmul_nt', 'matmul_tn')
+KERNEL_NAMES = ('matmul', 'matmul_nt', 'matmul_tn', 'delta_rule_fwd',
+                'delta_rule_bwd')
 
 
 def _block_spec(shape, index_map=None):

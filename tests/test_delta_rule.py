@@ -2,11 +2,14 @@
 sizes on the CPU: the chunked delta rule (ops/delta_rule.py) against the
 plain reference's token-by-token recurrence (benchmark/references/
 solar_open2.py) at chunks of 16 and 64, with beta near 2 and decays past
-float32's ``exp`` range, forward and backward; the step statistic of the
+float32's ``exp`` range, forward and backward, in XLA and on the Pallas
+kernels (ops/delta_rule_kernel.py, in Pallas's interpreter) at heads of 128
+and chunks of 64; which of the two a step takes; the step statistic of the
 worst chunk; the head shares of a ``kda`` and a ``gqa`` layer and the expert
 shares of a ``moe`` layer against the uncut layers; and the ``gqa`` layer
 without positions and with a gate a channel."""
 
+import functools
 import os
 import sys
 
@@ -22,6 +25,7 @@ from benchmark.references import solar_open2 as R              # noqa: E402
 from cxxnet_tpu.layers import ForwardContext, NodeSpec         # noqa: E402
 from cxxnet_tpu.layers import sequence as S                    # noqa: E402
 from cxxnet_tpu.ops import delta_rule                          # noqa: E402
+from cxxnet_tpu.ops import delta_rule_kernel                   # noqa: E402
 
 
 # --- the chunked delta rule against the recurrence ---------------------------
@@ -89,6 +93,73 @@ def test_chunked_delta_rule_gradients_equal_the_recurrences():
         assert np.isfinite(np.asarray(a)).all()
         np.testing.assert_allclose(a, b, atol=5e-5 * max(
             1.0, float(np.abs(b).max())))
+
+
+_kernels = functools.partial(delta_rule_kernel.chunk_gated_delta_rule,
+                             chunk=64, sub=16, interpret=True)
+KERNEL_CASES = ['beta near 2', 'decay past -88']
+
+
+def _kernel_inputs(case):
+    """2 heads of 128 over 130 positions (two chunks of 64 and a short
+    one), beta all but 2 or decays of up to -3 a position (chunk sums near
+    -96)."""
+    q, k, v, g, beta = _recurrence_inputs(
+        130, seed=11, dk=128, dv=128,
+        decay=3.0 if case == 'decay past -88' else 0.05)
+    if case == 'beta near 2':
+        beta = jnp.full_like(beta, 2.0 * 0.9999)
+    else:
+        assert float(delta_rule.chunk_log_decay_sums(g).min()) < -88.0
+    return q, k, v, g, beta
+
+
+@pytest.mark.parametrize('case', KERNEL_CASES)
+def test_the_kernels_equal_the_recurrence_and_the_xla_form(case):
+    """The forward kernel's output against the token-by-token recurrence
+    and against the XLA form, at the tolerance of the XLA form's test."""
+    args = _kernel_inputs(case)
+    got = jax.jit(lambda *a: _kernels(*a, 0.3))(*args)
+    assert np.isfinite(np.asarray(got)).all()
+    for want in (_token_by_token(*args, 0.3), _chunked(*args, 0.3, 64)):
+        scale = float(np.abs(want).max())
+        assert float(np.abs(got - want).max()) <= 2e-5 * max(scale, 1.0)
+
+
+@pytest.mark.parametrize('case', KERNEL_CASES)
+def test_the_kernels_gradients_equal_the_recurrences(case):
+    """All five gradients through the backward kernel (the solve's, the
+    sub-chunk exponentials' and the chunk decays' among them) against the
+    recurrence's and the XLA form's, at the tolerance of the XLA form's
+    test."""
+    args = _kernel_inputs(case)
+    w = jax.random.normal(jax.random.PRNGKey(3), args[2].shape)
+
+    def grads(rule):
+        return jax.jit(jax.grad(lambda *a: jnp.sum(w * rule(*a)),
+                                argnums=range(5)))(*args)
+    got = grads(lambda *a: _kernels(*a, 0.3))
+    for wants in (grads(lambda *a: _token_by_token(*a, 0.3)),
+                  grads(lambda *a: delta_rule.chunk_gated_delta_rule(
+                      *a, 0.3, 64))):
+        for a, b in zip(got, wants):
+            assert np.isfinite(np.asarray(a)).all()
+            np.testing.assert_allclose(a, b, atol=5e-5 * max(
+                1.0, float(np.abs(b).max())))
+
+
+@pytest.mark.parametrize('spmd,dk,dv,kernel', [
+    (1, 128, 128, True), (1, 256, 128, True), (4, 128, 128, False),
+    (1, 64, 128, False), (1, 128, 96, False)])
+def test_the_kernels_run_on_one_chip_at_heads_of_128(monkeypatch, spmd, dk,
+                                                     dv, kernel):
+    """``gated_delta_rule`` takes the kernels where the step runs on one
+    TPU chip and both head widths are multiples of 128, the XLA form
+    elsewhere; on the CPU always the XLA form."""
+    q, v = jnp.zeros((1, 1, 8, dk)), jnp.zeros((1, 1, 8, dv))
+    assert not delta_rule._use_kernel(q, v, spmd)
+    monkeypatch.setattr(delta_rule.jax, 'default_backend', lambda: 'tpu')
+    assert delta_rule._use_kernel(q, v, spmd) == kernel
 
 
 def test_the_statistic_is_the_worst_chunk_over_layers():

@@ -207,7 +207,20 @@ KERNEL_CASES = {
                           (_f32(8, 16), _f32(16, 8))),
     'matmul_tn': lambda: (jax.grad(lambda a, b: jnp.sum(
         pk.pallas_matmul(a, b)), argnums=1), (_f32(8, 16), _f32(16, 8))),
+    'delta_rule_fwd': lambda: (_delta_rule, _delta_rule_args()),
+    'delta_rule_bwd': lambda: (_grad(_delta_rule), _delta_rule_args()),
 }
+
+
+def _delta_rule(q, k, v, g, beta):
+    from cxxnet_tpu.ops import delta_rule_kernel
+    return delta_rule_kernel.chunk_gated_delta_rule(q, k, v, g, beta, 0.5, 16,
+                                                    8, interpret=True)
+
+
+def _delta_rule_args():
+    return (_f32(1, 1, 16, 8), _f32(1, 1, 16, 8), _f32(1, 1, 16, 8),
+            -_f32(1, 1, 16, 8), _f32(1, 1, 16))
 
 
 def test_kernel_table_is_the_cases():
@@ -238,7 +251,7 @@ def test_every_pallas_call_site_is_named_from_the_table():
                 v = kw['name']
                 assert isinstance(v, ast.Constant) \
                     and v.value in pk.KERNEL_NAMES, f'{path}:{node.lineno}'
-    assert sites == 3
+    assert sites == 5
 
 
 # --- B: hub spans on the profiler's clock -----------------------------------

@@ -647,7 +647,7 @@ def solar_step(one_chip):
     """``example/LM/Solar-Open2-250B.ep40tp4.conf``'s training step, compiled
     for one v5e chip from shapes alone (about a minute and a half)."""
     from cxxnet_tpu.nnet.trainer import NetTrainer
-    from cxxnet_tpu.ops import attention
+    from cxxnet_tpu.ops import attention, delta_rule
     tr = NetTrainer(_conf_without_iterators('LM',
                                             'Solar-Open2-250B.ep40tp4.conf')
                     + [('dev', 'cpu')])
@@ -655,10 +655,12 @@ def solar_step(one_chip):
     params, opt, arg = _described(tr, one_chip)
     seq = 8192
     with pytest.MonkeyPatch.context() as patch:
-        # ops/attention asks jax.default_backend(), which is the CPU here:
-        # the test steers it, the program has no option for it
+        # ops/attention and ops/delta_rule ask jax.default_backend(), which
+        # is the CPU here: the test steers them, the program has no option
+        # for it
         patch.setattr(attention, '_use_splash',
                       lambda q, k, v, spmd: spmd == 1)
+        patch.setattr(delta_rule, '_use_kernel', lambda q, v, spmd: spmd == 1)
         compiled = tr._train_step_fn._jit.lower(
             params, opt, None,
             arg((1, 1, 1, seq + 1), jnp.int32),
@@ -689,17 +691,47 @@ def test_the_solar_step_fits_the_chip(solar_step):
 
 @pytest.mark.parametrize('event,calls', [('splash_mqa_fwd', 2),
                                          ('splash_mqa_dq', 1),
-                                         ('splash_mqa_dkv', 1)])
+                                         ('splash_mqa_dkv', 1),
+                                         ('delta_rule_fwd', 2),
+                                         ('delta_rule_bwd', 1)])
 def test_the_solar_kernels_keep_their_names(solar_step, event, calls):
     """The one softmax layer's Mosaic calls are the block-sparse kernels
-    ``kernels.gqa_*_roofline_pct`` read (the forward twice: the
-    recomputation); every other Mosaic call of the step is a grouped
-    product: the delta layers' chunked rule, its triangular solves
-    included, is plain XLA."""
+    ``kernels.gqa_*_roofline_pct`` read, and each of the three delta
+    layers' are the delta rule's kernels ``kernels.delta_rule_ms_per_step``
+    reads: the forward twice (the recomputation) and the backward once.
+    Every other Mosaic call of the step is a grouped product."""
     kernels = _kernels_by_scope(solar_step[0].as_text())
-    in_gqa = [n for n, (scope, _) in kernels.items() if '_gqa' in scope]
-    assert len([n for n in in_gqa if n.startswith(event)]) == calls
-    assert not [n for n, (scope, _) in kernels.items() if '_kda' in scope]
-    others = {n for n in kernels if n not in in_gqa}
+    layer, names = (('_gqa', ('splash_mqa_fwd', 'splash_mqa_dq',
+                              'splash_mqa_dkv'))
+                    if event.startswith('splash') else
+                    ('_kda', ('delta_rule_fwd', 'delta_rule_bwd')))
+    mine = {n: scope for n, (scope, _) in kernels.items() if layer in scope}
+    assert mine and all(n.startswith(names) for n in mine), mine
+    scopes = sorted(set(mine.values()))
+    assert len(scopes) == (1 if layer == '_gqa' else 3), scopes
+    for scope in scopes:
+        assert len([n for n, at in mine.items()
+                    if at == scope and n.startswith(event)]) == calls, scope
+    others = {n for n, (scope, _) in kernels.items()
+              if '_gqa' not in scope and '_kda' not in scope}
     assert others and all(n.startswith('ragged-dot') for n in others), others
 
+
+def test_no_scan_or_solve_stands_in_a_delta_layer(solar_step):
+    """On the kernels the delta layers hold no loop over chunks and no
+    batched triangular solve (the XLA form's ``lax.scan``, its transpose
+    and ``lax.linalg.triangular_solve``, which the TPU's compiler rewrites
+    as ``InvertDiagBlocksLowerTriangular`` and fusions that keep its
+    ``op_name``), in any pass."""
+    from cxxnet_tpu.obs.programs import an_instruction_a_line
+    from cxxnet_tpu.utils import profiler
+    hlo = an_instruction_a_line(solar_step[0].as_text())
+    scopes = profiler.hlo_op_names(hlo)
+    found = []
+    for line in hlo.splitlines():
+        m = profiler._HLO_INSTRUCTION.match(line)
+        op = scopes.get(m.group(1), '') if m else ''
+        if '_kda' in profiler.scope_of(op)[0] and (
+                ' while(' in line or 'triangular' in op + line):
+            found.append(line[:200])
+    assert not found, found
