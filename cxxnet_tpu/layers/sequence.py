@@ -240,11 +240,14 @@ class LatentAttentionLayer(SequenceLayer):
     """Multi-head latent attention in the expanded (training) form, with its
     pre-norm and its residual: ``h + MLA(RMSNorm(h))``, no biases.
 
-    ``c_q = RMSNorm(x W_qa)``; ``[q_nope | q_rope] = c_q W_qb``;
+    ``c_q = RMSNorm(x W_qa)``; ``[q_nope | q_rope] = c_q W_qb``, or with
+    ``q_lora_rank = 0`` a full-rank query ``[q_nope | q_rope] = x W_q`` (no
+    ``W_qa``, no ``q_norm``: Kimi Linear's ``q_lora_rank: null``);
     ``[c_kv | k_r] = x W_kva``; ``[k_nope | v] = RMSNorm(c_kv) W_kvb``;
-    ``q_rope`` and ``k_r`` (one head, shared by all) rotated; scores
-    ``(q_nope . k_nope + q_rope . k_rope) / sqrt(nope + rope)``, causal
-    softmax, ``out = concat(P v) W_o``.  No cache, no absorption.
+    ``q_rope`` and ``k_r`` (one head, shared by all) rotated, or left as
+    they are with ``use_rope = 0`` (``mla_use_nope``); scores ``(q_nope .
+    k_nope + q_rope . k_rope) / sqrt(nope + rope)``, causal softmax, ``out =
+    concat(P v) W_o``.  No cache, no absorption.
 
     Between the projections and the kernels every activation is head-major,
     ``(batch, heads, seq, dim)``, written so by the product that makes it
@@ -256,6 +259,8 @@ class LatentAttentionLayer(SequenceLayer):
     type_id = kMLA
     param_fields = ('norm', 'wq_a', 'q_norm', 'wq_b', 'wkv_a', 'kv_norm',
                     'wkv_b', 'wo')
+    #: the leaves of a layer with a full-rank query (``q_lora_rank = 0``)
+    FULL_RANK_FIELDS = ('norm', 'wq', 'wkv_a', 'kv_norm', 'wkv_b', 'wo')
     recompute = True
 
     def __init__(self, name: str = ''):
@@ -264,6 +269,7 @@ class LatentAttentionLayer(SequenceLayer):
         self.q_lora_rank = self.kv_lora_rank = 0
         self.nope = self.rope_dim = self.v_dim = 0
         self.rope_theta = 10000.0
+        self.use_rope = 1
 
     def set_param(self, name, val):
         super().set_param(name, val)
@@ -271,6 +277,10 @@ class LatentAttentionLayer(SequenceLayer):
             self.nhead = int(val)
         if name == 'q_lora_rank':
             self.q_lora_rank = int(val)
+            self.param_fields = (type(self).param_fields if self.q_lora_rank
+                                 else self.FULL_RANK_FIELDS)
+        if name == 'use_rope':
+            self.use_rope = int(val)
         if name == 'kv_lora_rank':
             self.kv_lora_rank = int(val)
         if name == 'qk_nope_head_dim':
@@ -283,21 +293,25 @@ class LatentAttentionLayer(SequenceLayer):
             self.rope_theta = float(val)
 
     def infer_shapes(self, in_specs):
-        if min(self.nhead, self.q_lora_rank, self.kv_lora_rank, self.nope,
-               self.rope_dim, self.v_dim) <= 0 or self.rope_dim % 2:
+        if min(self.nhead, self.kv_lora_rank, self.nope, self.rope_dim,
+               self.v_dim) <= 0 or self.q_lora_rank < 0 or self.rope_dim % 2:
             raise ValueError(
-                'mla: set nhead, q_lora_rank, kv_lora_rank, '
-                'qk_nope_head_dim, qk_rope_head_dim (even) and v_head_dim')
+                'mla: set nhead, q_lora_rank (0: a full-rank query), '
+                'kv_lora_rank, qk_nope_head_dim, qk_rope_head_dim (even) and '
+                'v_head_dim')
         return [self._seq_spec(in_specs[0], 'mla')]
 
     def init_params(self, rng, in_specs, dtype=jnp.float32) -> Params:
         d, h = in_specs[0].c, self.nhead
-        return {
-            'norm': jnp.ones((d,), dtype),
+        qk = h * (self.nope + self.rope_dim)
+        query = {
             'wq_a': self._w(rng, 0, (d, self.q_lora_rank), dtype),
             'q_norm': jnp.ones((self.q_lora_rank,), dtype),
-            'wq_b': self._w(rng, 1, (self.q_lora_rank,
-                                     h * (self.nope + self.rope_dim)), dtype),
+            'wq_b': self._w(rng, 1, (self.q_lora_rank, qk), dtype),
+        } if self.q_lora_rank else {'wq': self._w(rng, 1, (d, qk), dtype)}
+        return {
+            'norm': jnp.ones((d,), dtype),
+            **query,
             'wkv_a': self._w(rng, 2, (d, self.kv_lora_rank + self.rope_dim),
                              dtype),
             'kv_norm': jnp.ones((self.kv_lora_rank,), dtype),
@@ -321,24 +335,32 @@ class LatentAttentionLayer(SequenceLayer):
                               preferred_element_type=jnp.float32).astype(dt)
 
         x = rms_norm(h, params['norm'], self.eps)
-        c_q = rms_norm(dot(x, params['wq_a']), params['q_norm'], self.eps)
-        q = heads(c_q, params['wq_b'])                       # [nope | rope]
-        q = jnp.concatenate(
-            [q[..., :self.nope], rope(q[..., self.nope:], self.rope_theta)],
-            axis=-1)
+        if self.q_lora_rank:
+            c_q = rms_norm(dot(x, params['wq_a']), params['q_norm'], self.eps)
+            q = heads(c_q, params['wq_b'])                   # [nope | rope]
+        else:
+            q = heads(x, params['wq'])                       # full rank
+        if self.use_rope:
+            q = jnp.concatenate(
+                [q[..., :self.nope],
+                 rope(q[..., self.nope:], self.rope_theta)], axis=-1)
         ckv = dot(x, params['wkv_a'])                        # [c_kv | k_r]
         c_kv = rms_norm(ckv[..., :self.kv_lora_rank], params['kv_norm'],
                         self.eps)
         kv = heads(c_kv, params['wkv_b'])                    # [k_nope | v]
-        # one head of rotated keys, shared: broadcast inside k's concat
-        k_rope = rope(ckv[..., self.kv_lora_rank:], self.rope_theta)[:, None]
+        k_rope = ckv[..., self.kv_lora_rank:]
+        if self.use_rope:
+            k_rope = rope(k_rope, self.rope_theta)
+        # one head of keys, shared: broadcast inside k's concat
+        k_rope = k_rope[:, None]
         k = jnp.concatenate(
             [kv[..., :self.nope],
              jnp.broadcast_to(k_rope, (b, nh, s, self.rope_dim))], axis=-1)
         v = kv[..., self.nope:]
         o = causal_attention(q, k, v,
                              1.0 / math.sqrt(self.nope + self.rope_dim),
-                             ctx.spmd_devices)               # (b, nh, s, v)
+                             ctx.spmd_devices,
+                             layer=ctx.layer_index)          # (b, nh, s, v)
         out = jnp.einsum('bhsv,hvd->bsd', o,
                          params['wo'].astype(dt).reshape(nh, self.v_dim, d),
                          preferred_element_type=jnp.float32)
@@ -485,7 +507,8 @@ class GroupedAttentionLayer(HeadShare, SequenceLayer):
         k = turned(heads(x, params['wk'], self.nkvhead))
         v = heads(x, params['wv'], self.nkvhead)
         o = causal_attention(q, k, v, 1.0 / math.sqrt(hd), ctx.spmd_devices,
-                             window=self.window)             # (b, nh, s, hd)
+                             window=self.window,
+                             layer=ctx.layer_index)          # (b, nh, s, hd)
         if elementwise:                                      # (b, nh, s, hd)
             gate = jax.nn.sigmoid(jnp.einsum(
                 'bsr,rhd->bhsd', x,
@@ -531,8 +554,10 @@ class DeltaAttentionLayer(HeadShare, SequenceLayer):
     depthwise, ``CONV_TAPS`` taps, no bias); one decay a channel of ``k``,
     ``log alpha = -exp(A_log) * softplus(x W_a_down W_a_up + dt_bias)``
     (``A_log`` one a head; ``W_a_down`` ``(d, head_dim)``, the low-rank
-    form), ``beta = BETA_MAX * sigmoid(x W_beta)`` one a head
-    (``BETA_MAX`` 2 lets ``I - beta k k^T`` take a negative eigenvalue);
+    form), ``beta = beta_max * sigmoid(x W_beta)`` one a head
+    (``allow_neg_eigval = 1``, the default, Kimi Linear's
+    ``kda_allow_neg_eigval``: ``beta_max`` 2 lets ``I - beta k k^T`` take a
+    negative eigenvalue; ``allow_neg_eigval = 0``: ``beta_max`` 1);
     ``S_t = (I - beta_t k_t k_t^T) Diag(alpha_t) S_{t-1} + beta_t k_t
     v_t^T`` from ``S_0 = 0`` and ``o_t = S_t^T q_t / sqrt(head_dim)``
     (``ops/delta_rule``: chunks of 64, float32 at the highest precision,
@@ -554,25 +579,32 @@ class DeltaAttentionLayer(HeadShare, SequenceLayer):
                     'wg_down', 'wg_up', 'g_bias', 'o_norm', 'wo')
     recompute = True
     has_stats = True
-    #: Kimi Linear's ``short_conv_kernel_size`` 4 and
-    #: ``kda_allow_neg_eigval`` (``beta`` in (0, 2))
+    #: Kimi Linear's ``short_conv_kernel_size`` 4
     CONV_TAPS = 4
-    BETA_MAX = 2.0
 
     def __init__(self, name: str = ''):
         super().__init__(name=name)
         self.nhead = self.head_dim = 0
+        self.allow_neg_eigval = 1
 
     def set_param(self, name, val):
         super().set_param(name, val)
         self._set_head_share(name, val)
-        if name in ('nhead', 'head_dim'):
+        if name in ('nhead', 'head_dim', 'allow_neg_eigval'):
             setattr(self, name, int(val))
 
+    @property
+    def beta_max(self) -> float:
+        """``beta``'s upper end: 2 with a negative eigenvalue allowed, 1
+        without."""
+        return 2.0 if self.allow_neg_eigval else 1.0
+
     def infer_shapes(self, in_specs):
-        if min(self.nhead, self.head_dim) <= 0 or self._head_share_error():
+        if min(self.nhead, self.head_dim) <= 0 or self._head_share_error() \
+                or self.allow_neg_eigval not in (0, 1):
             raise ValueError('kda: set nhead and head_dim; hold nhead of '
-                             'nhead_published heads from head_first')
+                             'nhead_published heads from head_first; '
+                             'allow_neg_eigval is 0 or 1')
         return [self._seq_spec(in_specs[0], 'kda')]
 
     def init_params(self, rng, in_specs, dtype=jnp.float32) -> Params:
@@ -645,7 +677,7 @@ class DeltaAttentionLayer(HeadShare, SequenceLayer):
         v = jax.nn.silu(conv('v'))
         log_decay = -jnp.exp(params['a_log'].astype(f32)).reshape(nh, 1, 1) \
             * jax.nn.softplus(low_rank('wa_down', 'wa_up', 'dt_bias'))
-        beta = self.BETA_MAX * jax.nn.sigmoid(jnp.moveaxis(jnp.dot(
+        beta = self.beta_max * jax.nn.sigmoid(jnp.moveaxis(jnp.dot(
             x, params['wbeta'].astype(dt), preferred_element_type=f32), 2, 1))
         o = self.recurrence(q, k, v, log_decay, beta, ctx.spmd_devices)
         gate = jax.nn.sigmoid(low_rank('wg_down', 'wg_up', 'g_bias'))

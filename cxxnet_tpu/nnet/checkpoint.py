@@ -66,14 +66,17 @@ _SAVES_PARAM_STRUCT = {lbase.kFullConnect, lbase.kConv, lbase.kBias,
                        lbase.kFixConnect}
 
 
-def layer_fields(type_id: int):
+def layer_fields(type_id: int, layer=None):
     """Field save order per layer type (reference SaveModel order)."""
     if type_id in (lbase.kFullConnect, lbase.kConv, lbase.kBatchNorm):
         return ('wmat', 'bias')
     if type_id in (lbase.kPRelu, lbase.kBias):
         return ('bias',)
     # a layer with no reference file to match: every field it declares, in
-    # that order
+    # that order - the built layer's own, where its keys decide which it
+    # holds (``mla`` at ``q_lora_rank = 0``)
+    if layer is not None:
+        return tuple(layer.param_fields)
     cls = lbase.LAYER_REGISTRY.get(type_id)
     return tuple(cls.param_fields) if cls is not None else ()
 
@@ -126,7 +129,7 @@ def serialize_blob(net, host: Dict[str, Dict[str, np.ndarray]]) -> bytes:
         if net.layer_primary[i] != i or info.type == lbase.kSharedLayer:
             continue
         layer = net.layers[i]
-        fields = layer_fields(info.type)
+        fields = layer_fields(info.type, layer)
         if not fields:
             continue
         if info.type in _SAVES_PARAM_STRUCT:
@@ -143,14 +146,17 @@ def serialize_blob(net, host: Dict[str, Dict[str, np.ndarray]]) -> bytes:
     return bytes(out)
 
 
-def blob_to_raw(cfg_layers, blob: bytes) -> Dict[str, Dict[str, np.ndarray]]:
-    """Parse a blob into disk-layout arrays keyed by layer index/field."""
+def blob_to_raw(cfg_layers, blob: bytes,
+                layers=None) -> Dict[str, Dict[str, np.ndarray]]:
+    """Parse a blob into disk-layout arrays keyed by layer index/field;
+    ``layers``, the built net's, where there is one."""
     params: Dict[str, Dict[str, np.ndarray]] = {}
     pos = 0
     for i, info in enumerate(cfg_layers):
         if info.type == lbase.kSharedLayer:
             continue
-        fields = layer_fields(info.type)
+        fields = layer_fields(info.type,
+                              layers[i] if layers is not None else None)
         if not fields:
             continue
         if info.type in _SAVES_PARAM_STRUCT:
@@ -370,7 +376,7 @@ def verify_model_digest(path: str):
 
 
 def blob_to_params(net, blob: bytes):
-    raw = blob_to_raw(net.cfg.layers, blob)
+    raw = blob_to_raw(net.cfg.layers, blob, net.layers)
     params = {}
     for i, info in enumerate(net.cfg.layers):
         key = str(i)

@@ -15,11 +15,14 @@ query head); position ``i`` sees ``j <= i`` and, with a ``window``, only
   measured on the v5e for 20 heads of 256 over 8,192 positions (PERF.md,
   PR 29: 5.4 ms forward and 23.3 ms forward and backward at 1024/512
   against 25.6 and 101.6 ms at the kernel's default of 128).
-* Grouped heads or a window, on one TPU chip: JAX's block-sparse kernel
-  (``...pallas.ops.tpu.splash_attention``, its multi-query form, one call a
-  key/value head by ``vmap``): the mask is laid over the grid of blocks
-  when the step is traced, so a key block wholly outside the window is
-  neither fetched nor computed, in the forward, the dq and the dkv kernel;
+* Grouped heads, a window, or value heads narrower or wider than the
+  query/key heads (latent attention at 192/128), on one TPU chip: JAX's
+  block-sparse kernel (``...pallas.ops.tpu.splash_attention``, its
+  multi-query form, one call a key/value head by ``vmap``; its forward, dq
+  and dkv kernels take a value head of its own width): the mask is laid
+  over the grid of blocks when the step is traced, so a key block wholly
+  outside the window is neither fetched nor computed, in the forward, the
+  dq and the dkv kernel;
   what it saves between the passes is one log-sum-exp a row, ``(heads,
   seq)`` float32 (the flash kernel's wrapper broadcasts its row statistics
   to lanes: 3.0 GB a layer at 72 heads, PERF.md 7).
@@ -42,6 +45,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from ..obs import record_event
 from .pallas_kernels import pallas_mode
 
 #: queries a block of the XLA path; a sequence no longer than this is one
@@ -73,10 +77,14 @@ def _use_flash(q, k, v, spmd_devices: int) -> bool:
 
 
 def _use_splash(q, k, v, spmd_devices: int) -> bool:
+    """Keys and values of one batch, heads and length; the value head may
+    be as wide as the query/key head or not; both a multiple of half the
+    128 lanes (192/128 in latent attention, 128/128 in ``gqa``)."""
     if not _on_one_tpu_chip(spmd_devices):
         return False
-    seq, dim = q.shape[2], q.shape[3]
-    return (k.shape == v.shape and k.shape[3] == dim and dim % 128 == 0
+    seq, dim, dim_v = q.shape[2], q.shape[3], v.shape[3]
+    return (k.shape[:3] == v.shape[:3] and k.shape[3] == dim
+            and dim % 64 == 0 and dim_v % 64 == 0
             and seq % SPLASH_BLOCKS_FULL[0] == 0)
 
 
@@ -163,16 +171,31 @@ def causal_attention_xla(q, k, v, scale: float, block_q: int = XLA_BLOCK_Q,
     return out.reshape(b, h, seq, v.shape[3])
 
 
+def attention_path(q, k, v, spmd_devices: int = 1, window: int = 0) -> str:
+    """The kernel ``causal_attention`` takes for these operands: ``flash``,
+    ``splash`` or ``xla``."""
+    if not window and _use_flash(q, k, v, spmd_devices):
+        return 'flash'
+    if _use_splash(q, k, v, spmd_devices):
+        return 'splash'
+    return 'xla'
+
+
 def causal_attention(q, k, v, scale: float, spmd_devices: int = 1,
-                     window: int = 0):
+                     window: int = 0, layer: int = -1):
     """softmax(q k^T * scale, causal and windowed) v; ``q``: ``(b, h, s,
     dk)``, ``k``: ``(b, hk, s, dk)``, ``v``: ``(b, hk, s, dv)`` with ``hk``
-    a divisor of ``h``; ``window`` 0 is none."""
+    a divisor of ``h``; ``window`` 0 is none; ``layer`` the conf layer's
+    index, for the ``attention.path`` event alone."""
     if q.shape[1] % k.shape[1]:
         raise ValueError(f'causal_attention: {q.shape[1]} query heads over '
                          f'{k.shape[1]} key/value heads')
-    if not window and _use_flash(q, k, v, spmd_devices):
+    path = attention_path(q, k, v, spmd_devices, window)
+    record_event('attention.path', 'attention', kernel=path, layer=layer,
+                 heads=q.shape[1], kv_heads=k.shape[1], dk=q.shape[3],
+                 dv=v.shape[3], seq=q.shape[2], window=window)
+    if path == 'flash':
         return _flash(q, k, v, scale)
-    if _use_splash(q, k, v, spmd_devices):
+    if path == 'splash':
         return _splash(q, k, v, scale, window)
     return causal_attention_xla(q, k, v, scale, window=window)

@@ -158,3 +158,70 @@ def test_blocked_attention_matches_the_dense_softmax(case):
     for g, w, name in zip(got, want, 'qkv'):
         np.testing.assert_allclose(g, w, atol=3e-5, rtol=1e-4,
                                    err_msg=f'd{name}')
+
+
+# --- the path each benchmark cell's attention takes --------------------------
+
+def _grouped(heads, kv_heads, dk=128, dv=None, seq=8192):
+    s = lambda h, d: jax.ShapeDtypeStruct(  # noqa: E731
+        (1, h, seq, d), jnp.bfloat16)
+    return s(heads, dk), s(kv_heads, dk), s(kv_heads, dv or dk)
+
+
+#: (backend, (q, k, v), devices of the mesh, window, the path)
+PATHS = {
+    # GLM's latent layers, 20 heads of 256 / 256: flash, as before
+    'glm-mla-256': ('tpu', _qkv(), 1, 0, 'flash'),
+    # Laguna's full and window layers and Solar's softmax layer: grouped
+    # heads of 128 on the block-sparse kernel, as before
+    'laguna-gqa-full-72-over-8': ('tpu', _grouped(72, 8), 1, 0, 'splash'),
+    'laguna-gqa-window-48-over-8': ('tpu', _grouped(48, 8), 1, 512,
+                                    'splash'),
+    'solar-gqa-16-over-2': ('tpu', _grouped(16, 2), 1, 0, 'splash'),
+    # Kimi Linear's latent layer, 32 heads of 192 query/key and 128 value
+    # dims: the block-sparse kernel takes the value head's own width
+    'kimi-mla-192-128': ('tpu', _grouped(32, 32, 192, 128), 1, 0, 'splash'),
+    'kimi-mla-on-the-cpu': ('cpu', _grouped(32, 32, 192, 128), 1, 0, 'xla'),
+    'kimi-mla-on-a-mesh-of-4': ('tpu', _grouped(32, 32, 192, 128), 4, 0,
+                                'xla'),
+    'kimi-mla-seq-no-block-divides': (
+        'tpu', _grouped(32, 32, 192, 128, seq=8192 + 512), 1, 0, 'xla'),
+    # a head width off the half lanes: neither kernel
+    'head-dim-96': ('tpu', _grouped(8, 8, 96), 1, 0, 'xla'),
+    'value-dim-96': ('tpu', _grouped(8, 8, 128, 96), 1, 0, 'xla'),
+}
+
+
+@pytest.mark.parametrize('case', sorted(PATHS))
+def test_the_path_reads_the_backend_the_mesh_and_the_shapes(case,
+                                                            monkeypatch):
+    backend, (q, k, v), spmd, window, want = PATHS[case]
+    monkeypatch.delenv('CXXNET_PALLAS', raising=False)
+    monkeypatch.setattr(jax, 'default_backend', lambda: backend)
+    assert attention.attention_path(q, k, v, spmd, window) == want
+    assert attention._use_splash(q, k, v, spmd) is (
+        want == 'splash' or (want == 'flash' and backend == 'tpu'))
+
+
+def test_each_trace_records_the_path_it_took():
+    """``attention.path``: one hub event each time a step is traced, none
+    when it runs again; the kernel, the conf layer, the heads and key/value
+    heads, both head sizes, the length and the window."""
+    from cxxnet_tpu.obs import install_hub
+    from cxxnet_tpu.obs.hub import TelemetryHub
+    hub = TelemetryHub()
+    was = install_hub(hub)
+    try:
+        q, k, v = _operands(1, 4, 64, 24, 16)
+        run = jax.jit(lambda q, k, v: attention.causal_attention(
+            q, k[:, :2], v[:, :2], 0.2, window=16, layer=7))
+        run(q, k, v)
+        run(q, k, v)
+    finally:
+        install_hub(was)
+    events = [e for e in hub.events() if e['name'] == 'attention.path']
+    assert len(events) == 1
+    assert events[0]['subsystem'] == 'attention'
+    assert events[0]['attrs'] == {'kernel': 'xla', 'layer': 7, 'heads': 4,
+                                  'kv_heads': 2, 'dk': 24, 'dv': 16,
+                                  'seq': 64, 'window': 16}

@@ -15,7 +15,11 @@ compare with.
 The language-model step programs of ``example/LM/tiny-glm.conf`` and
 ``tiny-laguna.conf`` at batch 2 hash to what the tree before the ``kda``
 layer gave (8ec148d): the ``gqa`` keys added with it (``use_rope``,
-``gate``, the head share) leave both as they were."""
+``gate``, the head share) leave both as they were.  ``tiny-solar.conf``'s
+hashes to what the tree before the ``mla`` keys ``q_lora_rank = 0`` and
+``use_rope``, ``kda``'s ``allow_neg_eigval`` and the block-sparse kernel at
+unequal head sizes gave (a68a39e): those leave GLM's, Laguna's and Solar's
+programs as they were."""
 
 import hashlib
 import os
@@ -156,12 +160,14 @@ def test_period_2_programs_are_the_parents(program):
     assert got == PERIOD_2[program]
 
 
-# 8ec148d's tree: the sequence confs' step programs at batch 2, one
-# token id a position and the next token's label (two heads for the GLM
-# twin's multi-token-prediction module)
+# 8ec148d's tree (a68a39e's for the Solar twin): the sequence confs' step
+# programs at batch 2, one token id a position and the next token's label
+# (two heads for the GLM twin's multi-token-prediction module), over each
+# conf's positions
 LM_CASES = {
-    'tiny-glm': (2, '18ae84738b5228948b47da0ccf8892f55a52074e8dee9af4f416d5f8b31c5438'),
-    'tiny-laguna': (1, '296d12655f89cc62831dcae83eea45c333e13a37bd4850aab3e82e582aecdae6'),
+    'tiny-glm': (2, 64, '18ae84738b5228948b47da0ccf8892f55a52074e8dee9af4f416d5f8b31c5438'),
+    'tiny-laguna': (1, 64, '296d12655f89cc62831dcae83eea45c333e13a37bd4850aab3e82e582aecdae6'),
+    'tiny-solar': (1, 96, '76bcb57c3bde6984f5acbb9fe6d4db1ade241b33e4c5068471b95c774c412ad7'),
 }
 
 
@@ -169,13 +175,13 @@ LM_CASES = {
 def test_lm_step_program_is_the_parents(case):
     if jax.__version__ != TAKEN_WITH_JAX:
         pytest.skip(f'hashes taken with jax {TAKEN_WITH_JAX}')
-    heads, want = LM_CASES[case]
+    heads, seq, want = LM_CASES[case]
     tr = _trainer(parse_config_file(os.path.join(ROOT, 'example', 'LM',
                                                  case + '.conf')), 2)
     assert tr.net.takes_token_ids
     rng = np.random.RandomState(0)
     staged = tr.stage_batch(DataBatch(
-        rng.randint(0, 96, (2, 1, 1, 65)).astype(np.float32),
-        rng.randint(0, 96, (2, 64 * heads)).astype(np.float32)))
+        rng.randint(0, 96, (2, 1, 1, seq + 1)).astype(np.float32),
+        rng.randint(0, 96, (2, seq * heads)).astype(np.float32)))
     assert _step_hash(tr, staged) == want
 

@@ -735,3 +735,115 @@ def test_no_scan_or_solve_stands_in_a_delta_layer(solar_step):
                 ' while(' in line or 'triangular' in op + line):
             found.append(line[:200])
     assert not found, found
+
+
+# --- Kimi-Linear-48B-A3B's step: latent attention at 192/128 on the ---------
+# --- block-sparse kernel beside four delta layers ---------------------------
+
+@pytest.fixture(scope='module')
+def kimi_step(one_chip):
+    """``example/LM/Kimi-Linear-48B-A3B.ep32.conf``'s training step,
+    compiled for one v5e chip from shapes alone (about a minute and a
+    half)."""
+    from cxxnet_tpu.nnet.trainer import NetTrainer
+    from cxxnet_tpu.ops import attention, delta_rule
+    tr = NetTrainer(_conf_without_iterators('LM',
+                                            'Kimi-Linear-48B-A3B.ep32.conf')
+                    + [('dev', 'cpu')])
+    tr.init_net()
+    params, opt, arg = _described(tr, one_chip)
+    seq = 8192
+    with pytest.MonkeyPatch.context() as patch:
+        # ops/attention and ops/delta_rule ask jax.default_backend(), which
+        # is the CPU here: the test steers the backend alone, so that the
+        # shapes choose the kernel as they do on the chip
+        patch.setattr(attention, '_on_one_tpu_chip', lambda spmd: spmd == 1)
+        patch.setattr(delta_rule, '_use_kernel', lambda q, v, spmd: spmd == 1)
+        compiled = tr._train_step_fn._jit.lower(
+            params, opt, None,
+            arg((1, 1, 1, seq + 1), jnp.int32),
+            arg((1, seq), jnp.float32), (), arg((1,), jnp.float32),
+            arg((2,), jnp.uint32), 0, 0, do_update=True).compile()
+    return compiled, params
+
+
+def test_the_kimi_step_fits_the_chip(kimi_step):
+    """602.5 M parameters at 12 bytes each resident (6.73 GiB) and the
+    step's temporaries under the chip's 15.75 GiB: the compiler's plan,
+    printed.  Read 10.83e9 bytes (10.09 GiB, of them 3.35 GiB of
+    temporaries) when the configuration came."""
+    import numpy as np
+    compiled, params = kimi_step
+    m = compiled.memory_analysis()
+    state = sum(int(np.prod(a.shape)) for a in jax.tree.leaves(params)) * 12
+    assert state == 602_450_816 * 12
+    live = (m.argument_size_in_bytes + m.output_size_in_bytes
+            - m.alias_size_in_bytes + m.temp_size_in_bytes)
+    print(f'the Kimi step\'s plan: {live} bytes, {live / 2 ** 30:.3f} GiB '
+          f'(state {state / 2 ** 30:.3f}, temporaries '
+          f'{m.temp_size_in_bytes / 2 ** 30:.3f})')
+    assert m.alias_size_in_bytes >= state          # the state is donated
+    assert live < 12.0e9 < 15.75 * 2 ** 30, live / 2 ** 30
+
+
+@pytest.mark.parametrize('event,calls', [('splash_mqa_fwd', 2),
+                                         ('splash_mqa_dq', 1),
+                                         ('splash_mqa_dkv', 1),
+                                         ('delta_rule_fwd', 2),
+                                         ('delta_rule_bwd', 1)])
+def test_the_kimi_kernels_keep_their_names(kimi_step, event, calls):
+    """The latent layer's (``l08_mla_attn3``) Mosaic calls are the
+    block-sparse kernels ``kernels.mla_*_roofline_pct`` read, at query/key
+    heads of 192 and value heads of 128, and each of the four delta layers'
+    are the delta rule's kernels: the forward twice (the recomputation) and
+    the backward once.  Every other Mosaic call of the step is a grouped
+    product."""
+    kernels = _kernels_by_scope(kimi_step[0].as_text())
+    layer, names = (('_mla', ('splash_mqa_fwd', 'splash_mqa_dq',
+                              'splash_mqa_dkv'))
+                    if event.startswith('splash') else
+                    ('_kda', ('delta_rule_fwd', 'delta_rule_bwd')))
+    mine = {n: scope for n, (scope, _) in kernels.items() if layer in scope}
+    assert mine and all(n.startswith(names) for n in mine), mine
+    scopes = sorted(set(mine.values()))
+    assert scopes == (['l08_mla_attn3'] if layer == '_mla' else
+                      ['l02_kda_kda0', 'l04_kda_kda1', 'l06_kda_kda2',
+                       'l10_kda_kda4']), scopes
+    for scope in scopes:
+        assert len([n for n, at in mine.items()
+                    if at == scope and n.startswith(event)]) == calls, scope
+    if layer == '_mla':
+        # what each kernel writes: the output at the value heads' 128, dq at
+        # the query/key heads' 192, dk and dv at their own
+        wide = {'splash_mqa_fwd': ['bf16[32,1,8192,128]'],
+                'splash_mqa_dq': ['bf16[32,1,8192,192]'],
+                'splash_mqa_dkv': ['bf16[32,8192,192]', 'bf16[32,8192,128]']}
+        for n, (_, line) in kernels.items():
+            if n.startswith(event):
+                outs = line.split(' custom-call(')[0]
+                assert all(w + '{' in outs for w in wide[event]), outs
+    others = {n for n, (scope, _) in kernels.items()
+              if '_mla' not in scope and '_kda' not in scope}
+    assert others and all(n.startswith('ragged-dot') for n in others), others
+
+
+def test_no_blocked_softmax_stands_in_the_latent_layer(kimi_step):
+    """On the kernel the latent layer holds no loop over blocks of queries
+    and no ``(heads, block, seq)`` score array (the XLA path's ``lax.map``
+    of masked softmaxes over blocks of 512 queries, recomputed in the
+    backward pass), in any pass.  (``wkv_b`` is ``(512, 8192)`` too: two
+    dims, not a score array.)"""
+    from cxxnet_tpu.obs.programs import an_instruction_a_line
+    from cxxnet_tpu.utils import profiler
+    hlo = an_instruction_a_line(kimi_step[0].as_text())
+    scopes = profiler.hlo_op_names(hlo)
+    found = []
+    for line in hlo.splitlines():
+        m = profiler._HLO_INSTRUCTION.match(line)
+        op = scopes.get(m.group(1), '') if m else ''
+        result = line.split(' = ', 1)[1][:80] if ' = ' in line else ''
+        if '_mla' in profiler.scope_of(op)[0] and (
+                ' while(' in line
+                or re.search(r'\[\d+(?:,\d+)*,512,8192\]', result)):
+            found.append(line[:200])
+    assert not found, found
